@@ -97,9 +97,10 @@ from . import models
 from . import utils
 
 # Persistent XLA compilation cache (doc/developer-guide/compile_cache.md):
-# opt-in via MXNET_TPU_COMPILE_CACHE so warm process starts skip XLA
-# compilation entirely — must be wired before the first compile dispatches.
-utils.compile.maybe_enable_persistent_cache_from_env()
+# JAX_COMPILATION_CACHE_DIR when the environment sets it, else
+# <checkout>/.jax_cache — must be resolved before the first compile
+# dispatches.
+utils.compile.configure_persistent_cache()
 from . import predictor as _predictor_mod
 from .predictor import Predictor
 from . import analysis

@@ -1,20 +1,12 @@
-"""JAX version-compatibility shims.
+"""The one place JAX APIs with a history of moving are imported from.
 
-Motivation (ISSUE 1): the seed pinned ``from jax import shard_map``, an
-import path that only exists in newer JAX — one moved symbol bricked all 75
-test modules at collection time. Every JAX API whose location or signature
-drifts across the supported range (``jax>=0.4.30,<0.6``, see pyproject.toml)
-is re-exported here once, and direct imports of the fragile paths are banned
-by the mxlint rule MX101 (``mxnet_tpu/analysis/source_lint.py``) so the
-breakage class cannot regress.
-
-Shims:
-  shard_map     : resolves ``jax.shard_map`` (new) or
-                  ``jax.experimental.shard_map.shard_map`` (old), and
-                  translates the ``check_vma`` kwarg (new name) to
-                  ``check_rep`` (old name) or back, whichever the installed
-                  signature accepts.
-  jax_version   : the installed version as a comparable int tuple.
+Motivation (ISSUE 1): the seed pinned an import path for ``shard_map``
+that a JAX release moved — one moved symbol bricked all 75 test modules
+at collection time. Call sites import such names from here, and direct
+imports of the fragile paths are banned by the mxlint rule MX101
+(``mxnet_tpu/analysis/source_lint.py``), so the next move is a one-line
+fix. The code is written for the one installed JAX (pinned in
+pyproject.toml): there are no branches for other versions.
 
 Keep this module dependency-light: it is imported by models/parallel at
 module scope, so anything heavy here taxes every ``import mxnet_tpu``.
@@ -22,68 +14,12 @@ module scope, so anything heavy here taxes every ``import mxnet_tpu``.
 
 from __future__ import annotations
 
-import inspect
-
 import jax
+from jax import shard_map  # mxlint: disable=MX101
 
-__all__ = ["shard_map", "jax_version", "JAX_VERSION",
-           "distributed_initialized"]
+__all__ = ["shard_map", "distributed_initialized"]
 
 
 def distributed_initialized() -> bool:
-    """True when the jax.distributed runtime is up.
-
-    API drift: ``jax.distributed.is_initialized()`` only exists in newer
-    JAX; older versions expose the client on ``distributed.global_state``.
-    """
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        return bool(is_init())
-    state = getattr(jax.distributed, "global_state", None)
-    return state is not None and getattr(state, "client", None) is not None
-
-
-def jax_version() -> tuple[int, ...]:
-    """Installed JAX version as an int tuple, e.g. (0, 4, 37)."""
-    parts = []
-    for p in jax.__version__.split("."):
-        digits = "".join(ch for ch in p if ch.isdigit())
-        if not digits:
-            break
-        parts.append(int(digits))
-    return tuple(parts)
-
-
-JAX_VERSION = jax_version()
-
-
-def _resolve_shard_map():
-    try:
-        from jax import shard_map as sm  # mxlint: disable=MX101
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm  # mxlint: disable=MX101
-    # jax >= 0.7 exposes jax.shard_map as a *module* with the callable inside
-    if not callable(sm):
-        sm = sm.shard_map
-    return sm
-
-
-_shard_map_impl = _resolve_shard_map()
-_shard_map_params = frozenset(inspect.signature(_shard_map_impl).parameters)
-
-
-def shard_map(f, mesh, in_specs, out_specs, **kwargs):
-    """Version-stable ``shard_map``.
-
-    Accepts either spelling of the replication-check flag (``check_vma`` in
-    new JAX, ``check_rep`` in old) and forwards whichever the installed
-    implementation understands; all other kwargs pass through untouched.
-    """
-    for new, old in (("check_vma", "check_rep"), ("check_rep", "check_vma")):
-        if new in kwargs and new not in _shard_map_params:
-            if old in _shard_map_params:
-                kwargs[old] = kwargs.pop(new)
-            else:  # neither spelling supported: drop rather than TypeError
-                kwargs.pop(new)
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kwargs)
+    """True when the jax.distributed runtime is up."""
+    return bool(jax.distributed.is_initialized())

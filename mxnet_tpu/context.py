@@ -16,6 +16,8 @@ import threading
 
 import jax
 
+from .base import MXNetError
+
 __all__ = ["Context", "cpu", "gpu", "tpu", "cpu_pinned", "current_context", "num_devices"]
 
 _thread_local = threading.local()
@@ -70,15 +72,19 @@ class Context:
     def jax_device(self) -> jax.Device:
         """Resolve to a concrete jax.Device.
 
-        ``tpu``/``gpu`` pick from accelerator devices, falling back to CPU when
-        no accelerator is attached (e.g. unit tests under JAX_PLATFORMS=cpu).
+        ``tpu``/``gpu`` name exactly one attached accelerator: no
+        accelerator, or a ``device_id`` past the last one, raises rather
+        than landing the work on the host CPU or on another chip.
         """
         if self.device_type in ("tpu", "gpu"):
             accel = _accelerator_devices()
-            if accel:
-                return accel[self.device_id % len(accel)]
-            cpus = _cpu_devices()
-            return cpus[self.device_id % len(cpus)]
+            if not 0 <= self.device_id < len(accel):
+                raise MXNetError(
+                    f"{self!r}: this process has {len(accel)} accelerator "
+                    f"device(s) (jax.local_devices(): "
+                    f"{[str(d) for d in jax.local_devices()]}); use "
+                    "mx.cpu() to run on the host")
+            return accel[self.device_id]
         cpus = _cpu_devices()
         return cpus[self.device_id % len(cpus)]
 
@@ -135,8 +141,13 @@ def current_context() -> Context:
 
 
 def num_devices(device_type="tpu") -> int:
-    """Number of attached devices of ``device_type`` ('tpu' counts accelerators)."""
+    """Number of attached devices of ``device_type`` ('tpu' counts
+    accelerators, and raises on a CPU-only host rather than count CPUs)."""
     if device_type in ("tpu", "gpu"):
         accel = _accelerator_devices()
-        return len(accel) if accel else len(_cpu_devices())
+        if not accel:
+            raise MXNetError(
+                f"num_devices({device_type!r}): no accelerator attached "
+                "to this process; num_devices('cpu') counts host devices")
+        return len(accel)
     return len(_cpu_devices())

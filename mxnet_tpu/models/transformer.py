@@ -16,6 +16,7 @@ Pure-functional: params are a flat dict (names match
 from __future__ import annotations
 
 import functools
+import logging
 import math
 
 import jax
@@ -23,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..base import MXNetError
 from ..parallel.sequence import attention_reference, ring_self_attention
 from ..parallel.tensor_parallel import transformer_param_specs
 
@@ -64,6 +66,7 @@ def _layernorm(x, scale, bias, eps=1e-5):
 class TransformerLM:
     def __init__(self, config):
         self.cfg = dict(config)
+        self._dense_notice_given = False
 
     def _use_flash(self) -> bool:
         impl = self.cfg.get("attn_impl", "auto")
@@ -72,6 +75,28 @@ class TransformerLM:
         if impl == "dense":
             return False
         return jax.default_backend() == "tpu"
+
+    def _flash_fits_mesh(self, mesh, batch) -> bool:
+        """The flash kernel runs per (dp, tp) shard under shard_map, which
+        needs the batch and the heads to split evenly. An explicit
+        ``attn_impl="flash"`` that cannot raises; ``auto`` takes the dense
+        path instead and says so once per model."""
+        if mesh is None:
+            return True
+        dp, tp = mesh.shape.get("dp", 1), mesh.shape.get("tp", 1)
+        heads = self.cfg["n_heads"]
+        if batch % dp == 0 and heads % tp == 0:
+            return True
+        why = (f"batch {batch} / heads {heads} do not split evenly over "
+               f"the mesh (dp={dp}, tp={tp})")
+        if self.cfg.get("attn_impl", "auto") == "flash":
+            raise MXNetError(f"attn_impl='flash': {why}; pad the batch, "
+                             "change the mesh, or ask for 'dense'")
+        if not self._dense_notice_given:
+            self._dense_notice_given = True
+            logging.warning("TransformerLM attn_impl='auto': %s; using "
+                            "dense attention", why)
+        return False
 
     # -- parameters -----------------------------------------------------------
     def init_params(self, key) -> dict:
@@ -164,15 +189,11 @@ class TransformerLM:
                 # flash blocks inside the ring on TPU; dense blocks in tests
                 attn = ring_self_attention(mesh, q, k, v, causal=True,
                                            use_flash=self._use_flash())
-            elif self._use_flash():
+            elif self._use_flash() and \
+                    self._flash_fits_mesh(mesh, q.shape[0]):
                 from ..ops.pallas import flash_attention
-                if mesh is None or q.shape[0] % mesh.shape.get("dp", 1) or \
-                        h % mesh.shape.get("tp", 1):
-                    # shard_map needs even partitioning; uneven batch/head
-                    # counts stay on the GSPMD-padded dense path
-                    attn = (flash_attention(q, k, v, causal=True)
-                            if mesh is None
-                            else attention_reference(q, k, v, causal=True))
+                if mesh is None:
+                    attn = flash_attention(q, k, v, causal=True)
                 else:
                     # pallas_call has no GSPMD partitioning rule; run the
                     # kernel per-shard over (dp, tp) via shard_map so the
@@ -250,8 +271,6 @@ class TransformerLM:
         built-in). lr_schedulers are rejected: the fused step carries no
         step counter — rebuild the step per phase (each build is a cache
         hit for unchanged lr) or train via FeedForward for scheduling."""
-        from ..base import MXNetError
-
         if optimizer is not None and optimizer.lr_scheduler is not None:
             raise MXNetError(
                 "make_train_step: lr_scheduler is not consulted by the "

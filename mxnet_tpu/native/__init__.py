@@ -3,15 +3,16 @@
 The reference's IO stack is C++ (src/io/ + dmlc-core); so is ours: RecordIO
 parsing, libjpeg decode, augmentation and batch assembly run in
 mxtpu_native.cc worker threads, keeping the Python side to a thin ctypes
-wrapper. Built lazily with `make` on first use (no pip involved); every
-consumer falls back to the pure-Python path when the toolchain or libjpeg
-is unavailable, so the native library is an accelerator, never a
-requirement.
+wrapper. Built with `make` from the tracked sources on first use (no pip
+involved) and loaded only when that build succeeded; every consumer falls
+back to the pure-Python path when the toolchain or libjpeg is unavailable,
+so the native library is an accelerator, never a requirement.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 
@@ -30,9 +31,13 @@ def _build() -> bool:
     try:
         subprocess.run(["make", "-C", _DIR, "-s"], check=True,
                        capture_output=True, timeout=120)
-        return os.path.exists(_SO)
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = getattr(e, "stderr", b"") or b""
+        logging.warning("native IO library not built (%s); using the "
+                        "pure-Python pipeline. %s", e,
+                        detail.decode(errors="replace").strip()[-400:])
         return False
+    return os.path.exists(_SO)
 
 
 def get_lib():
@@ -43,10 +48,10 @@ def get_lib():
             return _lib
         _tried = True
         # Always invoke make: its mxtpu_native.cc dependency makes a fresh
-        # .so a no-op, and a stale .so (built before an ABI change, e.g. the
-        # nhwc/out_u8 pipeline args) would otherwise be loaded silently and
-        # corrupt batches.
-        if not _build() and not os.path.exists(_SO):
+        # .so a no-op. A .so that make could not rebuild is never loaded:
+        # one built before an ABI change (e.g. the nhwc/out_u8 pipeline
+        # args) would corrupt batches without a word.
+        if not _build():
             return None
         try:
             lib = ctypes.CDLL(_SO)

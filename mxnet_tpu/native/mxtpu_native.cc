@@ -335,7 +335,7 @@ class ImagePipeline {
         // every other stage (record read, CRC, resize, crop, mirror, batch
         // assembly, delivery) live. tools/bench_io_scaling.py uses this to
         // measure the pipeline's non-decode cost — the serial floor of the
-        // Amdahl projection published in BENCH_NOTES_r03.md.
+        // Amdahl projection.
         h = w = std::max({256, cfg_.height, cfg_.width});
         pixels.assign(size_t(h) * w * 3, img_len ? img[0] : 0);
       } else if (!DecodeJpeg(img, img_len, &pixels, &h, &w)) {

@@ -43,17 +43,18 @@ def get_predict_lib():
         if _lib is not None or _tried:
             return _lib
         _tried = True
+        # Always invoke make (a no-op on a fresh .so) and load only what
+        # it just built from the tracked source, never a leftover binary.
+        # Only the predict target: it needs just zlib, and must not fail
+        # on hosts missing the pipeline library's libjpeg dep.
+        try:
+            subprocess.run(["make", "-C", _DIR, "-s",
+                            "libmxtpu_predict.so"], check=True,
+                           capture_output=True, timeout=120)
+        except (OSError, subprocess.SubprocessError):
+            return None
         if not os.path.exists(_SO):
-            # Build only the predict target: it needs just zlib, and must not
-            # fail on hosts missing the pipeline library's libjpeg dep.
-            try:
-                subprocess.run(["make", "-C", _DIR, "-s",
-                                "libmxtpu_predict.so"], check=True,
-                               capture_output=True, timeout=120)
-            except Exception:
-                return None
-            if not os.path.exists(_SO):
-                return None
+            return None
         try:
             _lib = load_lib(_SO)
         except OSError:

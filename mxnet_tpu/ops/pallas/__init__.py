@@ -17,7 +17,7 @@ Kernels (catalog: doc/developer-guide/kernels.md):
   adam                the whole Adam/AdamW update as one blocked pass
                       over the flattened (param, grad, m, v) slab —
                       bitwise parity with the per-leaf optimizer.
-  matmul              int8 matmul (per-channel scales, f32 accumulate)
+  matmul              int8 matmul (per-channel scales, int32 accumulate)
                       for the serving/predict path.
 
 Infrastructure:
@@ -31,8 +31,8 @@ Infrastructure:
   _common             the ONE interpret-mode gate: off-TPU backends run
                       every kernel through the Pallas interpreter, so
                       unit tests exercise the real kernel code paths on
-                      the 8-device CPU mesh; ``MXNET_TPU_PALLAS_INTERPRET``
-                      forces either direction.
+                      the 8-device CPU mesh (``MXNET_TPU_PALLAS_INTERPRET``
+                      overrides there); a TPU process always compiles.
 """
 
 from ._common import resolve_interpret, use_interpret  # noqa: F401

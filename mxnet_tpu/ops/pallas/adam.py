@@ -30,8 +30,11 @@ replicated params update replicatedly, exactly like the per-leaf tree it
 replaces. Gate: ``Adam(fused=True)`` / env ``MXNET_TPU_FUSED_ADAM``.
 
 Per-leaf scalars (bias-correction factors from each leaf's step counter,
-AdamW's decay-filtered weight decay) ride in SMEM, one scalar row per
-tile — leaves are padded to whole tiles so no tile straddles two leaves.
+AdamW's decay-filtered weight decay) ride in SMEM as whole ``(T,)`` arrays
+indexed by the grid position — leaves are padded to whole tiles so no tile
+straddles two leaves. Each ``block``-element tile is presented to Mosaic
+as an ``(8, block/8)`` slab (f32 sublane count), so ``block`` must be a
+multiple of 8 and, to fill the lanes, of 1024.
 """
 
 from __future__ import annotations
@@ -52,6 +55,7 @@ from .registry import KernelCost, io_bytes, register_kernel
 __all__ = ["fused_adam_apply", "fused_resolve", "DEFAULT_BLOCK"]
 
 DEFAULT_BLOCK = 8192  # f32 elements per tile (32 KB): VPU-bound either way
+_SUBLANES = 8         # f32 rows of one TPU vreg tile
 
 
 def fused_resolve(value) -> bool:
@@ -82,12 +86,13 @@ def _adam_kernel(w_ref, g_ref, m_ref, v_ref, c1_ref, c2_ref, wd_ref, lr_ref,
     g = g + wd_l2 * w
     m = beta1 * m_ref[:] + (1 - beta1) * g
     v = beta2 * v_ref[:] + (1 - beta2) * jnp.square(g)
-    mhat = m / c1_ref[0, 0]
-    vhat = v / c2_ref[0, 0]
-    lr = lr_ref[0, 0]
+    i = pl.program_id(0)
+    mhat = m / c1_ref[i]
+    vhat = v / c2_ref[i]
+    lr = lr_ref[0]
     new_w = w - lr * mhat / (jnp.sqrt(vhat) + eps)
     if decoupled:
-        new_w = new_w - lr * wd_ref[0, 0] * w
+        new_w = new_w - lr * wd_ref[i] * w
     wn_ref[:] = new_w
     mn_ref[:] = m
     vn_ref[:] = v
@@ -119,6 +124,10 @@ def fused_adam_apply(opt, params, grads, states, lr, *, block=None,
     """
     interpret = resolve_interpret(interpret)
     block = int(block or DEFAULT_BLOCK)
+    if block % _SUBLANES:
+        raise MXNetError(f"fused_adam block={block} must be a multiple of "
+                         f"{_SUBLANES} (one tile is an ({_SUBLANES}, "
+                         f"block/{_SUBLANES}) slab)")
     names = list(params)
     if not names:
         return {}, {}
@@ -130,51 +139,48 @@ def fused_adam_apply(opt, params, grads, states, lr, *, block=None,
     tiles = [-(-s // block) for s in sizes]
     T = sum(tiles)
 
-    flat_w = _flatten_padded(leaves_w, block).reshape(T, block)
+    # tile i is rows [8i, 8i+8) of the (8T, block/8) slab
+    slab = (T * _SUBLANES, block // _SUBLANES)
+    flat_w = _flatten_padded(leaves_w, block).reshape(slab)
     flat_g = _flatten_padded([grads[k] for k in names],
-                             block).reshape(T, block)
+                             block).reshape(slab)
     flat_m = _flatten_padded([states[k][0] for k in names],
-                             block).reshape(T, block)
+                             block).reshape(slab)
     flat_v = _flatten_padded([states[k][1] for k in names],
-                             block).reshape(T, block)
+                             block).reshape(slab)
 
-    # per-leaf scalars, broadcast to per-tile SMEM rows. The bias
-    # correction uses the SAME expressions as _apply_one (t+1, 1-beta**t)
-    # so the divided-by values are bitwise identical.
+    # per-leaf scalars, repeated per tile. The bias correction uses the
+    # SAME expressions as _apply_one (t+1, 1-beta**t) so the divided-by
+    # values are bitwise identical.
     t_new = {k: states[k][2] + 1.0 for k in names}
     c1_rows, c2_rows, wd_rows = [], [], []
     for k, nt in zip(names, tiles):
-        c1 = jnp.reshape(1 - opt.beta1 ** t_new[k], (1, 1))
-        c2 = jnp.reshape(1 - opt.beta2 ** t_new[k], (1, 1))
-        c1_rows.append(jnp.broadcast_to(c1.astype(jnp.float32), (nt, 1)))
-        c2_rows.append(jnp.broadcast_to(c2.astype(jnp.float32), (nt, 1)))
+        c1 = jnp.reshape(1 - opt.beta1 ** t_new[k], (1,))
+        c2 = jnp.reshape(1 - opt.beta2 ** t_new[k], (1,))
+        c1_rows.append(jnp.broadcast_to(c1.astype(jnp.float32), (nt,)))
+        c2_rows.append(jnp.broadcast_to(c2.astype(jnp.float32), (nt,)))
         if decoupled:
             wd = opt.weight_decay if (decay_filter is None
                                       or decay_filter(k)) else 0.0
-            wd_rows.append(np.full((nt, 1), wd, np.float32))
-    c1_t = jnp.concatenate(c1_rows) if len(c1_rows) > 1 else c1_rows[0]
-    c2_t = jnp.concatenate(c2_rows) if len(c2_rows) > 1 else c2_rows[0]
-    wd_t = jnp.asarray(np.concatenate(wd_rows) if len(wd_rows) > 1
-                       else wd_rows[0]) if decoupled \
-        else jnp.zeros((T, 1), jnp.float32)
-    lr_s = jnp.asarray(lr, jnp.float32).reshape(1, 1)
+            wd_rows.append(np.full((nt,), wd, np.float32))
+    c1_t = jnp.concatenate(c1_rows)
+    c2_t = jnp.concatenate(c2_rows)
+    wd_t = jnp.asarray(np.concatenate(wd_rows)) if decoupled \
+        else jnp.zeros((T,), jnp.float32)
+    lr_s = jnp.asarray(lr, jnp.float32).reshape(1)
 
     kern = functools.partial(
         _adam_kernel, beta1=opt.beta1, beta2=opt.beta2, eps=opt.epsilon,
         rescale=opt.rescale_grad, clip=opt.clip_gradient,
         wd_l2=(0.0 if decoupled else opt.wd), decoupled=decoupled)
-    big = pl.BlockSpec((1, block), lambda i: (i, 0))
-    row_scalar = pl.BlockSpec((1, 1), lambda i: (i, 0),
-                              memory_space=pltpu.SMEM)
-    one_scalar = pl.BlockSpec((1, 1), lambda i: (0, 0),
-                              memory_space=pltpu.SMEM)
+    big = pl.BlockSpec((_SUBLANES, slab[1]), lambda i: (i, 0))
+    scalars = pl.BlockSpec(memory_space=pltpu.SMEM)  # whole array
     new_w, new_m, new_v = pl.pallas_call(
         kern,
         grid=(T,),
-        in_specs=[big, big, big, big, row_scalar, row_scalar, row_scalar,
-                  one_scalar],
+        in_specs=[big, big, big, big, scalars, scalars, scalars, scalars],
         out_specs=[big, big, big],
-        out_shape=[jax.ShapeDtypeStruct((T, block), jnp.float32)] * 3,
+        out_shape=[jax.ShapeDtypeStruct(slab, jnp.float32)] * 3,
         interpret=interpret,
         name="fused_adam",
     )(flat_w, flat_g, flat_m, flat_v, c1_t, c2_t, wd_t, lr_s)

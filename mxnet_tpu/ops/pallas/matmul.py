@@ -1,4 +1,4 @@
-"""Int8 matmul with per-channel scales and f32 accumulation (serving path).
+"""Int8 matmul with per-channel scales and int32 accumulation (serving path).
 
 The predict/serving matmuls (ROADMAP item 1) are weight-stationary and
 error-tolerant: int8 operands run the MXU at twice the bf16 rate and
@@ -10,9 +10,9 @@ quantization error at the well-known ~1e-3 relative level. The kernel:
     wq (N, K) int8       -- weights, pre-quantized per output CHANNEL
                             (:func:`quantize_channels`, FC layout so
                             checkpoints map 1:1)
-    y  (M, N) float32    -- dot(int8, int8) accumulated in f32
-                            (`preferred_element_type`), rescaled by
-                            sx[m] * sw[n]
+    y  (M, N) float32    -- dot(int8, int8) accumulated in int32 (the
+                            MXU's integer mode; exact), converted and
+                            rescaled by sx[m] * sw[n] in f32
 
 Serving integration: ``ops.nn.FullyConnectedOp`` routes inference-mode
 matmuls here under :func:`int8_predict_scope` (or env
@@ -96,8 +96,8 @@ def _int8_mm_kernel(x_ref, wq_ref, sw_ref, o_ref):
     qx = jnp.clip(jnp.round(x / sx), -127, 127).astype(jnp.int8)
     acc = jax.lax.dot_general(
         qx, wq_ref[:], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)          # f32 accumulate
-    o_ref[:] = acc * sx * sw_ref[:]
+        preferred_element_type=jnp.int32)            # exact accumulate
+    o_ref[:] = acc.astype(jnp.float32) * sx * sw_ref[:]
 
 
 def int8_matmul(x, w, *, w_scale=None, block_m=256, block_n=256,
@@ -148,5 +148,5 @@ def _int8_mm_cost(in_avals, out_avals):
 
 register_kernel(
     "int8_matmul", _int8_mm_cost, module=__name__,
-    doc="per-channel-scaled int8 matmul, f32 accumulate, fused dynamic "
+    doc="per-channel-scaled int8 matmul, int32 accumulate, fused dynamic "
         "activation quantization (serving path)")

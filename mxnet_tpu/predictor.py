@@ -41,7 +41,7 @@ class Predictor:
         self.input_names = list(input_names)
         self.compute_dtype = compute_dtype
         # quantize="int8": serve FullyConnected matmuls through the int8
-        # Pallas kernel (per-channel weight scales, f32 accumulate; see
+        # Pallas kernel (per-channel weight scales, int32 accumulate; see
         # ops/pallas/matmul.py). The gate is trace-time, so forward()
         # wraps the jit dispatch in the scope — the first call traces the
         # quantized program, later calls reuse it.

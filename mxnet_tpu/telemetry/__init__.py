@@ -40,7 +40,8 @@ from .distributed import (trace_id, set_trace_id, set_world, current_rank,
                           load_rank_streams)
 from .timeline import (StepTimeline, Span, current_span,
                        clear_current_span, phase, timed)
-from .mfu import (MFUAccountant, resolve_peak_flops, measured_peak_flops,
+from .mfu import (MFUAccountant, DEVICE_PEAKS, device_peak_flops,
+                  resolve_peak_flops, measured_peak_flops,
                   record_compile_badput)
 from .exporters import (SCHEMA_VERSION, EVENT_GOLDEN_KEYS, JsonlWriter,
                         write_jsonl, read_jsonl, read_events, prom_dump,
@@ -74,7 +75,8 @@ __all__ = [
     "load_rank_streams",
     "StepTimeline", "Span", "current_span", "clear_current_span", "phase",
     "timed",
-    "MFUAccountant", "resolve_peak_flops", "measured_peak_flops",
+    "MFUAccountant", "DEVICE_PEAKS", "device_peak_flops",
+    "resolve_peak_flops", "measured_peak_flops",
     "record_compile_badput",
     "SCHEMA_VERSION", "EVENT_GOLDEN_KEYS", "JsonlWriter", "write_jsonl",
     "read_jsonl", "read_events", "prom_dump", "serve_http", "stop_http",

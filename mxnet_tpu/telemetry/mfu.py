@@ -8,10 +8,15 @@ only, no execution) and its per-primitive FLOP rows summed — forward,
 backward, and the fused optimizer update all included, because they are
 all in the one program. The denominator resolves, in order:
 
-  1. ``MXNET_TPU_PEAK_FLOPS`` — peak FLOP/s **per device** (the number
-     from the chip's datasheet, e.g. 275e12 for a TPU v4 chip's bf16 MXU);
-  2. a one-time measured matmul peak on the actual backend (the honest
-     default on CPU rigs, where a datasheet number would be fiction).
+  1. ``MXNET_TPU_PEAK_FLOPS`` — peak FLOP/s **per device**, for a chip
+     the table does not know;
+  2. :data:`DEVICE_PEAKS`, the published peak keyed by the device's
+     ``device_kind`` — the one table ``bench.py`` reads too;
+  3. on the CPU backend only, a one-time measured matmul rate (a
+     datasheet number would be fiction there; the ratio is rig-relative).
+
+An accelerator whose kind is not in the table has no peak: MFU is
+reported as unavailable, never computed against a guess.
 
 Caveat that ships with the number (see doc/developer-guide/telemetry.md):
 the jaxpr table counts *pre-fusion* model FLOPs — what the model
@@ -42,8 +47,26 @@ import os
 
 from .hub import hub as _hub
 
-__all__ = ["MFUAccountant", "resolve_peak_flops", "measured_peak_flops",
+__all__ = ["MFUAccountant", "DEVICE_PEAKS", "device_peak_flops",
+           "resolve_peak_flops", "measured_peak_flops",
            "record_compile_badput"]
+
+# Published per-chip peaks keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM).
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+}
+
+
+def device_peak_flops(device=None):
+    """Published bf16 peak FLOP/s of ``device`` (default: the first
+    device), or None when its kind is not in :data:`DEVICE_PEAKS`."""
+    import jax
+
+    if device is None:
+        device = jax.devices()[0]
+    row = DEVICE_PEAKS.get(device.device_kind)
+    return None if row is None else row["bf16_flops"]
 
 _MEASURED_PEAK = {}  # backend platform -> measured FLOP/s per device
 
@@ -85,9 +108,12 @@ def record_compile_badput(total_seconds, window_seconds, epoch=None):
 
 
 def measured_peak_flops(n=384, iters=8):
-    """One-time matmul-derived peak FLOP/s estimate for one device of the
-    default backend (cached per platform). Small n keeps it under ~0.2s on
-    CPU while saturating the unit enough for a usable ceiling."""
+    """One-time matmul-derived FLOP/s estimate for one device of the
+    default backend (cached per platform) — the CPU rigs' denominator;
+    at this size an accelerator is dispatch-bound, so
+    :func:`resolve_peak_flops` never consults it there. Small n keeps it
+    under ~0.2s on CPU while saturating the unit enough for a usable
+    ceiling."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -113,17 +139,31 @@ def measured_peak_flops(n=384, iters=8):
     flops = 2.0 * n * n * n * iters
     peak = flops / max(t.elapsed, 1e-9)
     _MEASURED_PEAK[platform] = peak
-    logging.info("telemetry: measured matmul peak %.2f GFLOP/s on %s "
-                 "(set MXNET_TPU_PEAK_FLOPS for the datasheet number)",
+    logging.info("telemetry: measured matmul peak %.2f GFLOP/s on %s",
                  peak / 1e9, platform)
     return peak
 
 
 def resolve_peak_flops(num_devices=1):
-    """Aggregate peak FLOP/s for ``num_devices`` devices (env override
-    first, measured fallback)."""
+    """Aggregate peak FLOP/s for ``num_devices`` devices (module
+    docstring: env override, published table, measured on CPU), or None
+    for an accelerator the table does not list."""
+    import jax
+
     raw = os.environ.get("MXNET_TPU_PEAK_FLOPS", "").strip()
-    per_device = float(raw) if raw else measured_peak_flops()
+    if raw:
+        per_device = float(raw)
+    else:
+        dev = jax.devices()[0]
+        per_device = device_peak_flops(dev)
+        if per_device is None and dev.platform == "cpu":
+            per_device = measured_peak_flops()
+        if per_device is None:
+            logging.warning(
+                "telemetry: no published peak for device kind %r; MFU is "
+                "unavailable (add it to telemetry.mfu.DEVICE_PEAKS or set "
+                "MXNET_TPU_PEAK_FLOPS)", dev.device_kind)
+            return None
     return per_device * max(int(num_devices), 1)
 
 
@@ -195,8 +235,6 @@ class MFUAccountant:
     def _compiled_flops(jitted, args):
         try:
             cost = jitted.lower(*args).compile().cost_analysis()
-            if isinstance(cost, (list, tuple)):  # per-device list on old jax
-                cost = cost[0] if cost else {}
             flops = float(cost.get("flops", 0.0))
             return flops or None
         except Exception:
@@ -237,7 +275,7 @@ class MFUAccountant:
                   "mean_step_seconds": mean_step, "goodput_pct": goodput,
                   "badput": badput, "mfu_pct": None,
                   "flops_per_step": self.flops_per_step}
-        if self.flops_per_step and steps:
+        if self.flops_per_step and steps and self.peak_flops:
             achieved = self.flops_per_step * steps / wall
             report["achieved_flops_per_sec"] = achieved
             report["mfu_pct"] = 100.0 * achieved / self.peak_flops
@@ -249,8 +287,8 @@ class MFUAccountant:
                 report["mfu_pct"], self.flops_per_step / 1e9,
                 mean_step * 1e3, self.peak_flops / 1e9, self.num_devices)
         else:
-            logger.info("Epoch[%d] MFU: n/a (FLOPs/step unresolved; "
-                        "%.2f ms/step)", epoch, mean_step * 1e3)
+            logger.info("Epoch[%d] MFU: n/a (FLOPs/step or device peak "
+                        "unresolved; %.2f ms/step)", epoch, mean_step * 1e3)
         h.gauge("goodput_pct", goodput)
         for reason, seconds in badput.items():
             if seconds <= 0:

@@ -555,13 +555,18 @@ _MEASURED_BW = {}
 
 
 def measured_peak_bandwidth(n_mb=32, iters=4):
-    """One-time measured memory bandwidth (bytes/s per device) on the
-    default backend — the roofline ridge's denominator (cached per
-    platform; the honest CPU-rig counterpart of mfu.measured_peak_flops)."""
+    """Memory bandwidth (bytes/s per device) of the default backend — the
+    roofline ridge's denominator: the published figure for a device kind
+    in ``mfu.DEVICE_PEAKS``, else a one-time measurement (cached per
+    platform; the CPU-rig counterpart of mfu.measured_peak_flops)."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
 
+    from .mfu import DEVICE_PEAKS
+
+    published = DEVICE_PEAKS.get(jax.devices()[0].device_kind)
+    if published is not None:
+        return published["hbm_bytes_per_s"]
     platform = jax.default_backend()
     if platform in _MEASURED_BW:
         return _MEASURED_BW[platform]

@@ -10,12 +10,14 @@ triggers a fresh compile mid-epoch. The reference design's answer was the
 per-shape cached-executor model (SURVEY §1: GraphExecutor "cached engine
 ops"); the TPU-native answer is four cooperating pieces:
 
-  1. **Persistent compilation cache** — ``configure_persistent_cache`` wires
-     ``jax_compilation_cache_dir`` so warm process starts deserialize
-     executables from disk instead of re-running XLA. Opt-in via the
-     ``MXNET_TPU_COMPILE_CACHE`` env var (a path, or ``1`` for the default
-     user-cache location) or the API; off by default so tests and one-shot
-     scripts never surprise-write to disk.
+  1. **Persistent compilation cache** — ``configure_persistent_cache``,
+     called once at package import, so warm process starts deserialize
+     executables from disk instead of re-running XLA. The directory is
+     placed from outside: where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
+     itself uses it and this code sets nothing; otherwise the cache lives
+     at a fixed path inside the checkout, ``.jax_cache`` (the path is part
+     of JAX's cache key, so it never moves). ``MXNET_TPU_COMPILE_CACHE=0``
+     turns the in-checkout cache off.
 
   2. **Program registry** — every jit program the framework dispatches goes
      through :func:`tracked_jit`, which attributes cache hits/misses,
@@ -60,8 +62,8 @@ from ..analysis.lockwatch import named_lock
 from ..base import MXNetError
 
 __all__ = [
-    "configure_persistent_cache", "maybe_enable_persistent_cache_from_env",
-    "persistent_cache_dir", "DEFAULT_CACHE_DIR",
+    "configure_persistent_cache", "persistent_cache_dir",
+    "CHECKOUT_CACHE_DIR",
     "ProgramRegistry", "registry", "compile_stats", "reset_compile_stats",
     "tracked_jit", "TrackedJit", "graph_fingerprint",
     "RecompileTracker", "RecompileError",
@@ -73,64 +75,41 @@ __all__ = [
 
 # -- 1. persistent on-disk XLA compilation cache -------------------------------
 
-DEFAULT_CACHE_DIR = os.path.join(
-    os.path.expanduser("~"), ".cache", "mxnet_tpu", "xla_cache")
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _OFF_VALUES = ("", "0", "off", "false", "no")
 _ON_VALUES = ("1", "on", "true", "yes")
 
-_cache_state = {"dir": None}
 
+def configure_persistent_cache():
+    """Resolve where JAX's persistent compilation cache lives; the package
+    calls this once at import. Returns the active directory or None.
 
-def configure_persistent_cache(cache_dir=None, min_compile_seconds=None):
-    """Enable JAX's persistent compilation cache at ``cache_dir``.
-
-    ``cache_dir=None`` resolves ``MXNET_TPU_COMPILE_CACHE`` (a path, or a
-    truthy value for :data:`DEFAULT_CACHE_DIR`; unset/falsy leaves the cache
-    off and returns None). ``min_compile_seconds`` sets
-    ``jax_persistent_cache_min_compile_time_secs`` — programs cheaper than
-    this are not worth the disk round-trip (env override:
-    ``MXNET_TPU_COMPILE_CACHE_MIN_SEC``, default 0.5).
-
-    Safe defaults: nothing is written unless explicitly asked for, the
-    directory is created if missing, and an unsupported jax build degrades
-    to a warning instead of an import failure. Returns the active cache
-    directory, or None when disabled/unavailable.
+    ``JAX_COMPILATION_CACHE_DIR`` in the environment wins outright: JAX
+    read it on its own at import, so nothing is set in code (nor its
+    thresholds — the ``JAX_PERSISTENT_CACHE_*`` variables stay in charge).
+    Otherwise the cache is :data:`CHECKOUT_CACHE_DIR` unless
+    ``MXNET_TPU_COMPILE_CACHE`` is falsy; ``MXNET_TPU_COMPILE_CACHE_MIN_SEC``
+    (default 0.5) keeps programs cheaper to compile than to read back out
+    of it.
     """
-    if cache_dir is None:
-        raw = os.environ.get("MXNET_TPU_COMPILE_CACHE", "")
-        if raw.strip().lower() in _OFF_VALUES:
-            return None
-        cache_dir = DEFAULT_CACHE_DIR if raw.strip().lower() in _ON_VALUES \
-            else raw
-    cache_dir = os.path.expanduser(cache_dir)
-    if min_compile_seconds is None:
-        min_compile_seconds = float(
-            os.environ.get("MXNET_TPU_COMPILE_CACHE_MIN_SEC", "0.5"))
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_seconds))
-    except Exception as e:  # pragma: no cover - old jax / read-only fs
-        logging.warning("persistent compilation cache unavailable: %s", e)
-        return None
-    _cache_state["dir"] = cache_dir
-    return cache_dir
-
-
-def maybe_enable_persistent_cache_from_env():
-    """Import-time hook: enable the cache iff MXNET_TPU_COMPILE_CACHE asks
-    for it (the package calls this once; explicit API calls still work)."""
-    if os.environ.get("MXNET_TPU_COMPILE_CACHE", "").strip().lower() \
-            not in _OFF_VALUES:
-        return configure_persistent_cache()
-    return None
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR") and os.environ.get(
+            "MXNET_TPU_COMPILE_CACHE", "1").strip().lower() not in _OFF_VALUES:
+        # JAX creates the directory on its first write, and only warns
+        # when it cannot (a read-only install runs without a cache)
+        jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs",
+            float(os.environ.get("MXNET_TPU_COMPILE_CACHE_MIN_SEC", "0.5")))
+    return persistent_cache_dir()
 
 
 def persistent_cache_dir():
-    """The active persistent-cache directory, or None when disabled."""
-    return _cache_state["dir"]
+    """The directory JAX's persistent cache uses, or None when it is off:
+    JAX's own setting, whoever set it."""
+    return jax.config.jax_compilation_cache_dir
 
 
 # -- 2. program registry -------------------------------------------------------
@@ -329,17 +308,13 @@ def _install_listeners(reg):
     global _LISTENERS_INSTALLED
     if _LISTENERS_INSTALLED:
         return
-    try:
-        from jax import monitoring
+    from jax import monitoring
 
-        monitoring.register_event_duration_secs_listener(
-            lambda name, secs, **kw: reg._on_duration(name, secs))
-        monitoring.register_event_listener(
-            lambda name, **kw: reg._on_event(name))
-        _LISTENERS_INSTALLED = True
-    except Exception as e:  # pragma: no cover - monitoring API drift
-        logging.warning("jax.monitoring unavailable; compile-seconds "
-                        "attribution disabled: %s", e)
+    monitoring.register_event_duration_secs_listener(
+        lambda name, secs, **kw: reg._on_duration(name, secs))
+    monitoring.register_event_listener(
+        lambda name, **kw: reg._on_event(name))
+    _LISTENERS_INSTALLED = True
 
 
 def registry() -> ProgramRegistry:
@@ -419,10 +394,7 @@ class TrackedJit:
         return (treedef, tuple(_leaf_spec(leaf) for leaf in flat))
 
     def _cache_size(self):
-        try:
-            return self._jitted._cache_size()
-        except Exception:  # pragma: no cover - private API drift
-            return None
+        return self._jitted._cache_size()
 
     def __call__(self, *args, **kwargs):
         reg = self._registry
@@ -454,7 +426,6 @@ class TrackedJit:
                         reg.record_call(self.label, "aot_hit")
                         return out
         before = self._cache_size()
-        compiles_before = reg.compiles_for(self.label)
         with reg.attribute(self.label):
             t0 = time.perf_counter()
             out = self._jitted(*args, **kwargs)
@@ -462,12 +433,7 @@ class TrackedJit:
             # of the dispatch (trace + compile on a miss), which is
             # synchronous — execution time is the profiler's job
             dt = time.perf_counter() - t0  # mxlint: disable=MX306
-        after = self._cache_size()
-        if before is not None and after is not None:
-            missed = after > before
-        else:  # private cache introspection gone: fall back to events
-            missed = reg.compiles_for(self.label) > compiles_before
-        if missed:
+        if self._cache_size() > before:
             reg.record_call(self.label, "miss", seconds=dt,
                             signature=self.signature(args, kwargs))
         else:

@@ -1,4 +1,4 @@
-"""Extended augmenter flags (VERDICT r3 item 6): rotate / rotate_list,
+"""Extended augmenter flags: rotate / rotate_list,
 min/max_random_scale, min/max_img_size, max_random_contrast,
 max_random_illumination, fixed mirror — in both the PIL and native paths.
 

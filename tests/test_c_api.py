@@ -29,11 +29,11 @@ NDHandle = ctypes.c_void_p
 
 @pytest.fixture(scope="module")
 def lib():
-    if not os.path.exists(_SO):
-        r = subprocess.run(["make", "-C", _DIR, "capi", "-s"],
-                           capture_output=True, text=True, timeout=300)
-        if not os.path.exists(_SO):
-            pytest.skip(f"cannot build libmxtpu_capi.so: {r.stderr[-400:]}")
+    # always through make (a no-op on a fresh build): never a leftover .so
+    r = subprocess.run(["make", "-C", _DIR, "capi", "-s"],
+                       capture_output=True, text=True, timeout=300)
+    if r.returncode != 0 or not os.path.exists(_SO):
+        pytest.skip(f"cannot build libmxtpu_capi.so: {r.stderr[-400:]}")
     lib = ctypes.CDLL(_SO)
     lib.MXGetLastError.restype = ctypes.c_char_p
     return lib

@@ -445,8 +445,8 @@ print(json.dumps({"cache_dir": cm.persistent_cache_dir(),
 def test_persistent_cache_reused_across_subprocess(tmp_path):
     cache = str(tmp_path / "xla_cache")
     env = {**os.environ, "JAX_PLATFORMS": "cpu",
-           "MXNET_TPU_COMPILE_CACHE": cache,
-           "MXNET_TPU_COMPILE_CACHE_MIN_SEC": "0"}
+           "JAX_COMPILATION_CACHE_DIR": cache,
+           "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"}
 
     def run():
         r = subprocess.run([sys.executable, "-c", _CHILD], env=env,
@@ -455,14 +455,63 @@ def test_persistent_cache_reused_across_subprocess(tmp_path):
         assert r.returncode == 0, r.stdout + r.stderr
         return json.loads(r.stdout.strip().splitlines()[-1])
 
+    in_checkout = os.path.join(REPO, ".jax_cache")
+    listing = lambda: sorted(os.listdir(in_checkout)) \
+        if os.path.isdir(in_checkout) else None  # noqa: E731
+    before = listing()
     cold = run()
-    assert cold["cache_dir"] == cache  # env wiring reached jax config
+    assert cold["cache_dir"] == cache  # the resolver reports JAX's own dir
+    assert listing() == before  # ...and nothing lands in the checkout's
     entries = [f for f in os.listdir(cache) if f.endswith("-cache")]
     assert entries, "cold run wrote nothing to the persistent cache"
     warm = run()
     # the warm process deserialized executables instead of compiling
     assert warm["persistent_hits"] > 0
     assert warm["persistent_hits"] >= cold["persistent_hits"]
+
+
+def test_cache_resolver_env_wins_else_checkout(monkeypatch):
+    """One rule for where the persistent cache lives: JAX's own
+    JAX_COMPILATION_CACHE_DIR when set (and then nothing is set in code),
+    else <checkout>/.jax_cache; MXNET_TPU_COMPILE_CACHE=0 turns the
+    in-checkout cache off."""
+    import jax
+
+    from mxnet_tpu.utils import compile as cm
+
+    updates = []
+    real_update = jax.config.update
+
+    def spy(key, value):
+        updates.append(key)
+        real_update(key, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+    # conftest's half of the rule: tier-1 runs with no cache directory, so
+    # no compile-count assertion can depend on an earlier run
+    assert cm.persistent_cache_dir() is None
+    assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
+    try:
+        # set from outside: JAX owns it (it reads the variable at import —
+        # the subprocess test below sees persistent_cache_dir() report it)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        cm.configure_persistent_cache()
+        assert updates == []
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        monkeypatch.setenv("MXNET_TPU_COMPILE_CACHE", "0")
+        assert cm.configure_persistent_cache() is None
+        assert updates == []
+
+        monkeypatch.delenv("MXNET_TPU_COMPILE_CACHE")
+        in_checkout = os.path.join(REPO, ".jax_cache")
+        assert cm.configure_persistent_cache() == in_checkout
+        assert cm.persistent_cache_dir() == in_checkout
+        assert "jax_compilation_cache_dir" in updates
+    finally:
+        real_update("jax_compilation_cache_dir", None)
+        real_update("jax_persistent_cache_min_compile_time_secs", min_secs)
 
 
 def test_masked_device_metrics_multi_position_labels():

@@ -52,20 +52,20 @@ def test_walk_found_the_tree():
 
 
 def test_shard_map_compat_shim():
-    """compat.shard_map accepts either spelling of the replication flag
-    and resolves on the installed JAX."""
+    """compat.shard_map is the installed JAX's own shard_map (one pinned
+    version, no translation layer)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from mxnet_tpu.compat import JAX_VERSION, shard_map
+    from mxnet_tpu.compat import shard_map
     from mxnet_tpu.parallel import make_mesh
 
-    assert isinstance(JAX_VERSION, tuple) and JAX_VERSION >= (0, 4)
+    assert shard_map is jax.shard_map
     mesh = make_mesh(dp=8)
     x = np.arange(8, dtype=np.float32).reshape(8, 1)
-    for flag in ({"check_vma": False}, {"check_rep": False}, {}):
+    for flag in ({"check_vma": False}, {}):
         out = shard_map(lambda v: v * 2, mesh=mesh, in_specs=P("dp"),
                         out_specs=P("dp"), **flag)(x)
         np.testing.assert_allclose(np.asarray(out), x * 2)

@@ -211,7 +211,7 @@ def test_dist_async_mlp_2proc():
 
 
 def test_dist_async_wire_throughput_single_process():
-    """Transport characterization (VERDICT r2 item 5): the raw-buffer frame
+    """Transport characterization: the raw-buffer frame
     path must move tensor payloads at memory-ish speed through the loopback
     parameter host — the old pickled-float wire measured ~10x slower. Loose
     bound so CI never flakes: >= 50 MB/s sustained push_pull of a 16 MB

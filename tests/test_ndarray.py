@@ -211,6 +211,22 @@ def test_ndarray_context():
     assert a.context.device_id == 1
 
 
+def test_accelerator_context_never_resolves_to_cpu():
+    """Without an accelerator mx.tpu()/mx.gpu() raise on use instead of
+    landing on a host device; so does a device_id past the last chip
+    (which used to alias onto chip id % n)."""
+    import jax
+
+    assert all(d.platform == "cpu" for d in jax.local_devices())
+    for make in (mx.tpu, mx.gpu):
+        for device_id in (0, 5):
+            with pytest.raises(mx.MXNetError, match="accelerator"):
+                make(device_id).jax_device
+    with pytest.raises(mx.MXNetError, match="no accelerator"):
+        mx.num_devices("tpu")
+    assert mx.num_devices("cpu") == len(jax.local_devices())
+
+
 def test_ndarray_asscalar_wait():
     a = mx.nd.ones((1,))
     assert float(a) == 1.0

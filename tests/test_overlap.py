@@ -1,4 +1,4 @@
-"""Feed/compute overlap in FeedForward.fit (VERDICT r3 item 3).
+"""Feed/compute overlap in FeedForward.fit.
 
 The trainer must hide host-side batch production (decode + transfer) under
 the device's step: an io-fed epoch costs ~max(feed, compute) per batch, not

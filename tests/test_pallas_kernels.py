@@ -92,13 +92,15 @@ def test_flash_attention_uses_shared_gate():
 
 # -- fused comm kernels: bitwise wire parity -----------------------------------
 
-@pytest.mark.parametrize("mode", ["int8", "twobit"])
-def test_fused_quantize_bitwise_wire_parity(mode):
+@pytest.mark.parametrize("mode,length", [("int8", 2048), ("twobit", 2048),
+                                         ("twobit", 1028)])
+def test_fused_quantize_bitwise_wire_parity(mode, length):
     """ACCEPTANCE: kernel payload == reference codec payload, bit for
-    bit, for every wire array AND the error-feedback round-trip."""
+    bit, for every wire array AND the error-feedback round-trip. 1028 is
+    a twobit row the 512-lane kernel view has to pad."""
     spec = comm.CompressionSpec(mode, chunk=256)
     rng = np.random.RandomState(0)
-    rows = jnp.asarray(rng.randn(8, 2048).astype(np.float32))
+    rows = jnp.asarray(rng.randn(8, length).astype(np.float32))
 
     @jax.jit
     def both(x):
@@ -134,13 +136,15 @@ def test_fused_quantize_1d_and_block_picking():
     assert pay["q"].shape == ref["q"].shape == (64,)
     assert pay["scale"].shape == ref["scale"].shape == (16,)
     assert dq.shape == (64,)
-    # block picking: divides, unit-multiple, capped
-    assert ck.pick_block(2048, 256, 512) == 512
-    assert ck.pick_block(2048, 256, 700) == 512
-    assert ck.pick_block(1280, 256, 512) == 256
-    assert ck.pick_block(12, 4, 8) == 4
+    assert (np.asarray(pay["q"]) == np.asarray(ref["q"])).all()
+    # block picking: whole rows under the cap, in 32-row (8-bit tile)
+    # steps, never below one tile; everything in one block when it fits
+    assert ck.rows_per_block(1000, 256, 65536) == 256
+    assert ck.rows_per_block(1000, 256, 70 * 256) == 64
+    assert ck.rows_per_block(1000, 256, 512) == 32
+    assert ck.rows_per_block(20, 256, 512) == 20
     with pytest.raises(mx.base.MXNetError):
-        ck.pick_block(10, 4)
+        ck.fused_quantize(spec, jnp.zeros((10,), jnp.float32))
 
 
 def test_exchange_kernel_path_hlo_and_values():

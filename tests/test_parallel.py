@@ -131,6 +131,28 @@ def test_transformer_multi_axis_train_step():
     assert losses[-1] < losses[0]
 
 
+def test_explicit_flash_raises_on_uneven_mesh(caplog):
+    """attn_impl='flash' under a mesh the batch/heads do not split over
+    raises (it used to fall to the dense path without a word); 'auto'
+    may choose dense, and says so once."""
+    cfg = transformer_lm_config(vocab_size=32, d_model=16, n_heads=2,
+                                n_layers=2, max_len=16, dtype=jnp.float32,
+                                attn_impl="flash")
+    tokens = np.zeros((3, 16), np.int32)  # batch 3 over dp=2
+    mesh = par.make_mesh(dp=2, tp=4)      # 2 heads over tp=4
+    model = TransformerLM(cfg)
+    params = model.init_params(jax.random.PRNGKey(0))
+    with pytest.raises(mx.MXNetError, match="attn_impl='flash'"):
+        jax.eval_shape(lambda p: model.forward(p, tokens, mesh=mesh), params)
+
+    auto = TransformerLM(dict(cfg, attn_impl="auto"))
+    auto._use_flash = lambda: True        # what 'auto' resolves to on a TPU
+    with caplog.at_level("WARNING"):
+        jax.eval_shape(lambda p: auto.forward(p, tokens, mesh=mesh), params)
+    notices = [r for r in caplog.records if "dense attention" in r.message]
+    assert len(notices) == 1              # once, not once per layer
+
+
 def test_column_row_parallel_numerics():
     x = np.random.RandomState(0).randn(4, 8).astype(np.float32)
     w1 = np.random.RandomState(1).randn(8, 16).astype(np.float32)

@@ -392,7 +392,7 @@ def test_r_train_demo_under_rscript(train_shim):
 
 
 # ---------------------------------------------------------------------------
-# Round-5 widening (VERDICT r4 item 6): checkpoint save/load through the
+# Round-5 widening: checkpoint save/load through the
 # shim (format parity with Python), kvstore surface, and the registered-
 # function route the R optimizer layer uses — each driven with the exact
 # .C pointer convention the new R files (model.R/kvstore.R/optimizer.R)

@@ -1,4 +1,4 @@
-"""R-source lint tier (VERDICT r4: the image ships no R interpreter, so the
+"""R-source lint tier (the image ships no R interpreter, so the
 .R layer needs at least a syntax/contract pass in CI).
 
 Three checks over every .R file in R-package/R/, demo/, tests/, and

@@ -292,6 +292,35 @@ def test_mfu_epoch_report_arithmetic(caplog):
     assert gauges["goodput_pct"] == pytest.approx(rep["goodput_pct"])
 
 
+def test_peak_comes_from_the_device_kind_table_or_not_at_all(monkeypatch):
+    """One table keyed by device_kind; an accelerator that is not in it
+    has no peak and MFU is reported unavailable, never guessed. The
+    measured probe is the CPU rigs' denominator only."""
+    import types
+
+    from mxnet_tpu.telemetry import mfu
+
+    v5e = types.SimpleNamespace(device_kind="TPU v5 lite", platform="tpu")
+    other = types.SimpleNamespace(device_kind="TPU v9 mega", platform="tpu")
+    assert mfu.device_peak_flops(v5e) == 197e12
+    assert mfu.device_peak_flops(other) is None
+
+    monkeypatch.delenv("MXNET_TPU_PEAK_FLOPS", raising=False)
+    monkeypatch.setattr(mfu, "measured_peak_flops",
+                        lambda: pytest.fail("probe used on an accelerator"))
+    import jax
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [v5e])
+    assert mfu.resolve_peak_flops(4) == 4 * 197e12
+    monkeypatch.setattr(jax, "devices", lambda *a: [other])
+    assert mfu.resolve_peak_flops(4) is None
+    acct = telemetry.MFUAccountant(num_devices=4)
+    acct.flops_per_step = 1e6
+    assert acct.epoch_report(0, steps=10, wall_seconds=1.0)["mfu_pct"] is None
+    monkeypatch.setenv("MXNET_TPU_PEAK_FLOPS", "2e12")
+    assert mfu.resolve_peak_flops(4) == 8e12
+
+
 # -- Speedometer warm-up skew fix ---------------------------------------------
 
 def test_speedometer_skips_compile_polluted_window(caplog):

@@ -1,0 +1,120 @@
+"""Every registered Pallas kernel compiles for the chip — checked without one.
+
+A compile-only ``v5e:2x2`` topology (no TPU attached: nothing executes, so
+this cannot take the real chip on a chip host either) is the target of an
+AOT ``jit(...).lower(...).compile()`` per kernel at one representative
+shape. The executable must contain a Mosaic custom call: a kernel the TPU
+lowering refuses (block shapes off the (8, 128) tile, an unsupported
+matmul mode) fails here, in the sandbox, instead of on the first chip run.
+``chip_smoke.py`` is the other half: the same kernels, executed on the
+chip, against their references.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import mxnet_tpu as mx
+from mxnet_tpu import comm
+from mxnet_tpu.ops import pallas as pk
+from mxnet_tpu.ops.pallas import comm_kernels as ck
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu in this environment
+        reason = f"compile-only v5e:2x2 topology unavailable: {e!r}"
+        print(reason)
+        pytest.skip(reason)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _mosaic_kernels(fn, *args):
+    """AOT-compile and return the names of the Mosaic kernels inside."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    return {name for name in pk.kernel_names() if name in text}
+
+
+def _adam_case(S):
+    opt = mx.optimizer.create("adam", learning_rate=1e-3, wd=1e-4)
+    shapes = {"w": (300, 100), "b": (100,), "s": ()}
+    p = {k: S(s) for k, s in shapes.items()}
+    st = {k: (S(s), S(s), S(())) for k, s in shapes.items()}
+    return (lambda p, g, s: pk.fused_adam_apply(opt, p, g, s, 1e-3,
+                                                interpret=False)), (p, p, st)
+
+
+def _flash_case(S):
+    q = S((1, 2, 2048, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        o = pk.flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    return jax.grad(loss, argnums=(0, 1, 2)), (q, q, q)
+
+
+def _quant_case(mode):
+    def build(S):
+        spec = comm.CompressionSpec(mode)
+        return (lambda x: ck.fused_quantize(spec, x, want_dequant=True,
+                                            interpret=False)), \
+            (S((4, 1 << 16)),)
+    return build
+
+
+def _dequant_case(mode, fn):
+    def build(S):
+        spec = comm.CompressionSpec(mode)
+        length = 1 << 16
+        pay = {"q": S((4, length), jnp.int8),
+               "scale": S((4, length // spec.chunk))} if mode == "int8" \
+            else {"q": S((4, length // 4), jnp.uint8)}
+        return (lambda p: fn(spec, p, interpret=False)), (pay,)
+    return build
+
+
+def _int8_mm_case(S):
+    return (lambda x, w: pk.int8_matmul(x, w, interpret=False)), \
+        (S((256, 2048)), S((1000, 2048)))
+
+
+CASES = {
+    "flash": (_flash_case, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    "fused_adam": (_adam_case, {"fused_adam"}),
+    "quant_int8": (_quant_case("int8"), {"quant_int8"}),
+    "quant_twobit": (_quant_case("twobit"), {"quant_twobit"}),
+    "dequant_int8": (_dequant_case("int8", ck.fused_dequant),
+                     {"dequant_int8"}),
+    "dequant_twobit": (_dequant_case("twobit", ck.fused_dequant),
+                       {"dequant_twobit"}),
+    "dequant_sum_int8": (_dequant_case("int8", ck.fused_dequant_sum),
+                         {"dequant_sum_int8"}),
+    "dequant_sum_twobit": (_dequant_case("twobit", ck.fused_dequant_sum),
+                           {"dequant_sum_twobit"}),
+    "int8_matmul": (_int8_mm_case, {"int8_matmul"}),
+}
+
+
+def test_cases_cover_the_registry():
+    covered = set().union(*(names for _, names in CASES.values()))
+    assert covered == set(pk.kernel_names())
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(v5e, case):
+    build, names = CASES[case]
+
+    def S(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    fn, args = build(S)
+    found = _mosaic_kernels(fn, *args)
+    assert names <= found, (case, names - found)

@@ -120,7 +120,7 @@ def _lenet_rgb(size):
 
 @pytest.mark.slow
 def test_lenet_augmented_pipeline_accuracy_parity():
-    """Augmentation tier (VERDICT r2 item 8): LeNet through the FULL
+    """Augmentation tier: LeNet through the FULL
     ImageRecordIter pipeline (JPEG shards, rand-crop jitter + mirror) must
     train to accuracy parity (+-2%) with the unaugmented center-crop run.
     Digits survive mirroring poorly in principle, but the val protocol is

@@ -1,7 +1,7 @@
 """Native input-pipeline decode scaling characterization (CPU-only).
 
 Substantiates the claim "the native ImageRecordIter pipeline scales with
-decode worker threads" (BENCH_NOTES_r02.md) with measurements rather than
+decode worker threads" with measurements rather than
 assertion. Reference anchor: the original's OpenMP decode
 (src/io/iter_image_recordio.cc:187) and its 3,000 img/s HDD figure
 (example/imagenet/README.md:5).
@@ -23,7 +23,7 @@ measured directly. What CAN be measured honestly:
 4. an Amdahl projection for an 8-core host: serial term from (2)'s
    skip-work floor, parallel term = the rest.
 
-Writes io_scaling JSON lines and a summary (pasted into BENCH_NOTES_r03.md).
+Writes io_scaling JSON lines and a summary.
 """
 
 from __future__ import annotations

@@ -4,8 +4,12 @@
 processes on localhost).
 
 TPU-native version: spawns N worker processes wired together through
-``jax.distributed`` (coordinator on localhost), each seeing a slice of the
-CPU devices — the single-machine stand-in for a multi-host TPU job. Server
+``jax.distributed`` (coordinator on localhost) — the single-machine,
+CPU-only stand-in for a multi-host job. Every child runs with
+JAX_PLATFORMS=cpu: a TPU chip belongs to one process, so N workers that
+each opened the host's chips would all but one die. To train on several
+chips of one host use ONE process with ``ctx=[mx.tpu(i) for i in
+range(n)]`` (the dp mesh in FeedForward.fit), not this launcher. Server
 processes (-s) are accepted for reference-script compatibility and launched
 with DMLC_ROLE=server, where mxnet_tpu.kvstore_server retires them
 immediately (no server role under sync allreduce).
@@ -33,7 +37,9 @@ def _free_port() -> int:
 
 
 def main():
-    ap = argparse.ArgumentParser(description=__doc__)
+    ap = argparse.ArgumentParser(
+        description=__doc__,
+        formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("-n", "--num-workers", type=int, default=1)
     ap.add_argument("-s", "--num-servers", type=int, default=0)
     ap.add_argument("command", nargs=argparse.REMAINDER)
@@ -53,6 +59,7 @@ def main():
     def env_for(role, rank):
         env = dict(os.environ)
         env.update({
+            "JAX_PLATFORMS": "cpu",  # see module docstring: never the chips
             "MXTPU_NUM_WORKERS": str(args.num_workers),
             "MXTPU_COORDINATOR": coordinator,
             "MXTPU_ASYNC_PORT": str(async_port),
