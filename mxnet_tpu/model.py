@@ -32,6 +32,8 @@ most — reference call stack in SURVEY.md §3.1):
 from __future__ import annotations
 
 import contextlib
+import functools
+import itertools
 import logging
 import os
 import sys
@@ -218,7 +220,8 @@ class _AsyncDeviceFeed:
 
     _SENTINEL = object()
 
-    def __init__(self, data_iter, extract, place, depth=2, snapshot=None):
+    def __init__(self, data_iter, extract, place, depth=2, snapshot=None,
+                 epoch=None):
         import queue
         import threading
 
@@ -227,20 +230,38 @@ class _AsyncDeviceFeed:
         self._closed = False
 
         def worker():
+            # spans of the feed thread (attrs: the epoch being fed and the
+            # batch's ordinal in it): feed.produce is the iterator's next(),
+            # feed.place the extraction and the dispatch of the transfer,
+            # feed.queue_full the put, which blocks while the consumer has
+            # ``depth`` batches in hand: the healthy state
             try:
-                for batch in data_iter:
-                    # place() dispatches the async device_put; the consumer
-                    # gets arrays whose transfer is already in flight
-                    placed = place(extract(batch))
+                batches = iter(data_iter)
+                for step in itertools.count():
+                    with telemetry_mod.phase("feed.produce", epoch=epoch,
+                                             step=step):
+                        try:
+                            batch = next(batches)
+                        except StopIteration:
+                            break
+                    with telemetry_mod.phase("feed.place", epoch=epoch,
+                                             step=step) as span:
+                        arrays = extract(batch)
+                        span.attrs["bytes"] = _host_nbytes(arrays)
+                        # place() dispatches the async device_put; the
+                        # consumer gets arrays whose transfer is in flight
+                        placed = place(arrays)
                     if snapshot is not None:
                         batch = snapshot(batch)
                     item = (batch, placed)
-                    while not self._closed:
-                        try:
-                            self._q.put(item, timeout=0.2)
-                            break
-                        except queue.Full:
-                            continue
+                    with telemetry_mod.phase("feed.queue_full", epoch=epoch,
+                                             step=step):
+                        while not self._closed:
+                            try:
+                                self._q.put(item, timeout=0.2)
+                                break
+                            except queue.Full:
+                                continue
                     if self._closed:
                         return
             except BaseException as e:  # noqa: BLE001 - re-raised on main
@@ -321,18 +342,51 @@ def _snapshot_batch(batch):
     return _FeedBatchView(batch, label)
 
 
-def _timed_feed(feed, tl):
-    """Wrap the device feed so time blocked waiting for the next batch is
-    banked on the timeline as the following step's ``data_wait`` phase."""
+def _host_nbytes(arrays):
+    """Bytes of the values of ``arrays`` that are in host memory (numpy, or
+    a jax.Array on a CPU device) when ``place`` gets them: what it has to
+    move. An array already on an accelerator counts 0."""
+    total = 0
+    for v in arrays.values():
+        if isinstance(v, jax.Array):
+            if all(d.platform == "cpu" for d in v.devices()):
+                total += v.nbytes
+        else:
+            total += getattr(v, "nbytes", 0)
+    return total
+
+
+def _feed_waits(feed, epoch, tl):
+    """The feed, with each blocking wait for the next ``(batch, arrays)`` as
+    a ``fit.feed_wait`` span carrying the ``step`` it feeds (the last one of
+    an epoch waits for the feed's end and feeds none). With a timeline the
+    wait is also banked as the following step's ``data_wait`` phase."""
     it = iter(feed)
-    while True:
-        t0 = tl.clock()
-        try:
-            item = next(it)
-        except StopIteration:
-            return
-        tl.note_data_wait(tl.clock() - t0)
+    for step in itertools.count():
+        with telemetry_mod.phase("fit.feed_wait", epoch=epoch,
+                                 step=step) as wait:
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        if tl is not None:
+            tl.note_data_wait(wait.end_ts - wait.start)
         yield item
+
+
+def _spans_fit_start(fit):
+    """``fit.start`` runs from ``fit``'s entry to its first epoch. It is
+    opened around the call, so that every way out of ``fit``'s set-up closes
+    it, and ``fit`` ends it at its first ``fit.epoch`` through
+    ``self._fit_start`` (the close at the call's end is then a no-op)."""
+    @functools.wraps(fit)
+    def spanned(self, *args, **kwargs):
+        with telemetry_mod.phase("fit.start") as self._fit_start:
+            try:
+                return fit(self, *args, **kwargs)
+            finally:
+                del self._fit_start
+    return spanned
 
 
 def _create_kvstore(kvstore, num_device, arg_params):
@@ -396,6 +450,7 @@ class FeedForward(BASE_ESTIMATOR):
         state.pop("telemetry", None)
         state.pop("_active_timeline", None)
         state.pop("health_monitor", None)
+        state.pop("_fit_start", None)  # the open span of a fit in flight
         return state
 
     def __setstate__(self, state):
@@ -414,35 +469,40 @@ class FeedForward(BASE_ESTIMATOR):
         accelerator each distinct shape would be its own compile and
         dispatch — ~270 arrays for a ResNet. Parameters upload once, in
         bulk, when the train state is built."""
-        arg_shapes, _, aux_shapes = self.symbol.infer_shape(**input_shapes)
-        arg_names = self.symbol.list_arguments()
-        input_names = set(input_shapes.keys())
-        param_names = [n for n in arg_names if n not in input_names]
-        aux_names = self.symbol.list_auxiliary_states()
-        shape_of = dict(zip(arg_names, arg_shapes))
-        arg_params = dict(self.arg_params or {})
-        aux_params = dict(self.aux_params or {})
-        try:
-            host = jax.local_devices(backend="cpu")[0]
-        except RuntimeError:  # no cpu backend registered
-            host = None
-        scope = jax.default_device(host) if host is not None \
-            else contextlib.nullcontext()
-        with scope:
-            for name in param_names:
-                if name in arg_params and not overwrite:
-                    continue
-                arr = nd.zeros(shape_of[name], cpu())
-                self.initializer(name, arr)
-                arg_params[name] = arr
-            for name, shape in zip(aux_names, aux_shapes):
-                if name in aux_params and not overwrite:
-                    continue
-                arr = nd.zeros(shape, cpu())
-                self.initializer(name, arr)
-                aux_params[name] = arr
-        self.arg_params, self.aux_params = arg_params, aux_params
-        return param_names, aux_names
+        with telemetry_mod.phase("setup.init_params") as span:
+            arg_shapes, _, aux_shapes = self.symbol.infer_shape(**input_shapes)
+            arg_names = self.symbol.list_arguments()
+            input_names = set(input_shapes.keys())
+            param_names = [n for n in arg_names if n not in input_names]
+            aux_names = self.symbol.list_auxiliary_states()
+            shape_of = dict(zip(arg_names, arg_shapes))
+            arg_params = dict(self.arg_params or {})
+            aux_params = dict(self.aux_params or {})
+            try:
+                host = jax.local_devices(backend="cpu")[0]
+            except RuntimeError:  # no cpu backend registered
+                host = None
+            scope = jax.default_device(host) if host is not None \
+                else contextlib.nullcontext()
+            initialised = 0
+            with scope:
+                for name in param_names:
+                    if name in arg_params and not overwrite:
+                        continue
+                    arr = nd.zeros(shape_of[name], cpu())
+                    self.initializer(name, arr)
+                    arg_params[name] = arr
+                    initialised += 1
+                for name, shape in zip(aux_names, aux_shapes):
+                    if name in aux_params and not overwrite:
+                        continue
+                    arr = nd.zeros(shape, cpu())
+                    self.initializer(name, arr)
+                    aux_params[name] = arr
+                    initialised += 1
+            self.arg_params, self.aux_params = arg_params, aux_params
+            span.attrs["arrays"] = initialised
+            return param_names, aux_names
 
     # -- device mesh ----------------------------------------------------------
     def _make_mesh(self, dist: bool):
@@ -1022,6 +1082,7 @@ class FeedForward(BASE_ESTIMATOR):
         return compile_mod.tracked_jit(step, label=label)
 
     # -- fit ------------------------------------------------------------------
+    @_spans_fit_start
     def fit(self, X, y=None, eval_data=None, eval_metric="accuracy",
             epoch_end_callback=None, batch_end_callback=None, kvstore="local",
             logger=None, work_load_list=None, batch_size=128,
@@ -1418,16 +1479,20 @@ class FeedForward(BASE_ESTIMATOR):
         # device-resident training state (f32 master params). dist_async
         # keeps NO worker-side optimizer state: the server owns it
         # (update-on-kvstore), so a momentum tree here would be dead HBM.
-        params = {k: jnp.asarray(self.arg_params[k].asnumpy()) for k in param_names}
-        aux = {k: jnp.asarray(self.aux_params[k].asnumpy()) for k in aux_names}
-        opt_state = {} if async_kv else optimizer.init_state_tree(params)
-        if resume_opt_leaves is not None:
-            # restore momentum/moments: re-thread the saved flat leaves
-            # through this optimizer's state structure
-            flat, treedef = jax.tree_util.tree_flatten(opt_state)
-            if len(flat) == len(resume_opt_leaves):
-                opt_state = jax.tree_util.tree_unflatten(
-                    treedef, [jnp.asarray(leaf) for leaf in resume_opt_leaves])
+        with telemetry_mod.phase("setup.place_state"):
+            params = {k: jnp.asarray(self.arg_params[k].asnumpy())
+                      for k in param_names}
+            aux = {k: jnp.asarray(self.aux_params[k].asnumpy())
+                   for k in aux_names}
+            opt_state = {} if async_kv else optimizer.init_state_tree(params)
+            if resume_opt_leaves is not None:
+                # restore momentum/moments: re-thread the saved flat leaves
+                # through this optimizer's state structure
+                flat, treedef = jax.tree_util.tree_flatten(opt_state)
+                if len(flat) == len(resume_opt_leaves):
+                    opt_state = jax.tree_util.tree_unflatten(
+                        treedef,
+                        [jnp.asarray(leaf) for leaf in resume_opt_leaves])
         # One compiled step per bucket key (None = the single-symbol case);
         # all entries share the same live param/opt-state pytrees. The
         # programs live in self._train_fns so precompile() warms the exact
@@ -1605,8 +1670,9 @@ class FeedForward(BASE_ESTIMATOR):
 
         feed_depth = int(os.environ.get("MXTPU_FEED_PREFETCH", "2"))
 
-        # -- telemetry wiring (tl None = the loop takes the exact
-        # pre-instrumentation path; doc/developer-guide/telemetry.md) ------
+        # -- telemetry wiring (tl None = no timeline, no per-step sync; the
+        # telemetry.phase() spans of the loop are on either way;
+        # doc/developer-guide/telemetry.md) ---------------------------------
         # OOM preflight (ISSUE 9): with a budget configured
         # (MXNET_TPU_HBM_BYTES or the backend's bytes_limit), reject an
         # over-budget configuration NOW — ranked byte report naming the
@@ -1703,10 +1769,15 @@ class FeedForward(BASE_ESTIMATOR):
         def _write_back():
             # write state back so callbacks/checkpoints see current values
             # (device_get: sharded -> host, so predict/save work off-mesh)
-            for k in param_names:
-                self.arg_params[k] = NDArray(_host_local(params[k]))
-            for k in aux_names:
-                self.aux_params[k] = NDArray(_host_local(aux[k]))
+            nbytes = sum(v.nbytes for v in params.values()) \
+                + sum(v.nbytes for v in aux.values())
+            with telemetry_mod.phase("fit.epoch.write_back", epoch=epoch,
+                                     arrays=len(params) + len(aux),
+                                     bytes=nbytes):
+                for k in param_names:
+                    self.arg_params[k] = NDArray(_host_local(params[k]))
+                for k in aux_names:
+                    self.aux_params[k] = NDArray(_host_local(aux[k]))
 
         def _guard_meta():
             if guard_cfg is None:
@@ -2066,516 +2137,610 @@ class FeedForward(BASE_ESTIMATOR):
           final_epoch = self.num_epoch or 1
           epoch = self.begin_epoch
           epoch_tic = None
+          self._fit_start.end()
           while epoch < final_epoch:
-            # the epoch clock survives an elastic redo: on resize the
-            # loop `continue`s without advancing `epoch` or resetting the
-            # clock, so the aborted attempt + downtime price into this
-            # epoch's wall (and its `resize` badput bucket), never into
-            # throughput
-            if epoch_tic is None:
-                epoch_tic = time.time()
-            tic = epoch_tic
-            attempt_tic = time.time()
-            resize_ev = None
-            compile_snap = compile_mod.registry().snapshot()
-            comm_snap = comm_mod.registry().snapshot() \
-                if comm_spec is not None else None
-            host_comm_snap = kv.compression_stats() \
-                if async_comm_spec is not None and \
-                hasattr(kv, "compression_stats") else None
-            epoch_span_base = len(tl.spans) if tl is not None else 0
-            ckpt_base = _ckpt_seconds() if mfu_acct is not None else 0.0
-            retries_base = self.guard_stats["step_retries"] \
-                if guard_cfg is not None else 0
-            skipped_base = self.guard_stats["skipped_steps"] \
-                if guard_cfg is not None else 0
-            eval_metric.reset()
-            maccum = self._DeviceMetricAccum(eval_metric)
-            nbatch = 0
-            train_data.reset()
-            if feed_depth > 0:
-                feed = _AsyncDeviceFeed(train_data, _extract_batch,
-                                        _place_batch, depth=feed_depth,
-                                        snapshot=_snapshot_batch)
-            else:  # MXTPU_FEED_PREFETCH=0: synchronous feed (debugging)
-                feed = ((b, _place_batch(_extract_batch(b)))
-                        for b in train_data)
-            feed_src = _timed_feed(feed, tl) if tl is not None else feed
-            try:
-                for batch, batch_arrays in feed_src:
-                    if skip_batches > 0:
-                        # step-granular resume (ISSUE 17): fast-forward a
-                        # resumed/redone epoch past batches it already
-                        # trained — consume the feed without dispatching,
-                        # without drawing RNG keys and without advancing
-                        # num_update, so the first live batch sees exactly
-                        # the state the checkpointed run saw
-                        skip_batches -= 1
-                        nbatch += 1
-                        continue
-                    if fleet_ctl is not None:
-                        # policy tick (synchronous mode), then any staged
-                        # actuation that must run on the training thread
-                        # (tier re-warm). Evictions/backfills the tick
-                        # issues land in the coordinator and surface
-                        # through the elastic poll right below.
-                        if not fleet_ctl.threaded:
-                            fleet_ctl.tick()
-                        retier_act = fleet_ctl.take_retier()
-                        if retier_act is not None:
-                            _apply_retier(retier_act)
-                    if elastic_co is not None:
-                        # membership poll, once per step: chaos sites,
-                        # heartbeat expiry, then any pending change —
-                        # a hit aborts the attempt (this epoch redoes on
-                        # the new world after the resize below)
-                        elastic_co.chaos_poll()
-                        elastic_co.check_heartbeats()
-                        resize_ev = elastic_co.poll()
-                        if resize_ev is not None:
-                            break
-                    span = tl.begin_step(epoch, nbatch) if tl is not None \
-                        else None
-                    if preempt_handler is not None and \
-                            preempt_mod.preemption_requested():
-                        _preempt_flush()
-                    if watchdog is not None:
-                        watchdog.check()
-                    if span is not None:
-                        # dispatch opens as soon as the batch is in hand:
-                        # program-cache resolution / first-step graph
-                        # build / the one-time FLOP trace are launch-side
-                        # host work, not a data stall
-                        span.mark("dispatch")
-                    bkey = getattr(batch, "bucket_key", None)
-                    b_dnames = getattr(batch, "data_names", data_names)
-                    b_lnames = getattr(batch, "label_names", label_names)
-                    if bkey not in train_steps:
-                        train_steps[bkey] = self._get_train_step(
-                            bkey, b_dnames, b_lnames, optimizer, mesh,
-                            metric=eval_metric if use_device_metric else None,
-                            apply_update=not async_kv,
-                            guard_cfg=guard_cfg, pad_policy=pad_policy,
-                            compression=comm_spec,
-                            overlap_plan=overlap_plan,
-                            comm_kernels=kern_cfg, health_cfg=health_cfg)
-                    train_step = train_steps[bkey]
-                    pad_tail = ()
-                    if pad_policy is not None:
-                        pad_tail = (batch_arrays.pop("__num_valid__"),)
-                    rng = random_mod.next_key()
-                    lr = optimizer._get_lr()
-                    optimizer.num_update = num_update
-                    if mfu_acct is not None and \
-                            mfu_acct.flops_per_step is None and \
-                            getattr(train_step, "_tracked", None) is not None:
-                        # abstract-trace the exact program about to
-                        # dispatch (shapes only, pre-donation) for the
-                        # jaxpr FLOP table behind the MFU line
-                        mfu_acct.maybe_trace(
-                            train_step._tracked._jitted,
-                            (params, opt_state, aux, batch_arrays, rng,
-                             jnp.float32(lr), maccum.state)
-                            + _state_tail() + pad_tail)
-                    if prof_session is not None and prof_session.pending:
-                        # maybe open the capture window (warmup done AND
-                        # last step compile-quiet); the args thunk lets the
-                        # session harvest this exact program's HLO metadata
-                        def _prof_args():
-                            return (params, opt_state, aux, batch_arrays,
-                                    rng, jnp.float32(lr), maccum.state) \
-                                + _state_tail() + pad_tail
-                        prof_session.before_step(
-                            getattr(train_step, "_tracked", None),
-                            _prof_args,
-                            compile_mod.registry().snapshot()["compiles"])
-                    if shard_audit_on and bkey not in _shard_audited:
-                        _shard_audited.add(bkey)
-                        tj = getattr(train_step, "_tracked", None)
-                        if tj is not None:
-                            # warms the exact program about to dispatch
-                            # (TrackedJit AOT) and audits its optimized
-                            # HLO; raises on MX802 before the step runs
-                            self._shard_audit_program(
-                                tj,
-                                (params, opt_state, aux, batch_arrays,
-                                 rng, jnp.float32(lr), maccum.state)
-                                + _state_tail() + pad_tail,
-                                mesh=mesh, comm_spec=comm_spec,
-                                overlap_plan=overlap_plan,
-                                flat_elems=comm_mod.flat_size(params),
-                                logger=logger)
-                    # state tail mirrors the step signature:
-                    # [gstate][cstate][hstate][valid]
-                    hs_tail = () if hstate is None else (hstate,)
-                    if guard_cfg is None:
-                        tail = () if cstate is None else (cstate,)
-                        res = train_step(params, opt_state, aux,
-                                         batch_arrays, rng, lr,
-                                         maccum.state, *tail, *hs_tail,
-                                         *pad_tail)
-                    else:
-                        batch_arrays = self._chaos_step_sites(
-                            batch_arrays, b_dnames, watchdog)
-                        retries = guard_cfg.max_step_retries
-                        while True:
-                            try:
-                                # the injected raise fires BEFORE dispatch,
-                                # so donated buffers are still live on retry
-                                chaos_mod.maybe_raise(
-                                    "step.raise",
-                                    chaos_mod.TransientStepError)
-                                tail = (gstate,) if cstate is None \
-                                    else (gstate, cstate)
-                                res = train_step(
-                                    params, opt_state, aux, batch_arrays,
-                                    rng, lr, maccum.state, *tail, *hs_tail,
-                                    *pad_tail)
-                                break
-                            except chaos_mod.TransientStepError:
-                                if retries <= 0:
-                                    # retry budget exhausted: leave a
-                                    # black box before failing the run
-                                    telemetry_mod.flight.auto_dump(
-                                        "guard_trip")
-                                    raise
-                                retries -= 1
-                                self.guard_stats["step_retries"] += 1
-                                telemetry_mod.counter(
-                                    "resilience_step_retries_total")
-                                if span is not None:
-                                    span.event("step_retry")
-                        if watchdog is not None:
-                            watchdog.beat()
-                    if span is not None:
-                        span.mark("device")
-                        if tcfg.sync:
-                            # exact device phase: wait for the step's
-                            # output buffers (see TelemetryConfig.sync)
-                            jax.block_until_ready(res)
-                        # stale-sync: the kvstore slot becomes "wire" — it
-                        # times only the un-hidden tail of the PREVIOUS
-                        # round's push (the hidden part lands as an
-                        # "overlap" sub-span from push_pull_stale)
-                        span.mark("wire" if stale_sync
-                                  else ("kvstore" if async_kv else "host"))
-                    params, opt_state, aux, outs, maccum.state = res[:5]
-                    idx = 5
-                    if guard_cfg is not None:
-                        gstate = res[idx]
-                        idx += 1
-                    if cstate is not None:
-                        cstate = res[idx]
-                        idx += 1
-                    if hstate is not None:
-                        hstate = res[idx]
-                        if nbatch % health_cfg.every == 0:
-                            # pull the tiny stat vectors + emit the health
-                            # event; the monitor's detectors run inside
-                            # the emit, so any health_anomaly lands in the
-                            # flight ring BEFORE the guard-skip event that
-                            # closes the story
-                            _, h_finite = \
-                                telemetry_mod.health.observe_device_stats(
-                                    health_groups, hstate, epoch, nbatch)
-                            # only a guard that actually skips gets the
-                            # skip event — with skip_nonfinite=False the
-                            # poisoned update was APPLIED, and a post-
-                            # mortem must not read a skip that never ran
-                            if guard_cfg is not None and \
-                                    guard_cfg.skip_nonfinite and \
-                                    not h_finite:
-                                if span is not None:
-                                    span.event("guard_skip")
-                                else:
-                                    telemetry_mod.emit(
-                                        "step_event", span_kind="step",
-                                        epoch=epoch, step=nbatch,
-                                        name="guard_skip")
-                    if prof_session is not None and prof_session.open:
-                        # window accounting: the K-th step blocks on its
-                        # outputs, stops the trace, attributes, publishes;
-                        # the wall time returns as `profile` badput
-                        profile_badput += prof_session.after_step(
-                            res, epoch=epoch)
-                    step_finite = True
-                    if guard_cfg is not None and (async_kv
-                                                  or not use_device_metric):
-                        # these paths sync to host right below anyway; the
-                        # in-jit fast path never reads this flag
-                        step_finite = bool(np.asarray(  # mxlint: disable=MX309
-                            _host_local(gstate["last_finite"])))
-                    if async_kv:
-                        if step_finite and stale_sync:
-                            # pipelined push: THIS step's grads go to the
-                            # parameter host on a background thread while
-                            # the next step computes; the weights returned
-                            # are one round stale (overlap= on dist_async)
-                            pulled = kv.push_pull_stale(
-                                {name: _host_local(params[name])
-                                 for name in param_names})
-                        elif step_finite:
-                            # params slot carries grads (apply_update=False):
-                            # ONE round trip applies them on the host
-                            # (updated on arrival) and returns the fresh
-                            # weights — unbounded-staleness async, like the
-                            # reference's dist_async worker loop
-                            pulled = kv.push_pull(
-                                {name: _host_local(params[name])
-                                 for name in param_names})
-                        elif stale_sync:
-                            # guard tripped: drain the in-flight round, drop
-                            # the bad grads, re-pull current weights
-                            pulled = kv.flush_stale(param_names)
-                        else:
-                            # guard tripped: the grads are non-finite — do
-                            # NOT poison the parameter host; re-pull the
-                            # current weights instead (the params slot holds
-                            # the bad grads and must be replaced either way)
-                            pulled = kv.pull_many(param_names)
-                        params = {k: jnp.asarray(pulled[k])
-                                  for k in param_names}
-                    if span is not None and async_kv:
-                        span.mark("host")
-                    num_update += 1
-                    if use_device_metric:
-                        maccum.after_batch(batch.label)
-                    elif step_finite:
-                        outs_h = [_host_local(o)
-                                  for o in outs[: len(batch.label)]]
-                        labels_h = batch.label
-                        if pad_policy is not None:
-                            # batch.label holds the UNPADDED rows; slice the
-                            # outputs to the valid prefix (wrap-around pad
-                            # rows excluded too — that's the policy's
-                            # metric-correctness contract)
-                            nv = int(labels_h[0].shape[0]) - int(
-                                getattr(batch, "pad", 0) or 0)
-                            outs_h = [o[:nv] for o in outs_h]
-                            # host-metric path: the per-batch pull IS the
-                            # metric contract here (device metrics are the
-                            # sanctioned fast path)
-                            labels_h = [
-                                np.asarray(l.asnumpy()  # mxlint: disable=MX309
-                                           if hasattr(l, "asnumpy") else l)[:nv]
-                                for l in labels_h]
-                        eval_metric.update(labels_h,
-                                           [NDArray(o) for o in outs_h])
-                    nbatch += 1
-                    if ckpt_writer is not None and \
-                            num_update % ckpt_every == 0 and \
-                            num_update != ckpt_last_update:
-                        # cadence hit (ISSUE 17): one blocking host copy,
-                        # then the writer thread owns durability — the
-                        # loop is back on the next batch immediately.
-                        # (guard-skipped steps leave num_update in place:
-                        # the dedup keeps a skipped batch from re-saving
-                        # the same update)
-                        ckpt_last_update = num_update
-                        _ckpt_tick()
-                    if ckpt_writer is not None and \
-                            fleet_ctl is not None:
-                        ckpt_act = fleet_ctl.take_ckpt_cadence()
-                        if ckpt_act is not None:
-                            # controller-staged cadence change: host-side
-                            # counter only, nothing recompiles
-                            ckpt_every = max(1, int(ckpt_act["every"]))
-                            fleet_ctl.ckpt_cadence_applied(ckpt_act)
-                            logger.info("controller: checkpoint cadence "
-                                        "-> every %d step(s)", ckpt_every)
-                    if batch_end_callback is not None:
-                        p = BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                          eval_metric=eval_metric)
-                        for cb in _as_list(batch_end_callback):
-                            cb(p)
-                    if span is not None:
-                        span.end()
-                    else:
-                        # timeline off: the always-on flight recorder still
-                        # gets a step mark (identity + timestamp), so a
-                        # crash dump shows the last K steps either way
-                        telemetry_mod.flight.note_step(epoch, nbatch - 1)
-            finally:
-                if feed_depth > 0:
-                    feed.close()
-            if resize_ev is not None:
-                # elastic resize: quiesce, re-shard, re-plan, re-warm —
-                # then redo this epoch on the new world. Everything the
-                # aborted attempt spent (its steps get redone) plus the
-                # resize downtime is this epoch's `resize` badput.
-                _apply_resize(resize_ev)
-                resize_badput += time.time() - attempt_tic
-                continue
-            if stale_sync:
-                # drain the pipeline at the epoch boundary: the last step's
-                # push must land before callbacks/checkpoints read weights
-                pulled = kv.flush_stale(param_names)
-                params = {k: jnp.asarray(pulled[k]) for k in param_names}
-            if use_device_metric:
-                maccum.finish()
-            # stop the epoch clock only once the last step's buffers are
-            # ready — a returned dispatch is not a finished step (the
-            # un-barriered-timing footgun, mxlint MX306)
-            jax.block_until_ready(jax.tree_util.tree_leaves(params)[:1])
-            if prof_session is not None and prof_session.open:
-                # epoch ended inside the window: the device work above has
-                # retired, so close with what was captured rather than
-                # leaking an open trace into the next epoch
-                profile_badput += prof_session.close(epoch=epoch)
-            name, value = eval_metric.get()
-            logger.info("Epoch[%d] Train-%s=%f", epoch, name, value)
-            logger.info("Epoch[%d] Time cost=%.3f", epoch, time.time() - tic)
-            cdiff = compile_mod.registry().snapshot()
-            if cdiff["compiles"] > compile_snap["compiles"]:
-                # compile activity this epoch (expected in epoch 1 / on a
-                # new bucket; anything later is shape drift — see
-                # RecompileTracker): programs, seconds, cache traffic
-                logger.info(
-                    "Epoch[%d] Compile: %d XLA compile(s), %.2fs "
-                    "(jit hits=%d misses=%d, persistent-cache hits=%d, "
-                    "saved=%.2fs)", epoch,
-                    cdiff["compiles"] - compile_snap["compiles"],
-                    cdiff["compile_seconds"] - compile_snap["compile_seconds"],
-                    cdiff["hits"] - compile_snap["hits"],
-                    cdiff["misses"] - compile_snap["misses"],
-                    cdiff["persistent_cache_hits"]
-                    - compile_snap["persistent_cache_hits"],
-                    cdiff["persistent_cache_saved_seconds"]
-                    - compile_snap["persistent_cache_saved_seconds"])
-            if comm_snap is not None:
-                cdelta = comm_mod.registry().snapshot()
-                steps_d = cdelta["steps"] - comm_snap["steps"]
-                if steps_d:
-                    wire_d = cdelta["wire_bytes"] - comm_snap["wire_bytes"]
-                    fp32_d = (cdelta["fp32_wire_bytes"]
-                              - comm_snap["fp32_wire_bytes"])
-                    logger.info(
-                        "Epoch[%d] Comm: %d sync steps, %.2f MB on the wire "
-                        "(%s; fp32 would be %.2f MB, %.1fx)", epoch,
-                        steps_d, wire_d / 1e6, comm_spec.mode, fp32_d / 1e6,
-                        fp32_d / wire_d if wire_d else float("inf"))
-            if host_comm_snap is not None:
-                hs = kv.compression_stats()
-                sent_d = hs["bytes_encoded"] - host_comm_snap["bytes_encoded"]
-                raw_d = hs["bytes_raw"] - host_comm_snap["bytes_raw"]
-                if sent_d:
-                    logger.info(
-                        "Epoch[%d] Comm: %.2f MB pushed to the parameter "
-                        "host (%s; fp32 would be %.2f MB, %.1fx)", epoch,
-                        sent_d / 1e6, async_comm_spec.mode, raw_d / 1e6,
-                        raw_d / sent_d)
-            if stale_sync and tl is not None:
-                # overlap accounting (needs the sync timeline): wire phase
-                # = the blocked tail, overlap subs = what the pipeline hid
-                spans_e = tl.spans[epoch_span_base:]
-                compute_s = sum(d for s in spans_e
-                                for n, _, d in s.phases() if n == "device")
-                tail_s = sum(d for s in spans_e
-                             for n, _, d in s.phases() if n == "wire")
-                hidden_s = sum(d for s in spans_e
-                               for n, _, d in s.subs if n == "overlap")
-                # step = the schedule-controlled time (device compute +
-                # blocking wire tail) — NOT the whole span: data_wait/
-                # dispatch/host stalls are not the pipeline's doing and
-                # would read as negative efficiency on a slow dataloader
-                eff = comm_mod.overlap_efficiency(
-                    compute_s + tail_s, compute_s, tail_s + hidden_s)
-                telemetry_mod.gauge("comm_overlap_efficiency", eff)
-                logger.info(
-                    "Epoch[%d] Overlap: %.2fs on the wire (%.2fs hidden "
-                    "under compute, %.2fs blocking tail), efficiency=%.2f",
-                    epoch, tail_s + hidden_s, hidden_s, tail_s, eff)
-            if guard_cfg is not None:
-                self.guard_stats["skipped_steps"] = int(np.asarray(
-                    _host_local(gstate["skipped"])))
-                self.guard_stats["loss_scale"] = float(np.asarray(
-                    _host_local(gstate["scale"])))
-                skipped_delta = self.guard_stats["skipped_steps"] \
-                    - skipped_base
-                if skipped_delta > 0:
-                    telemetry_mod.counter("resilience_skipped_steps_total",
-                                          skipped_delta)
-                telemetry_mod.gauge("loss_scale",
-                                    self.guard_stats["loss_scale"])
-                if self.guard_stats["skipped_steps"] or \
-                        self.guard_stats["step_retries"]:
-                    logger.info(
-                        "Epoch[%d] Guard: skipped_steps=%d step_retries=%d "
-                        "loss_scale=%g", epoch,
-                        self.guard_stats["skipped_steps"],
-                        self.guard_stats["step_retries"],
-                        self.guard_stats["loss_scale"])
-
-            if sharded_checkpoint_dir is not None:
-                if ckpt_writer is not None:
-                    # drain first: a queued cadence snapshot may share
-                    # this num_update's step id, and two writers must
-                    # never race one .tmp.<step> dir
-                    ckpt_writer.flush()
-                comm_state, comm_meta = _comm_ckpt()
-                # armed runs keep ONE step-id namespace (num_update) for
-                # cadence and epoch-end saves; unarmed runs keep the
-                # legacy epoch-granular ids. batches_done=0: the resumed
-                # run starts the NEXT epoch from its top.
-                step_id = num_update if ckpt_every is not None \
-                    else epoch + 1
-                ckpt_plane_mod.save_now(
-                    sharded_checkpoint_dir, step_id, params, aux=aux,
-                    symbol=self.symbol, opt_state=opt_state,
-                    comm_state=comm_state,
-                    extra_meta={"epoch": epoch + 1,
-                                "num_update": num_update,
-                                **_resume_meta(0), **_guard_meta(),
-                                **comm_meta},
-                    keep=ckpt_writer.keep_last_k
-                    if ckpt_writer is not None else None)
-
-            if mfu_acct is not None and nbatch:
-                spans_e = tl.spans[epoch_span_base:] if tl is not None else []
-                data_wait = sum(d for s in spans_e
-                                for n, _, d in s.phases() if n == "data_wait")
-                mfu_acct.epoch_report(
-                    epoch, nbatch, time.time() - tic,
-                    compile_seconds=cdiff["compile_seconds"]
-                    - compile_snap["compile_seconds"],
-                    data_wait_seconds=data_wait,
-                    skipped_steps=(self.guard_stats["skipped_steps"]
-                                   - skipped_base)
-                    if guard_cfg is not None else 0,
-                    step_retries=(self.guard_stats["step_retries"]
-                                  - retries_base)
-                    if guard_cfg is not None else 0,
-                    checkpoint_seconds=_ckpt_seconds() - ckpt_base,
-                    resize_seconds=resize_badput,
-                    profile_seconds=profile_badput,
-                    logger=logger)
-
-            _write_back()
-
-            if mem_prev is not None:
-                # close the epoch's watermark window: emits the
-                # memory_watermark event and runs the epoch-over-epoch
-                # leak detector (telemetry/memory.py)
-                telemetry_mod.memory.epoch_mark(epoch, logger=logger)
-
-            if eval_data is not None:
+            with telemetry_mod.phase("fit.epoch", epoch=epoch):
+                # the epoch clock survives an elastic redo: on resize the
+                # loop `continue`s without advancing `epoch` or resetting the
+                # clock, so the aborted attempt + downtime price into this
+                # epoch's wall (and its `resize` badput bucket), never into
+                # throughput
+                if epoch_tic is None:
+                    epoch_tic = time.time()
+                tic = epoch_tic
+                attempt_tic = time.time()
+                resize_ev = None
+                compile_snap = compile_mod.registry().snapshot()
+                comm_snap = comm_mod.registry().snapshot() \
+                    if comm_spec is not None else None
+                host_comm_snap = kv.compression_stats() \
+                    if async_comm_spec is not None and \
+                    hasattr(kv, "compression_stats") else None
+                epoch_span_base = len(tl.spans) if tl is not None else 0
+                ckpt_base = _ckpt_seconds() if mfu_acct is not None else 0.0
+                retries_base = self.guard_stats["step_retries"] \
+                    if guard_cfg is not None else 0
+                skipped_base = self.guard_stats["skipped_steps"] \
+                    if guard_cfg is not None else 0
                 eval_metric.reset()
-                eval_iter = _init_iter(eval_data[0], eval_data[1], batch_size, is_train=False) \
-                    if isinstance(eval_data, tuple) else eval_data
-                self._eval(eval_iter, eval_metric, params, aux, data_names, label_names)
-                name, value = eval_metric.get()
-                logger.info("Epoch[%d] Validation-%s=%f", epoch, name, value)
+                maccum = self._DeviceMetricAccum(eval_metric)
+                nbatch = 0
+                with telemetry_mod.phase("fit.epoch.feed_start", epoch=epoch):
+                    train_data.reset()
+                    if feed_depth > 0:
+                        feed = _AsyncDeviceFeed(train_data, _extract_batch,
+                                                _place_batch, depth=feed_depth,
+                                                snapshot=_snapshot_batch,
+                                                epoch=epoch)
+                    else:  # MXTPU_FEED_PREFETCH=0: synchronous (debugging)
+                        feed = ((b, _place_batch(_extract_batch(b)))
+                                for b in train_data)
+                feed_src = _feed_waits(feed, epoch, tl)
+                try:
+                    for batch, batch_arrays in feed_src:
+                        if skip_batches > 0:
+                            # step-granular resume (ISSUE 17): fast-forward a
+                            # resumed/redone epoch past batches it already
+                            # trained — consume the feed without dispatching,
+                            # without drawing RNG keys and without advancing
+                            # num_update, so the first live batch sees exactly
+                            # the state the checkpointed run saw
+                            skip_batches -= 1
+                            nbatch += 1
+                            continue
+                        if fleet_ctl is not None:
+                            # policy tick (synchronous mode), then any staged
+                            # actuation that must run on the training thread
+                            # (tier re-warm). Evictions/backfills the tick
+                            # issues land in the coordinator and surface
+                            # through the elastic poll right below.
+                            if not fleet_ctl.threaded:
+                                fleet_ctl.tick()
+                            retier_act = fleet_ctl.take_retier()
+                            if retier_act is not None:
+                                _apply_retier(retier_act)
+                        if elastic_co is not None:
+                            # membership poll, once per step: chaos sites,
+                            # heartbeat expiry, then any pending change —
+                            # a hit aborts the attempt (this epoch redoes on
+                            # the new world after the resize below)
+                            elastic_co.chaos_poll()
+                            elastic_co.check_heartbeats()
+                            resize_ev = elastic_co.poll()
+                            if resize_ev is not None:
+                                break
+                        with telemetry_mod.phase("fit.step", epoch=epoch,
+                                                 step=nbatch) as step_span:
+                            span = tl.begin_step(epoch, nbatch) \
+                                if tl is not None else None
+                            if preempt_handler is not None and \
+                                    preempt_mod.preemption_requested():
+                                _preempt_flush()
+                            if watchdog is not None:
+                                watchdog.check()
+                            with telemetry_mod.phase("fit.dispatch",
+                                                     epoch=epoch,
+                                                     step=nbatch) as part:
+                                if span is not None:
+                                    # dispatch opens as soon as the batch is in
+                                    # hand: program-cache resolution /
+                                    # first-step graph build / the one-time
+                                    # FLOP trace are launch-side host work, not
+                                    # a data stall
+                                    span.mark("dispatch", ts=part.start)
+                                bkey = getattr(batch, "bucket_key", None)
+                                b_dnames = getattr(batch, "data_names",
+                                                   data_names)
+                                b_lnames = getattr(batch, "label_names",
+                                                   label_names)
+                                if bkey not in train_steps:
+                                    train_steps[bkey] = self._get_train_step(
+                                        bkey, b_dnames, b_lnames, optimizer,
+                                        mesh,
+                                        metric=eval_metric
+                                        if use_device_metric else None,
+                                        apply_update=not async_kv,
+                                        guard_cfg=guard_cfg,
+                                        pad_policy=pad_policy,
+                                        compression=comm_spec,
+                                        overlap_plan=overlap_plan,
+                                        comm_kernels=kern_cfg,
+                                        health_cfg=health_cfg)
+                                train_step = train_steps[bkey]
+                                pad_tail = ()
+                                if pad_policy is not None:
+                                    pad_tail = (
+                                        batch_arrays.pop("__num_valid__"),)
+                                rng = random_mod.next_key()
+                                lr = optimizer._get_lr()
+                                optimizer.num_update = num_update
+                                if mfu_acct is not None and \
+                                        mfu_acct.flops_per_step is None and \
+                                        getattr(train_step, "_tracked",
+                                                None) is not None:
+                                    # abstract-trace the exact program about to
+                                    # dispatch (shapes only, pre-donation) for
+                                    # the jaxpr FLOP table behind the MFU line
+                                    mfu_acct.maybe_trace(
+                                        train_step._tracked._jitted,
+                                        (params, opt_state, aux,
+                                         batch_arrays, rng, jnp.float32(lr),
+                                         maccum.state)
+                                        + _state_tail() + pad_tail)
+                                if prof_session is not None and \
+                                        prof_session.pending:
+                                    # maybe open the capture window (warmup
+                                    # done AND last step compile-quiet); the
+                                    # args thunk lets the session harvest this
+                                    # exact program's HLO metadata
+                                    def _prof_args():
+                                        return (params, opt_state, aux,
+                                                batch_arrays, rng,
+                                                jnp.float32(lr),
+                                                maccum.state) \
+                                            + _state_tail() + pad_tail
+                                    prof_session.before_step(
+                                        getattr(train_step, "_tracked", None),
+                                        _prof_args,
+                                        compile_mod.registry().snapshot()[
+                                            "compiles"])
+                                if shard_audit_on and \
+                                        bkey not in _shard_audited:
+                                    _shard_audited.add(bkey)
+                                    tj = getattr(train_step, "_tracked", None)
+                                    if tj is not None:
+                                        # warms the exact program about to
+                                        # dispatch (TrackedJit AOT) and audits
+                                        # its optimized HLO; raises on MX802
+                                        # before the step runs
+                                        self._shard_audit_program(
+                                            tj,
+                                            (params, opt_state, aux,
+                                             batch_arrays, rng,
+                                             jnp.float32(lr), maccum.state)
+                                            + _state_tail() + pad_tail,
+                                            mesh=mesh, comm_spec=comm_spec,
+                                            overlap_plan=overlap_plan,
+                                            flat_elems=comm_mod.flat_size(
+                                                params),
+                                            logger=logger)
+                                # state tail mirrors the step signature:
+                                # [gstate][cstate][hstate][valid]
+                                hs_tail = () if hstate is None else (hstate,)
+                                if guard_cfg is None:
+                                    tail = () if cstate is None else (cstate,)
+                                    res = train_step(params, opt_state, aux,
+                                                     batch_arrays, rng, lr,
+                                                     maccum.state, *tail,
+                                                     *hs_tail, *pad_tail)
+                                else:
+                                    batch_arrays = self._chaos_step_sites(
+                                        batch_arrays, b_dnames, watchdog)
+                                    retries = guard_cfg.max_step_retries
+                                    while True:
+                                        try:
+                                            # the injected raise fires BEFORE
+                                            # dispatch, so donated buffers are
+                                            # still live on retry
+                                            chaos_mod.maybe_raise(
+                                                "step.raise",
+                                                chaos_mod.TransientStepError)
+                                            tail = (gstate,) \
+                                                if cstate is None \
+                                                else (gstate, cstate)
+                                            res = train_step(
+                                                params, opt_state, aux,
+                                                batch_arrays, rng, lr,
+                                                maccum.state, *tail,
+                                                *hs_tail, *pad_tail)
+                                            break
+                                        except chaos_mod.TransientStepError:
+                                            if retries <= 0:
+                                                # retry budget exhausted: leave
+                                                # a black box before failing
+                                                # the run
+                                                telemetry_mod.flight.auto_dump(
+                                                    "guard_trip")
+                                                raise
+                                            retries -= 1
+                                            self.guard_stats[
+                                                "step_retries"] += 1
+                                            telemetry_mod.counter(
+                                                "resilience_step_retries"
+                                                "_total")
+                                            if span is not None:
+                                                span.event("step_retry")
+                                    if watchdog is not None:
+                                        watchdog.beat()
+                            with telemetry_mod.phase("fit.step_host",
+                                                     epoch=epoch,
+                                                     step=nbatch) as part:
+                                if span is not None:
+                                    span.mark("device", ts=part.start)
+                                    if tcfg.sync:
+                                        # exact device phase: wait for the
+                                        # step's output buffers (see
+                                        # TelemetryConfig.sync)
+                                        jax.block_until_ready(res)
+                                    # stale-sync: the kvstore slot becomes
+                                    # "wire" — it times only the un-hidden tail
+                                    # of the PREVIOUS round's push (the hidden
+                                    # part lands as an "overlap" sub-span from
+                                    # push_pull_stale)
+                                    span.mark("wire" if stale_sync
+                                              else ("kvstore" if async_kv
+                                                    else "host"))
+                                params, opt_state, aux, outs, maccum.state = \
+                                    res[:5]
+                                idx = 5
+                                if guard_cfg is not None:
+                                    gstate = res[idx]
+                                    idx += 1
+                                if cstate is not None:
+                                    cstate = res[idx]
+                                    idx += 1
+                                if hstate is not None:
+                                    hstate = res[idx]
+                                    if nbatch % health_cfg.every == 0:
+                                        # pull the tiny stat vectors + emit the
+                                        # health event; the monitor's detectors
+                                        # run inside the emit, so any
+                                        # health_anomaly lands in the flight
+                                        # ring BEFORE the guard-skip event that
+                                        # closes the story
+                                        _, h_finite = telemetry_mod.health \
+                                            .observe_device_stats(
+                                                health_groups, hstate, epoch,
+                                                nbatch)
+                                        # only a guard that actually skips gets
+                                        # the skip event — with
+                                        # skip_nonfinite=False the poisoned
+                                        # update was APPLIED, and a post-
+                                        # mortem must not read a skip that
+                                        # never ran
+                                        if guard_cfg is not None and \
+                                                guard_cfg.skip_nonfinite and \
+                                                not h_finite:
+                                            if span is not None:
+                                                span.event("guard_skip")
+                                            else:
+                                                telemetry_mod.emit(
+                                                    "step_event",
+                                                    span_kind="step",
+                                                    epoch=epoch, step=nbatch,
+                                                    name="guard_skip")
+                                if prof_session is not None and \
+                                        prof_session.open:
+                                    # window accounting: the K-th step blocks
+                                    # on its outputs, stops the trace,
+                                    # attributes, publishes; the wall time
+                                    # returns as `profile` badput
+                                    profile_badput += prof_session.after_step(
+                                        res, epoch=epoch)
+                                step_finite = True
+                                if guard_cfg is not None and (
+                                        async_kv or not use_device_metric):
+                                    # these paths sync to host right below
+                                    # anyway; the in-jit fast path never reads
+                                    # this flag
+                                    step_finite = bool(
+                                        np.asarray(  # mxlint: disable=MX309
+                                            _host_local(
+                                                gstate["last_finite"])))
+                                if async_kv:
+                                    if step_finite and stale_sync:
+                                        # pipelined push: THIS step's grads go
+                                        # to the parameter host on a background
+                                        # thread while the next step computes;
+                                        # the weights returned are one round
+                                        # stale (overlap= on dist_async)
+                                        pulled = kv.push_pull_stale(
+                                            {name: _host_local(params[name])
+                                             for name in param_names})
+                                    elif step_finite:
+                                        # params slot carries grads
+                                        # (apply_update=False): ONE round trip
+                                        # applies them on the host (updated on
+                                        # arrival) and returns the fresh
+                                        # weights — unbounded-staleness async,
+                                        # like the reference's dist_async
+                                        # worker loop
+                                        pulled = kv.push_pull(
+                                            {name: _host_local(params[name])
+                                             for name in param_names})
+                                    elif stale_sync:
+                                        # guard tripped: drain the in-flight
+                                        # round, drop the bad grads, re-pull
+                                        # current weights
+                                        pulled = kv.flush_stale(param_names)
+                                    else:
+                                        # guard tripped: the grads are
+                                        # non-finite — do NOT poison the
+                                        # parameter host; re-pull the current
+                                        # weights instead (the params slot
+                                        # holds the bad grads and must be
+                                        # replaced either way)
+                                        pulled = kv.pull_many(param_names)
+                                    params = {k: jnp.asarray(pulled[k])
+                                              for k in param_names}
+                                if span is not None and async_kv:
+                                    span.mark("host")
+                                num_update += 1
+                                if use_device_metric:
+                                    maccum.after_batch(batch.label)
+                                elif step_finite:
+                                    outs_h = [
+                                        _host_local(o)
+                                        for o in outs[: len(batch.label)]]
+                                    labels_h = batch.label
+                                    if pad_policy is not None:
+                                        # batch.label holds the UNPADDED rows;
+                                        # slice the outputs to the valid prefix
+                                        # (wrap-around pad rows excluded too —
+                                        # that's the policy's
+                                        # metric-correctness contract)
+                                        nv = int(labels_h[0].shape[0]) - int(
+                                            getattr(batch, "pad", 0) or 0)
+                                        outs_h = [o[:nv] for o in outs_h]
+                                        # host-metric path: the per-batch pull
+                                        # IS the metric contract here (device
+                                        # metrics are the sanctioned fast path)
+                                        labels_h = [
+                                            np.asarray(l.asnumpy()  # mxlint: disable=MX309
+                                                       if hasattr(l, "asnumpy")
+                                                       else l)[:nv]
+                                            for l in labels_h]
+                                    eval_metric.update(
+                                        labels_h, [NDArray(o) for o in outs_h])
+                                nbatch += 1
+                                if ckpt_writer is not None and \
+                                        num_update % ckpt_every == 0 and \
+                                        num_update != ckpt_last_update:
+                                    # cadence hit (ISSUE 17): one blocking host
+                                    # copy, then the writer thread owns
+                                    # durability — the loop is back on the next
+                                    # batch immediately. (guard-skipped steps
+                                    # leave num_update in place: the dedup
+                                    # keeps a skipped batch from re-saving the
+                                    # same update)
+                                    ckpt_last_update = num_update
+                                    _ckpt_tick()
+                                if ckpt_writer is not None and \
+                                        fleet_ctl is not None:
+                                    ckpt_act = fleet_ctl.take_ckpt_cadence()
+                                    if ckpt_act is not None:
+                                        # controller-staged cadence change:
+                                        # host-side counter only, nothing
+                                        # recompiles
+                                        ckpt_every = max(
+                                            1, int(ckpt_act["every"]))
+                                        fleet_ctl.ckpt_cadence_applied(
+                                            ckpt_act)
+                                        logger.info(
+                                            "controller: checkpoint cadence "
+                                            "-> every %d step(s)", ckpt_every)
+                                if batch_end_callback is not None:
+                                    p = BatchEndParam(
+                                        epoch=epoch, nbatch=nbatch,
+                                        eval_metric=eval_metric)
+                                    for cb in _as_list(batch_end_callback):
+                                        cb(p)
+                            if span is not None:
+                                span.end()
+                        if span is None:
+                            # timeline off: the always-on flight recorder
+                            # still gets a step mark (identity, timestamp,
+                            # duration), so a crash dump shows the last K
+                            # steps either way
+                            telemetry_mod.flight.note_step(
+                                epoch, nbatch - 1, dur_ms=1e3 * (
+                                    step_span.end_ts - step_span.start))
+                finally:
+                    if feed_depth > 0:
+                        with telemetry_mod.phase("fit.epoch.feed_close",
+                                                 epoch=epoch):
+                            feed.close()
+                if resize_ev is not None:
+                    # elastic resize: quiesce, re-shard, re-plan, re-warm —
+                    # then redo this epoch on the new world. Everything the
+                    # aborted attempt spent (its steps get redone) plus the
+                    # resize downtime is this epoch's `resize` badput.
+                    _apply_resize(resize_ev)
+                    resize_badput += time.time() - attempt_tic
+                    continue
+                if stale_sync:
+                    # drain the pipeline at the epoch boundary: the last step's
+                    # push must land before callbacks/checkpoints read weights
+                    pulled = kv.flush_stale(param_names)
+                    params = {k: jnp.asarray(pulled[k]) for k in param_names}
+                with telemetry_mod.phase("fit.epoch.drain", epoch=epoch):
+                    if use_device_metric:
+                        maccum.finish()
+                    # stop the epoch clock only once the last step's buffers
+                    # are ready — a returned dispatch is not a finished step
+                    # (the un-barriered-timing footgun, mxlint MX306)
+                    jax.block_until_ready(
+                        jax.tree_util.tree_leaves(params)[:1])
+                if prof_session is not None and prof_session.open:
+                    # epoch ended inside the window: the device work above has
+                    # retired, so close with what was captured rather than
+                    # leaking an open trace into the next epoch
+                    profile_badput += prof_session.close(epoch=epoch)
+                with telemetry_mod.phase("fit.epoch.metric_pull",
+                                         epoch=epoch):
+                    name, value = eval_metric.get()
+                    logger.info("Epoch[%d] Train-%s=%f", epoch, name, value)
+                    logger.info("Epoch[%d] Time cost=%.3f", epoch,
+                                time.time() - tic)
+                cdiff = compile_mod.registry().snapshot()
+                if cdiff["compiles"] > compile_snap["compiles"]:
+                    # compile activity this epoch (expected in epoch 1 / on
+                    # a new bucket; anything later is shape drift — see
+                    # RecompileTracker): programs, seconds, cache traffic
+                    logger.info(
+                        "Epoch[%d] Compile: %d XLA compile(s), %.2fs "
+                        "(jit hits=%d misses=%d, persistent-cache hits=%d, "
+                        "saved=%.2fs)", epoch,
+                        cdiff["compiles"] - compile_snap["compiles"],
+                        cdiff["compile_seconds"]
+                        - compile_snap["compile_seconds"],
+                        cdiff["hits"] - compile_snap["hits"],
+                        cdiff["misses"] - compile_snap["misses"],
+                        cdiff["persistent_cache_hits"]
+                        - compile_snap["persistent_cache_hits"],
+                        cdiff["persistent_cache_saved_seconds"]
+                        - compile_snap["persistent_cache_saved_seconds"])
+                if comm_snap is not None:
+                    cdelta = comm_mod.registry().snapshot()
+                    steps_d = cdelta["steps"] - comm_snap["steps"]
+                    if steps_d:
+                        wire_d = cdelta["wire_bytes"] - comm_snap["wire_bytes"]
+                        fp32_d = (cdelta["fp32_wire_bytes"]
+                                  - comm_snap["fp32_wire_bytes"])
+                        logger.info(
+                            "Epoch[%d] Comm: %d sync steps, %.2f MB on the "
+                            "wire (%s; fp32 would be %.2f MB, %.1fx)", epoch,
+                            steps_d, wire_d / 1e6, comm_spec.mode,
+                            fp32_d / 1e6,
+                            fp32_d / wire_d if wire_d else float("inf"))
+                if host_comm_snap is not None:
+                    hs = kv.compression_stats()
+                    sent_d = hs["bytes_encoded"] \
+                        - host_comm_snap["bytes_encoded"]
+                    raw_d = hs["bytes_raw"] - host_comm_snap["bytes_raw"]
+                    if sent_d:
+                        logger.info(
+                            "Epoch[%d] Comm: %.2f MB pushed to the parameter "
+                            "host (%s; fp32 would be %.2f MB, %.1fx)", epoch,
+                            sent_d / 1e6, async_comm_spec.mode, raw_d / 1e6,
+                            raw_d / sent_d)
+                if stale_sync and tl is not None:
+                    # overlap accounting (needs the sync timeline): wire
+                    # phase = the blocked tail, overlap subs = what the
+                    # pipeline hid
+                    spans_e = tl.spans[epoch_span_base:]
+                    compute_s = sum(d for s in spans_e
+                                    for n, _, d in s.phases() if n == "device")
+                    tail_s = sum(d for s in spans_e
+                                 for n, _, d in s.phases() if n == "wire")
+                    hidden_s = sum(d for s in spans_e
+                                   for n, _, d in s.subs if n == "overlap")
+                    # step = the schedule-controlled time (device compute +
+                    # blocking wire tail) — NOT the whole span: data_wait/
+                    # dispatch/host stalls are not the pipeline's doing and
+                    # would read as negative efficiency on a slow
+                    # dataloader
+                    eff = comm_mod.overlap_efficiency(
+                        compute_s + tail_s, compute_s, tail_s + hidden_s)
+                    telemetry_mod.gauge("comm_overlap_efficiency", eff)
+                    logger.info(
+                        "Epoch[%d] Overlap: %.2fs on the wire (%.2fs hidden "
+                        "under compute, %.2fs blocking tail), efficiency=%.2f",
+                        epoch, tail_s + hidden_s, hidden_s, tail_s, eff)
+                if guard_cfg is not None:
+                    self.guard_stats["skipped_steps"] = int(np.asarray(
+                        _host_local(gstate["skipped"])))
+                    self.guard_stats["loss_scale"] = float(np.asarray(
+                        _host_local(gstate["scale"])))
+                    skipped_delta = self.guard_stats["skipped_steps"] \
+                        - skipped_base
+                    if skipped_delta > 0:
+                        telemetry_mod.counter("resilience_skipped_steps_total",
+                                              skipped_delta)
+                    telemetry_mod.gauge("loss_scale",
+                                        self.guard_stats["loss_scale"])
+                    if self.guard_stats["skipped_steps"] or \
+                            self.guard_stats["step_retries"]:
+                        logger.info(
+                            "Epoch[%d] Guard: skipped_steps=%d "
+                            "step_retries=%d loss_scale=%g", epoch,
+                            self.guard_stats["skipped_steps"],
+                            self.guard_stats["step_retries"],
+                            self.guard_stats["loss_scale"])
 
-            if epoch_end_callback is not None:
-                if preempt_handler is not None and \
-                        preempt_mod.preemption_requested():
-                    _preempt_flush()  # don't start callbacks on a dead clock
-                for cb in _as_list(epoch_end_callback):
-                    cb(epoch, self.symbol, self.arg_params, self.aux_params)
-            epoch_tic = None
-            resize_badput = 0.0
-            profile_badput = 0.0
-            epoch += 1
+                if sharded_checkpoint_dir is not None:
+                    with telemetry_mod.phase("fit.epoch.checkpoint",
+                                             epoch=epoch):
+                        if ckpt_writer is not None:
+                            # drain first: a queued cadence snapshot may share
+                            # this num_update's step id, and two writers must
+                            # never race one .tmp.<step> dir
+                            ckpt_writer.flush()
+                        comm_state, comm_meta = _comm_ckpt()
+                        # armed runs keep ONE step-id namespace (num_update)
+                        # for cadence and epoch-end saves; unarmed runs keep
+                        # the legacy epoch-granular ids. batches_done=0: the
+                        # resumed run starts the NEXT epoch from its top.
+                        step_id = num_update if ckpt_every is not None \
+                            else epoch + 1
+                        ckpt_plane_mod.save_now(
+                            sharded_checkpoint_dir, step_id, params, aux=aux,
+                            symbol=self.symbol, opt_state=opt_state,
+                            comm_state=comm_state,
+                            extra_meta={"epoch": epoch + 1,
+                                        "num_update": num_update,
+                                        **_resume_meta(0), **_guard_meta(),
+                                        **comm_meta},
+                            keep=ckpt_writer.keep_last_k
+                            if ckpt_writer is not None else None)
+
+                if mfu_acct is not None and nbatch:
+                    spans_e = tl.spans[epoch_span_base:] \
+                        if tl is not None else []
+                    data_wait = sum(d for s in spans_e
+                                    for n, _, d in s.phases()
+                                    if n == "data_wait")
+                    mfu_acct.epoch_report(
+                        epoch, nbatch, time.time() - tic,
+                        compile_seconds=cdiff["compile_seconds"]
+                        - compile_snap["compile_seconds"],
+                        data_wait_seconds=data_wait,
+                        skipped_steps=(self.guard_stats["skipped_steps"]
+                                       - skipped_base)
+                        if guard_cfg is not None else 0,
+                        step_retries=(self.guard_stats["step_retries"]
+                                      - retries_base)
+                        if guard_cfg is not None else 0,
+                        checkpoint_seconds=_ckpt_seconds() - ckpt_base,
+                        resize_seconds=resize_badput,
+                        profile_seconds=profile_badput,
+                        logger=logger)
+
+                _write_back()
+
+                if mem_prev is not None:
+                    # close the epoch's watermark window: emits the
+                    # memory_watermark event and runs the epoch-over-epoch
+                    # leak detector (telemetry/memory.py)
+                    telemetry_mod.memory.epoch_mark(epoch, logger=logger)
+
+                if eval_data is not None:
+                    with telemetry_mod.phase("fit.epoch.eval", epoch=epoch):
+                        eval_metric.reset()
+                        eval_iter = _init_iter(
+                            eval_data[0], eval_data[1], batch_size,
+                            is_train=False) \
+                            if isinstance(eval_data, tuple) else eval_data
+                        self._eval(eval_iter, eval_metric, params, aux,
+                                   data_names, label_names)
+                        name, value = eval_metric.get()
+                        logger.info("Epoch[%d] Validation-%s=%f", epoch, name,
+                                    value)
+
+                if epoch_end_callback is not None:
+                    with telemetry_mod.phase("fit.epoch.callback",
+                                             epoch=epoch):
+                        if preempt_handler is not None and \
+                                preempt_mod.preemption_requested():
+                            # don't start callbacks on a dead clock
+                            _preempt_flush()
+                        for cb in _as_list(epoch_end_callback):
+                            cb(epoch, self.symbol, self.arg_params,
+                               self.aux_params)
+                epoch_tic = None
+                resize_badput = 0.0
+                profile_badput = 0.0
+                epoch += 1
         finally:
             if ckpt_writer is not None:
                 # drain queued snapshots so the last cadence hit is
@@ -2805,6 +2970,11 @@ class FeedForward(BASE_ESTIMATOR):
                 args += (_sds((), np.dtype(np.int32)),)
             jobs.append((step._tracked, args))
 
+        def _compile(tj, args):
+            # the lowering and the XLA compile, or the persistent cache's read
+            with telemetry_mod.phase("setup.compile", label=tj.label):
+                tj.precompile(*args)
+
         t0 = time.time()
         if parallel and len(jobs) > 1:
             import concurrent.futures as cf
@@ -2814,13 +2984,13 @@ class FeedForward(BASE_ESTIMATOR):
             with cf.ThreadPoolExecutor(max_workers=workers,
                                        thread_name_prefix="mx-precompile") \
                     as pool:
-                futures = [pool.submit(tj.precompile, *args)
+                futures = [pool.submit(_compile, tj, args)
                            for tj, args in jobs]
                 for f in futures:
                     f.result()
         else:
             for tj, args in jobs:
-                tj.precompile(*args)
+                _compile(tj, args)
         wall = time.time() - t0
         logging.info("precompile: %d program(s) ready in %.2fs", len(jobs),
                      wall)

@@ -39,7 +39,8 @@ from .distributed import (trace_id, set_trace_id, set_world, current_rank,
                           merge_traces, detect_stragglers,
                           load_rank_streams)
 from .timeline import (StepTimeline, Span, current_span,
-                       clear_current_span, phase, timed)
+                       clear_current_span, phase, timed, span_records,
+                       spans_dropped)
 from .mfu import (MFUAccountant, DEVICE_PEAKS, device_peak_flops,
                   resolve_peak_flops, measured_peak_flops,
                   record_compile_badput)
@@ -74,7 +75,7 @@ __all__ = [
     "record_clock_beacon", "merge_traces", "detect_stragglers",
     "load_rank_streams",
     "StepTimeline", "Span", "current_span", "clear_current_span", "phase",
-    "timed",
+    "timed", "span_records", "spans_dropped",
     "MFUAccountant", "DEVICE_PEAKS", "device_peak_flops",
     "resolve_peak_flops", "measured_peak_flops",
     "record_compile_badput",

@@ -74,6 +74,7 @@ DEFAULT_COUNTERS = (
 )
 
 _RESERVOIR = 2048  # per-histogram retained observations (percentile window)
+SPAN_RING = 16_384  # telemetry.phase() records kept, oldest dropped first
 
 
 class Histogram:
@@ -157,6 +158,10 @@ class MetricsHub:
         self._gauges = {}            # (name, labelkey) -> float
         self._hists = {}             # (name, labelkey) -> Histogram
         self._events = collections.deque(maxlen=ring_size)
+        # telemetry.phase() records (timeline.py): a whole benchmark run
+        # is about 2,500
+        self._spans = collections.deque(maxlen=SPAN_RING)
+        self._spans_dropped = 0
         self._collectors = {}        # family -> callable() -> {name: value}
         self._sinks = []             # streaming event sinks (JsonlWriter)
         self._kind_sinks = {}        # kind -> [sinks]: filtered sinks (the
@@ -199,6 +204,28 @@ class MetricsHub:
         with self._lock:
             h = self._hists.get((name, _label_key(labels)))
             return None if h is None else h.percentile(q)
+
+    # -- spans ----------------------------------------------------------------
+    def record_span(self, record, seconds):
+        """One closed ``telemetry.phase()``: its record into the span ring
+        (the oldest goes, and is counted, when the ring is full) and its
+        duration into the ``<name>_seconds`` histogram, under one lock."""
+        key = (record[0] + "_seconds", ())
+        with self._lock:
+            if len(self._spans) == SPAN_RING:
+                self._spans_dropped += 1
+            self._spans.append(record)
+            h = self._hists.get(key)
+            if h is None:
+                h = self._hists[key] = Histogram()
+            h.observe(seconds)
+
+    def span_ring(self):
+        with self._lock:
+            return list(self._spans)
+
+    def spans_dropped(self):
+        return self._spans_dropped
 
     # -- events ---------------------------------------------------------------
     def emit(self, kind, **fields):

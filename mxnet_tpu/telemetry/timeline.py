@@ -42,11 +42,13 @@ import json
 import threading
 import time
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from . import distributed as _dist
 from .hub import hub as _hub
 
 __all__ = ["Span", "StepTimeline", "current_span", "clear_current_span",
-           "phase", "timed"]
+           "phase", "timed", "span_records", "spans_dropped"]
 
 _TLS = threading.local()
 
@@ -69,21 +71,102 @@ def clear_current_span():
     _TLS.span = None
 
 
-@contextlib.contextmanager
-def phase(name):
-    """Record a named sub-phase on the current span (no-op without one) and
-    a duration histogram either way. The hook lower layers use: kvstore
-    push/pull and checkpoint flushes call this, so their time lands inside
-    whatever step span is in flight."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        _hub().observe(f"{name}_seconds", dt)
-        span = current_span()
+class phase:
+    """``with telemetry.phase(name, **attrs):`` — the program's one span
+    primitive. One ``with`` does three things:
+
+    1. opens a ``jax.profiler.TraceAnnotation("mx." + name, **attrs)``: with
+       a profiler session in flight the span lands on the ``/host:CPU``
+       plane of the same trace as the device's lines, on the profiler's
+       clock; with none it costs a flag check;
+    2. appends one record to the hub's bounded span ring
+       (:func:`span_records`), stamped with ``time.perf_counter()``.
+       ``parent`` is the span enclosing it on the same thread; ``epoch``
+       and ``step`` are the attrs of those names, inherited from the
+       parent when not given, so the spans of one step or one epoch share
+       them;
+    3. if a :class:`StepTimeline` step span is open on the thread,
+       attaches itself to it as a sub-span; and observes the
+       ``<name>_seconds`` histogram either way.
+
+    A span that an exception closes is recorded like any other. ``as``
+    gives the span itself: ``start`` and, once it has closed, ``end_ts``
+    are its ``time.perf_counter`` stamps; ``attrs`` may be added to before
+    it closes (the ring record sees them, the profiler's event does not);
+    ``end()`` closes it ahead of the ``with`` (a second close is a no-op).
+    A span never synchronises with the device."""
+
+    __slots__ = ("name", "attrs", "epoch", "step", "parent", "start", "end_ts",
+                 "_ann")
+
+    def __init__(self, name, **attrs):
+        self.name = name
+        self.attrs = attrs
+        self.end_ts = None
+
+    def __enter__(self):
+        tls = _TLS
+        try:
+            stack = tls.phases
+        except AttributeError:
+            stack = tls.phases = []
+            tls.thread = threading.current_thread().name
+        attrs = self.attrs
+        if stack:
+            parent = stack[-1]
+            self.parent = parent.name
+            self.epoch = attrs.get("epoch", parent.epoch)
+            self.step = attrs.get("step", parent.step)
+        else:
+            self.parent = None
+            self.epoch = attrs.get("epoch")
+            self.step = attrs.get("step")
+        stack.append(self)
+        self._ann = _TraceAnnotation("mx." + self.name, **attrs)
+        self._ann.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.end()
+        return False
+
+    def end(self):
+        if self.end_ts is not None:
+            return
+        end = self.end_ts = time.perf_counter()
+        self._ann.__exit__(None, None, None)
+        tls = _TLS
+        if tls.phases[-1] is self:
+            tls.phases.pop()
+        else:                       # closed out of order
+            tls.phases.remove(self)
+        start = self.start
+        _hub().record_span(
+            (self.name, tls.thread, start, end, self.parent, self.epoch,
+             self.step, self.attrs), end - start)
+        span = getattr(tls, "span", None)
         if span is not None:
-            span.add_sub(name, t0, dt)
+            span.add_sub(self.name, start, end - start)
+
+
+_RECORD_KEYS = ("name", "thread", "start", "end", "parent", "epoch", "step",
+                "attrs")
+
+
+def span_records(since=None):
+    """The hub's span ring as plain dicts, oldest first: ``name``,
+    ``thread``, ``start`` / ``end`` (``time.perf_counter`` seconds),
+    ``parent`` (name of the enclosing span on the thread, or None),
+    ``epoch``, ``step`` and ``attrs``. ``since`` keeps the records that
+    ended at or after that ``time.perf_counter`` reading."""
+    return [dict(zip(_RECORD_KEYS, rec)) for rec in _hub().span_ring()
+            if since is None or rec[3] >= since]
+
+
+def spans_dropped():
+    """How many of the oldest span records the ring (16,384) has let go."""
+    return _hub().spans_dropped()
 
 
 @contextlib.contextmanager
