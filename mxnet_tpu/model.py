@@ -1767,17 +1767,34 @@ class FeedForward(BASE_ESTIMATOR):
         epoch = self.begin_epoch
 
         def _write_back():
-            # write state back so callbacks/checkpoints see current values
-            # (device_get: sharded -> host, so predict/save work off-mesh)
-            nbytes = sum(v.nbytes for v in params.values()) \
-                + sum(v.nbytes for v in aux.values())
+            # write state back so callbacks/checkpoints see current values,
+            # as host-backed NDArrays (cpu context, each leaf's dtype kept:
+            # predict/save work off-mesh, nothing is uploaded again).
+            # Every fully addressable leaf joins ONE batched transfer:
+            # device_get starts all the device-to-host copies before it
+            # waits for any, so their latencies overlap. A leaf that spans
+            # other processes' devices (jax.distributed) cannot join and
+            # gives this process's rows by itself. All copies are complete
+            # on return: the next step donates `params`.
+            leaves = [params[k] for k in param_names] \
+                + [aux[k] for k in aux_names]
+            joins = [x.is_fully_addressable for x in leaves]
             with telemetry_mod.phase("fit.epoch.write_back", epoch=epoch,
-                                     arrays=len(params) + len(aux),
-                                     bytes=nbytes):
-                for k in param_names:
-                    self.arg_params[k] = NDArray(_host_local(params[k]))
-                for k in aux_names:
-                    self.aux_params[k] = NDArray(_host_local(aux[k]))
+                                     arrays=len(leaves),
+                                     bytes=sum(x.nbytes for x in leaves),
+                                     batched=sum(joins)):
+                fetched = iter(jax.device_get(
+                    [x for x, join in zip(leaves, joins) if join]))
+                values = [next(fetched) if join else _host_local(x)
+                          for x, join in zip(leaves, joins)]
+                # no target: the arrays land UNCOMMITTED, as the
+                # initializer's do. predict/score hand them to jit beside a
+                # batch committed to ctx, which refuses a committed cpu array
+                with jax.default_device(cpu().jax_device):
+                    landed = [NDArray(v) for v in jax.device_put(values)]
+                n = len(param_names)
+                self.arg_params.update(zip(param_names, landed[:n]))
+                self.aux_params.update(zip(aux_names, landed[n:]))
 
         def _guard_meta():
             if guard_cfg is None:

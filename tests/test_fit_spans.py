@@ -162,6 +162,8 @@ def test_write_back_counts_parameters_and_auxiliary_states(run):
     for r in spans:
         assert r["attrs"]["arrays"] == arrays
         assert r["attrs"]["bytes"] == nbytes
+        # every leaf went through the one batched transfer
+        assert r["attrs"]["batched"] == arrays
 
 
 def test_feed_place_counts_the_host_bytes_of_a_numpy_batch(run):
