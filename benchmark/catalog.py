@@ -8,9 +8,15 @@ never by editing the runner.
                  plain reference (and, if the model needs code, its
                  builder) beside it
   traffic mix    ``traffic/<traffic>.json``; its ``kind`` names the feeder
-                 ``feeds/<kind>.py`` (``make(...)``)
+                 ``feeds/<kind>.py`` (``make(...)``: an object with
+                 ``iter``, the DataIter ``fit`` gets, whose ``ring[0]`` is
+                 a (data, label) batch as it is handed over,
+                 ``steps_per_epoch``, ``batch_rows``, ``check_rows(n)``)
   metric         ``end_to_end/<name>.py`` or ``layer_metrics/<name>.py``
                  (``METRIC`` and ``read(run)``)
+  operator       ``walkers/<Operator>.py`` (``layers(node, in_shapes,
+                 out_shapes)``): what a node of that operator adds to the
+                 FLOP recipe, found by ``walk.py``
   peaks          ``peaks.json``, keyed by ``device_kind``
 """
 

@@ -17,17 +17,21 @@ class StepSpy:
     it dispatches: counts calls and keeps the LAST call's placed batch and
     outputs (earlier outputs are donated to the next step, so only the
     last are alive after ``fit`` returns). A call that raises is counted
-    in ``raised`` and re-raised."""
+    in ``raised`` and re-raised. ``tracked`` is the program's handle on the
+    step's compiled programs (``utils/compile.py`` ``TrackedJit``), or
+    ``None``."""
 
     def __init__(self, model):
         self.calls = 0
         self.raised = 0
         self.batch = None
         self.outputs = None
+        self.tracked = None
         build = model._build_train_step
 
         def spy_build(*args, **kwargs):
             run = build(*args, **kwargs)
+            self.tracked = getattr(run, "_tracked", None)
 
             def spied(params, opt_state, aux, batch, *rest):
                 self.calls += 1
@@ -82,6 +86,29 @@ def replica_faults(spy, devices):
     return faults
 
 
+def train_program_text(spy):
+    """``(text, why_not)``: the optimized-HLO text of the ONE train program
+    the run dispatched, from the executable that is already warm (no
+    lowering, no compile, no jit cache consulted), or ``None`` and the
+    reason. The program's public ``TrackedJit.optimized_hlo`` wants the
+    call's arguments again, which the last step donated; until it offers
+    the text without them (PERF.md section 7) this reads the handle's
+    table of warmed executables."""
+    import jax
+
+    if spy.tracked is None:
+        return None, "the step fit built carries no _tracked handle"
+    programs = list(getattr(spy.tracked, "_aot", {}).values())
+    if len(programs) != 1:
+        return None, (f"the handle holds {len(programs)} warmed train "
+                      "programs, not one")
+    try:
+        text = programs[0].as_text()
+    except jax.errors.JaxRuntimeError as e:
+        return None, f"the runtime refused the text: {str(e).splitlines()[0]}"
+    return text, None if text else "this backend gives no text"
+
+
 def load_reference(config_path, config):
     """The plain reference beside the configuration's file."""
     return catalog.load_file_module(
@@ -101,21 +128,28 @@ def reference_error(mx, model, symbol, config, config_path, images, device,
     """Relative L2 error of the system's logits, computed as the cell
     computes (``compute_dtype``, the trained weights as ``fit`` wrote them
     back) on ``device``, against the configuration's plain float32
-    reference of the same weights on the same images."""
+    reference of the same weights on the same rows (images, or ids)."""
     import jax
 
-    head = symbol.get_internals()[config["logits"]]
+    # ``predict`` cuts every output to the batch's rows, so logits of
+    # ``(rows x positions, classes)`` would come back as the first ``rows``
+    # positions: both sides are compared as one row a sample, every
+    # position of every sequence (for an image's logits nothing changes)
+    n = len(images)
+    head = mx.symbol.Reshape(data=symbol.get_internals()[config["logits"]],
+                             target_shape=(n, -1))
     served = mx.FeedForward(head, ctx=mx.Context(device.platform, device.id),
                             arg_params=model.arg_params,
                             aux_params=model.aux_params,
                             compute_dtype=compute_dtype)
-    got = served.predict(images, batch_size=len(images))
+    got = served.predict(images, batch_size=n)
     ref = load_reference(config_path, config)
     params = {k: v.asnumpy() for k, v in model.arg_params.items()}
     aux = {k: v.asnumpy() for k, v in model.aux_params.items()}
     with jax.default_device(device), \
             jax.default_matmul_precision("highest"):
         want = np.asarray(jax.jit(ref.logits)(params, aux, images))
+    want = want.reshape(n, -1)
     if got.shape != want.shape or not np.all(np.isfinite(got)):
         return float("inf")
     return relative_error(got, want)

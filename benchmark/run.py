@@ -21,9 +21,11 @@ the window's samples over all its seconds. The checks that decide
 stdout, in order: a line of itemised set-up seconds, a line with every
 epoch's two stamps, seconds, rate and loss, and LAST one JSON object
 (``correct``, ``attempted``, ``failed``, ``metrics``, ``device``, with
-``--trace 1`` also ``breakdown``). ``--trace 0`` reports the cell's
-end-to-end metrics, ``--trace 1`` its per-layer metrics from a profiler
-trace of the window's first two whole epochs.
+``--trace 1`` also ``breakdown``, and last ``compared``: every number that
+decided ``correct`` beside its limit, which are also the last lines on
+stderr). ``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1``
+its per-layer metrics from a profiler trace of the window's first two
+whole epochs and from the train program's HLO text (``scopes.py``).
 
 Exit code non-zero, and no result line, without a TPU or with fewer chips
 than the cell asks for, outside a checkout, or when anything raises.
@@ -52,6 +54,7 @@ import catalog  # noqa: E402
 import checks  # noqa: E402
 import epochs  # noqa: E402
 import flops  # noqa: E402
+import scopes  # noqa: E402
 import trace_reduce  # noqa: E402
 
 # traced: measured epochs 1 and 2, whole. The profiler starts inside the
@@ -182,8 +185,12 @@ def main(argv=None):
     item("data")
 
     t_pre = time.perf_counter()
-    warm = model.precompile(data=feed.iter, eval_metric=metric,
-                            kvstore=kvstore)
+    # shapes AND types of a batch as the feeder hands it over, read off its
+    # ring: ``precompile(data=<DataIter>)`` would take token ids for float32
+    spec = [{name: (tuple(a.shape), np.dtype(a.dtype))}
+            for name, a in zip((data_name, label_name), feed.iter.ring[0])]
+    warm = model.precompile(data_shapes=spec[0], label_shapes=spec[1],
+                            eval_metric=metric, kvstore=kvstore)
     precompile_s = time.perf_counter() - t_pre
     _, plan = memory.largest_plan(labels=warm["labels"])
     plan_bytes = memory.program_step_bytes(plan) if plan else None
@@ -265,10 +272,10 @@ def main(argv=None):
     faults += checks.placement_faults(spy, devices, platform)
     if chips > 1:
         faults += checks.replica_faults(spy, devices)
-    images = feed.check_rows(int(config["reference_rows"]))
+    sample_rows = feed.check_rows(int(config["reference_rows"]))
     ref_err = checks.reference_error(
-        mx, model, symbol, config, found["config_path"], images, devices[0],
-        compute_dtype)
+        mx, model, symbol, config, found["config_path"], sample_rows,
+        devices[0], compute_dtype)
     if not ref_err <= float(config["reference_tolerance"]):
         faults.append(f"logits differ from the float32 reference by "
                       f"{ref_err} (relative L2), tolerance "
@@ -276,10 +283,22 @@ def main(argv=None):
     attempted = rows[-1]["steps"] - rows[0]["steps"]
     bad_epochs = sum(not math.isfinite(v) for v in losses[1:])
     failed = spy.raised + bad_epochs * feed.steps_per_epoch
+    # the scope of every instruction of the train program, for the metric
+    # files that read device time by scope: from the executable that is
+    # already warm, after the window and after the count of train programs
+    # above, so that it adds nothing to what decides ``correct``
+    t_hlo = time.perf_counter()
+    text, no_text = checks.train_program_text(spy) if args.trace \
+        else (None, "read with --trace 1 only")
+    hlo_scopes = scopes.hlo_scopes(text) if text else None
+    hlo_text_s = time.perf_counter() - t_hlo
     say({"memory_stats_chip0": stats[0]})
     say({"checks": {"reference_relative_error": ref_err,
                     "compiles_in_window": compiles_in_window,
                     "train_programs": sorted(programs), "faults": faults,
+                    "hlo_text_s": hlo_text_s,
+                    "hlo_instructions": len(hlo_scopes or ()),
+                    "hlo_text_not_read": no_text,
                     "after_window_s": time.perf_counter() - t_end}})
 
     run = {
@@ -293,7 +312,7 @@ def main(argv=None):
         "compiles_in_window": compiles_in_window,
         "flops_per_sample": flops.train_flops_per_sample(
             config["flops_per_sample"]["layers"]),
-        "peak": peak, "trace": reduced,
+        "peak": peak, "trace": reduced, "hlo_scopes": hlo_scopes,
         "traced_epochs": list(range(TRACE_FROM, TRACE_TO)),   # 0-based
     }
     group, folder = ("per_layer", "layer_metrics") if args.trace \
@@ -314,6 +333,25 @@ def main(argv=None):
         device["window_s"] = reduced["window_s"]
         result["breakdown"] = {"device_ops": reduced["top_ops"],
                                "idle_gaps": reduced["idle_gaps"]}
+    # every number compared beside its limit: last in the result's line
+    # and the last lines on standard error
+    result["compared"] = {
+        "reference_relative_error": {
+            "value": ref_err, "limit": float(config["reference_tolerance"])},
+        "last_loss_over_warmup_loss": {
+            "value": losses[-1] / losses[0] if losses[0] else float("nan"),
+            "limit": 1.0},
+        "compiles_in_window": {"value": compiles_in_window, "limit": 0},
+        "train_programs": {"value": len(programs), "limit": 1},
+        "steps_failed": {"value": failed, "limit": 0},
+        "other_faults": {"value": len(faults), "limit": 0},
+    }
+    for fault in faults:
+        print(f"benchmark: fault: {fault}", file=sys.stderr)
+    for name, pair in result["compared"].items():
+        print(f"benchmark: compared {name} = {pair['value']} "
+              f"(limit {pair['limit']})", file=sys.stderr)
+    sys.stderr.flush()
     say(result)
     return 0
 

@@ -10,9 +10,11 @@ HLO instruction ``%fusion.21 = (...) fusion(...)``: cut here to
 ``fusion.21``) and ``Async XLA Ops`` (copies and slices in flight beside
 them), and a plane ``/host:CPU`` with one line per host thread, on which
 the benchmark's own ``jax.profiler.TraceAnnotation`` spans (names starting
-``bench.``) appear. All start times are nanoseconds on one clock. Besides
-the train step, ``fit`` runs three tiny programs a step
-(``jit_convert_element_type``, ``jit__threefry_split``, ``jit__unstack``).
+``bench.``) and the program's (``telemetry.phase()``: ``mx.``) appear. All
+start times are nanoseconds on one clock. Besides the train step, ``fit``
+runs three tiny programs a step (``jit_convert_element_type``,
+``jit__threefry_split``, ``jit__unstack``). The events carry no scope: an
+instruction's is read from the program's HLO text (``scopes.py``).
 
 The train-step program is not looked up by name: it is the module that
 took most device time in the trace, which in a traced training window it
@@ -21,6 +23,7 @@ is by two orders of magnitude.
 
 from __future__ import annotations
 
+import bisect
 import gzip
 import re
 import statistics
@@ -30,7 +33,7 @@ HOST_PLANE = "/host:CPU"
 MODULES_LINE = "XLA Modules"
 OPS_LINE = "XLA Ops"
 ASYNC_LINE = "Async XLA Ops"
-SPAN_PREFIX = "bench."
+SPAN_PREFIX = ("bench.", "mx.")    # the harness's spans, the program's
 COLLECTIVE = re.compile(
     r"^(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute"
     r"|collective-broadcast)")
@@ -122,12 +125,12 @@ def _gaps(intervals, lo, hi):
 
 
 def _name_gap(gap, spans, boundaries):
-    """What the host was doing in an idle gap, as far as the benchmark's
-    own spans can say: the shortest ``bench.`` span that covers the gap's
-    middle, then whether the gap straddles an epoch boundary."""
+    """What the host was doing in an idle gap, as far as the kept spans
+    can say: the shortest span that covers the gap's middle, without its
+    prefix, then whether the gap straddles an epoch boundary."""
     mid = (gap[0] + gap[1]) / 2
     covering = [(d, n) for n, s, d in spans if s <= mid <= s + d]
-    name = min(covering)[1][len(SPAN_PREFIX):] if covering else "untraced"
+    name = min(covering)[1].split(".", 1)[1] if covering else "untraced"
     where = "epoch_tail" if any(gap[0] <= b <= gap[1] for b in boundaries) \
         else "between_steps"
     return f"{name}/{where}"
@@ -167,13 +170,30 @@ def reduce_device(rows, steps_per_epoch, spans=()):
     idle = sorted(_gaps(busy, lo, hi), key=lambda g: g[0] - g[1])[:10]
     out["idle_gaps"] = [[_name_gap(g, spans, boundaries), (g[1] - g[0]) / 1e9]
                         for g in idle]
-    per_op = {}
+    # every instruction's seconds in the traced span, longest first
+    # (``top_ops`` is its head), the steps that span holds, and the same
+    # for the instructions that ran inside an execution of the train
+    # program: an instruction's name is unique in its program only, so a
+    # join with that program's HLO text takes these (``scopes.py``)
+    per_op, own = {}, {}
+    starts = [s for s, _ in steps]
     for name, s, d in ops:
         a, b = max(s, lo), min(s + d, hi)
         if b > a:
             per_op[name] = per_op.get(name, 0.0) + (b - a)
-    out["top_ops"] = [[n, t / 1e9] for n, t in
-                      sorted(per_op.items(), key=lambda kv: -kv[1])[:10]]
+            i = bisect.bisect_right(starts, s) - 1
+            if i >= 0 and s < steps[i][1]:
+                own[name] = own.get(name, 0.0) + (b - a)
+
+    def longest_first(seconds):
+        return {n: t / 1e9 for n, t in
+                sorted(seconds.items(), key=lambda kv: -kv[1])}
+
+    out["op_seconds"] = longest_first(per_op)
+    out["program_op_seconds"] = longest_first(own)
+    out["span_steps"] = n_steps
+    out["top_ops"] = [[n, t] for n, t in
+                      list(out["op_seconds"].items())[:10]]
     is_coll = [bool(COLLECTIVE.match(e[0])) for e in ops]
     coll = _clip([e for e, c in zip(ops, is_coll) if c]
                  + [e for e in rows.get("async", ())
@@ -212,6 +232,9 @@ def reduce(trace, steps_per_epoch):
         "window_s": sum(r["window_s"] for r in per.values()) / chips,
         "busy_s": sum(r["busy_s"] for r in per.values()) / chips,
         "top_ops": first["top_ops"],
+        "op_seconds": first["op_seconds"],
+        "program_op_seconds": first["program_op_seconds"],
+        "span_steps": first["span_steps"],
         "idle_gaps": first["idle_gaps"],
         "collective_ms_per_step": first["collective_ms_per_step"],
         "collective_exposed_ms_per_step":

@@ -19,7 +19,8 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 BENCH = os.path.join(ROOT, "benchmark")
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")   # the contract's
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 
@@ -38,6 +39,8 @@ epochs = _module(os.path.join(BENCH, "epochs.py"), "bench_epochs")
 flops = _module(os.path.join(BENCH, "flops.py"), "bench_flops")
 trace_reduce = _module(os.path.join(BENCH, "trace_reduce.py"),
                        "bench_trace_reduce")
+scopes = _module(os.path.join(BENCH, "scopes.py"), "bench_scopes")
+walk = _module(os.path.join(BENCH, "walk.py"), "bench_walk")
 
 
 def _spawn(cmd, cwd, devices, timeout=600):
@@ -243,37 +246,102 @@ def test_flops_hand_worked_layers_and_totals(bench):
         assert totals["inception_bn"] == pytest.approx(12.196, abs=0.001)
 
 
+def test_flops_matmul_and_attention_by_hand():
+    # a product at every one of 8,192 positions of a sample: 2,048 x 4,096
+    # = 8,388,608 multiply-adds a position, x 8,192 = 68,719,476,736,
+    # 137,438,953,472 FLOP forward
+    proj = {"op": "matmul", "name": "q_proj", "cin": 2048, "cout": 4096,
+            "rows": 8192}
+    assert flops.layer_forward_flops(proj) == 137_438_953_472
+    # rows may be a fraction: the picks one chip's experts are expected to
+    # serve, 8,192 positions x 8 picks x 16 of 64 experts = 16,384 ... and
+    # 8,192 x 8 x 1/3 is no whole number
+    routed = dict(proj, rows=8192 * 8 / 3)
+    assert flops.layer_forward_flops(routed) == pytest.approx(
+        2 * 2048 * 4096 * 8192 * 8 / 3)
+    # causal attention over T = 8: query i sees i + 1 keys, 1 + ... + 8 =
+    # 36 pairs, 4.5 a query = (T + 1) / 2. 2 heads, 16 a key for the
+    # scores and 16 for the values: 2 x 36 x 32 = 2,304 multiply-adds
+    full = {"op": "attention", "name": "attn", "heads": 2, "qk_dim": 16,
+            "v_dim": 16, "q_len": 8, "kv_mean": (8 + 1) / 2}
+    assert flops.layer_forward_flops(full) == 2 * 2304
+    # window W = 3 over T = 8: 1 + 2 + 3 + 3 x 5 = 21 pairs, 2.625 a query
+    # = W - W (W - 1) / (2 T) = 3 - 6 / 16; 2 x 21 x 32 = 1,344
+    assert 3 - 3 * 2 / (2 * 8) == 21 / 8
+    windowed = dict(full, kv_mean=3 - 3 * (3 - 1) / (2 * 8))
+    assert flops.layer_forward_flops(windowed) == 2 * 1344
+    # the window as long as the sequence is the full causal count
+    assert 8 - 8 * 7 / (2 * 8) == (8 + 1) / 2
+    # latent attention: the keys' width and the values' differ
+    assert flops.layer_forward_flops(
+        dict(full, qk_dim=24, v_dim=16)) == 2 * 2 * 36 * 40
+    assert flops.train_flops_per_sample([proj, full]) == \
+        3 * (137_438_953_472 + 4608)
+
+
 def test_layer_lists_match_the_models_the_builders_make(bench):
     """The recipe in a configuration's file is data; the model is code.
-    Walk the built symbol's shapes and require the same layers."""
+    Walk the built symbol's nodes, each by its operator's walker
+    (``benchmark/walkers/``), and require the same layers."""
     for c in bench["configs"]:
         config = catalog.read_json(os.path.join(ROOT, c["file"]))
-        internals = catalog.build_symbol(
-            config["builder"],
-            os.path.dirname(os.path.join(ROOT, c["file"]))).get_internals()
-        arg_shapes, out_shapes, _ = internals.infer_shape(
-            data=(1, *config["image"]))
-        args = dict(zip(internals.list_arguments(), arg_shapes))
-        outs = dict(zip(internals.list_outputs(), out_shapes))
-        walked = []
-        for name, shape in args.items():
-            if not name.endswith("_weight"):
-                continue
-            base = name[:-len("_weight")]
-            if len(shape) == 4:
-                out = outs[base + "_output"]          # NHWC
-                walked.append({"op": "conv", "name": base,
-                               "kernel": [shape[2], shape[3]],
-                               "cin": shape[1], "cout": shape[0],
-                               "out": [out[1], out[2]]})
-            else:
-                walked.append({"op": "fc", "name": base, "cin": shape[1],
-                               "cout": shape[0]})
+        symbol = catalog.build_symbol(
+            config["builder"], os.path.dirname(os.path.join(ROOT, c["file"])))
+        walked = walk.layers_of(symbol, config)
         assert walked == config["flops_per_sample"]["layers"], c["name"]
         if "block_channels" in config:     # the source's own table
-            blocks = [shape[-1] for name, shape in outs.items()
+            internals = symbol.get_internals()
+            _, out_shapes, _ = internals.infer_shape(
+                data=(1, *walk.sample_shape(config)))
+            blocks = [shape[-1] for name, shape in
+                      zip(internals.list_outputs(), out_shapes)
                       if name.endswith("_chconcat_output")]
             assert blocks == config["block_channels"], c["name"]
+
+
+def test_the_walk_goes_by_operator_and_a_walkerless_one_fails_by_name(
+        tmp_path):
+    import mxnet_tpu as mx
+
+    sym = mx.symbol
+    data = sym.Variable("data")
+    # per-position products: FullyConnected on (positions, width) rows is
+    # a matmul with the rows a sample has; Embedding and BatchNorm have
+    # learnable arguments and no product
+    ids_net = sym.FullyConnected(
+        data=sym.Reshape(data=sym.Embedding(
+            data=data, input_dim=50, output_dim=12, name="embed"),
+            target_shape=(-1, 12), name="rows"),
+        num_hidden=20, name="proj")
+    assert walk.layers_of(ids_net, {"input_shape": [6]}) == [
+        {"op": "matmul", "name": "proj", "cin": 12, "cout": 20, "rows": 6}]
+    image_net = sym.FullyConnected(
+        data=sym.Flatten(data=sym.BatchNorm(data=sym.Convolution(
+            data=data, kernel=(3, 3), num_filter=4, pad=(1, 1),
+            name="c1"), name="bn")), num_hidden=5, name="head")
+    assert walk.layers_of(image_net, {"image": [3, 8, 8]}) == [
+        {"op": "conv", "name": "c1", "kernel": [3, 3], "cin": 3, "cout": 4,
+         "out": [8, 8]},
+        {"op": "fc", "name": "head", "cin": 256, "cout": 5}]
+    # a learnable argument under an operator no file accounts for
+    up = sym.Deconvolution(data=data, kernel=(2, 2), num_filter=4,
+                           name="up")
+    with pytest.raises(walk.WalkError) as err:
+        walk.layers_of(up, {"image": [3, 8, 8]})
+    assert "'Deconvolution'" in str(err.value)
+    assert "walkers/Deconvolution.py" in str(err.value)
+    # ... until a later PR adds the file, beside the others, editing none
+    here = tmp_path / "benchmark"
+    shutil.copytree(os.path.join(BENCH, "walkers"), here / "walkers")
+    (here / "walkers" / "Deconvolution.py").write_text(
+        "def layers(node, in_shapes, out_shapes):\n"
+        "    w = in_shapes[node['args'].index('weight')]\n"
+        "    return [{'op': 'conv', 'name': node['name'], 'kernel': "
+        "[w[2], w[3]], 'cin': w[0], 'cout': w[1], "
+        "'out': list(in_shapes[0][2:])}]\n")
+    assert walk.layers_of(up, {"image": [3, 8, 8]}, here=str(here)) == [
+        {"op": "conv", "name": "up", "kernel": [2, 2], "cin": 3, "cout": 4,
+         "out": [8, 8]}]
 
 
 # -- the plain references --------------------------------------------------------
@@ -328,10 +396,12 @@ def test_plain_reference_agrees_with_the_system_in_float32(name):
 
 # -- the trace reduction ---------------------------------------------------------
 
-def _synthetic_trace():
+def _synthetic_trace(pull_op_us=0.0):
     """Two chips' worth of two 4-step epochs: 100 us steps 10 us apart, a
     300 us epoch tail; every step holds a 60 us fusion, a 30 us all-reduce
-    of which 10 us overlap the fusion, and 10 us of nothing."""
+    of which 10 us overlap the fusion, and 10 us of nothing. With
+    ``pull_op_us`` the other program, ``jit_pull``, runs an instruction
+    that has the NAME of the train program's fusion."""
     def line(name, events):
         body = "".join(
             f"events {{ metadata_id: {mid} offset_ps: {int(start * 1e6)} "
@@ -344,14 +414,18 @@ def _synthetic_trace():
     ops = []
     for s in starts:
         ops += [(2, s, 60.0), (3, s + 50.0, 30.0), (2, s + 90.0, 10.0)]
+    if pull_op_us:
+        ops.append((2, starts[3] + 150.0, pull_op_us))
     meta = "".join(
         f'event_metadata {{ key: {k} value {{ id: {k} name: "{n}" }} }}\n'
         for k, n in ((1, "jit_step(7)"), (2, "fusion.1"),
                      (3, "all-reduce.2"), (4, "jit_pull(9)"),
                      (5, "bench.fit.epoch"), (6, "bench.feed.next"),
-                     (7, "other")))
+                     (7, "other"), (8, "mx.fit.epoch.write_back")))
     device = line("XLA Modules", modules) + line("XLA Ops", ops) + meta
-    host = line("main", [(5, -5.0, 745.0), (7, 0.0, 10.0)]) \
+    # the program's own span covers 200 us of the 300 us epoch tail
+    host = line("main", [(5, -5.0, 745.0), (7, 0.0, 10.0),
+                         (8, 480.0, 200.0)]) \
         + line("feed", [(6, 10.0, 1.0)]) + meta
     return ('planes { name: "/device:TPU:0" ' + device + "}\n"
             'planes { name: "/device:TPU:1" ' + device + "}\n"
@@ -365,8 +439,8 @@ def test_trace_reduction_on_a_synthetic_trace():
     events = trace_reduce.events_of(
         ProfileData.from_text_proto(_synthetic_trace()))
     assert sorted(events["devices"]) == [0, 1]
-    assert [s[0] for s in events["spans"]] == ["bench.fit.epoch",
-                                               "bench.feed.next"]
+    assert [s[0] for s in events["spans"]] == [
+        "bench.fit.epoch", "bench.feed.next", "mx.fit.epoch.write_back"]
     r = trace_reduce.reduce(events, steps_per_epoch=4)
     assert r["chips"] == 2 and r["program"] == "jit_step" and r["steps"] == 8
     assert r["device_step_ms_p50"] == pytest.approx(0.1)
@@ -379,11 +453,45 @@ def test_trace_reduction_on_a_synthetic_trace():
     assert r["top_ops"][1] == ["all-reduce.2", pytest.approx(4 * 30e-6)]
     assert r["collective_ms_per_step"] == pytest.approx(0.03)
     assert r["collective_exposed_ms_per_step"] == pytest.approx(0.02)
+    # every instruction's seconds in the traced span, of which top_ops
+    # is the head, and the steps the span holds
+    assert r["op_seconds"] == {"fusion.1": pytest.approx(4 * 70e-6),
+                               "all-reduce.2": pytest.approx(4 * 30e-6)}
+    assert [list(kv) for kv in r["op_seconds"].items()] == r["top_ops"]
+    assert r["program_op_seconds"] == r["op_seconds"]   # one program's only
+    assert r["span_steps"] == 4
+    # a gap is named by the shortest span over its middle: the program's
+    # own (``mx.``) inside the harness's wrapper, each without its prefix
     name, seconds = r["idle_gaps"][0]
-    assert name == "fit.epoch/epoch_tail" and seconds == pytest.approx(300e-6)
+    assert name == "fit.epoch.write_back/epoch_tail"
+    assert seconds == pytest.approx(300e-6)
     assert r["idle_gaps"][1][0] == "fit.epoch/between_steps"
     assert trace_reduce.reduce({"devices": {}, "spans": []}, 4) is None
     assert trace_reduce.union_ns([(0, 2), (1, 3), (5, 6)]) == 4
+
+
+def test_an_instruction_of_another_program_stays_out_of_the_train_programs():
+    """An instruction's name is unique in its program only: what ran
+    outside the train program's executions is in ``op_seconds`` and not in
+    ``program_op_seconds``, which the join with the HLO text reads."""
+    from jax.profiler import ProfileData
+
+    r = trace_reduce.reduce(trace_reduce.events_of(
+        ProfileData.from_text_proto(_synthetic_trace(pull_op_us=4.0))), 4)
+    assert r["busy_s"] == pytest.approx(4 * 90e-6 + 4e-6)
+    assert r["op_seconds"]["fusion.1"] == pytest.approx(4 * 70e-6 + 4e-6)
+    assert r["program_op_seconds"] == {
+        "fusion.1": pytest.approx(4 * 70e-6),
+        "all-reduce.2": pytest.approx(4 * 30e-6)}
+    # the scope join gives the stray 4 us to ``unscoped``, not to the
+    # scope the train program's ``fusion.1`` has
+    run = {"trace": r, "hlo_scopes": {
+        "fusion.1": "jit(step)/jvp(c1/Convolution)/conv_general_dilated",
+        "all-reduce.2": "jit(step)/transpose(jvp(c1/Convolution))/psum"}}
+    assert scopes.bucket_ms_per_step(run, "forward") == pytest.approx(0.07)
+    assert scopes.bucket_ms_per_step(run, "backward") == pytest.approx(0.03)
+    assert scopes.bucket_ms_per_step(run, "unscoped") == pytest.approx(0.001)
+    assert scopes.ms_per_step(run, "c1/Convolution") == pytest.approx(0.1)
 
 
 RECORDED = os.path.join(BENCH, "testdata", "resnet50_device.xplane.pb.gz")
@@ -409,6 +517,162 @@ def test_trace_reduction_on_the_recorded_chip_trace():
     assert [g[0] for g in r["idle_gaps"]] == [g[0] for g in
                                               pinned["idle_gaps"]]
     assert r["collective_ops"] == 0
+    # since PR 26: every instruction of the span, not the ten longest. On
+    # one chip's line no two run at once, so they add up to the busy time
+    ops = r["op_seconds"]
+    assert len(ops) == 4150 and r["span_steps"] == 4
+    assert [list(kv) for kv in list(ops.items())[:10]] == r["top_ops"]
+    assert list(ops.values()) == sorted(ops.values(), reverse=True)
+    assert sum(ops.values()) == pytest.approx(pinned["busy_s"], rel=1e-9)
+    assert ops["custom-call.58"] == pytest.approx(1e-9)
+    # the train program's own: the five instructions of the tiny programs
+    # fit runs a step fall out (a ``fusion.16`` among them, a name that
+    # any program may have)
+    own = r["program_op_seconds"]
+    assert len(own) == 4145 and set(own) <= set(ops)
+    assert sum(own.values()) == pytest.approx(0.4126494, rel=1e-7)
+    assert sorted(set(ops) - set(own)) == [
+        "add_add_fusion", "broadcast_add_fusion", "fusion.16",
+        "pad_add_fusion", "slice_bitcast_fusion"]
+    assert all(own[n] == ops[n] for n in own)
+
+
+# -- device time by scope ----------------------------------------------------------
+
+HLO_TEXT = '''HloModule jit_step, is_scheduled=true
+
+%fused_computation.1 (param_0: f32[8]) -> f32[8] {
+  %param_0 = f32[8]{0} parameter(0)
+  %convert.5 = bf16[8]{0} convert(%param_0), metadata={op_name="jit(step)/jvp()/convert_element_type" stack_frame_id=3}
+  %convert.6 = f32[8]{0} convert(%convert.5)
+  ROOT %bitcast.2 = f32[8]{0} bitcast(%convert.6)
+}
+
+%fused_computation.2 (param_0.1: f32[8], param_1: f32[8]) -> (f32[8], f32[8]) {
+  %param_0.1 = f32[8]{0} parameter(0)
+  %param_1 = f32[8]{0} parameter(1)
+  %mul.3 = f32[8]{0} multiply(%param_0.1, %param_1), metadata={op_name="jit(step)/optimizer/update/mul"}
+  %add.4 = f32[8]{0} add(%mul.3, %param_1), metadata={op_name="jit(step)/optimizer/update/add"}
+  ROOT %tuple.9 = (f32[8]{0}, f32[8]{0}) tuple(%add.4, %mul.3)
+}
+
+ENTRY %main.10 (p0: f32[8], p1: f32[8]) -> (f32[8], f32[8]) {
+  %p0 = f32[8]{0} parameter(0), metadata={op_name="params[\\'w\\']"}
+  %p1 = f32[8]{0} parameter(1)
+  %fusion.1 = f32[8]{0} fusion(%p0), kind=kLoop, calls=%fused_computation.1
+  %fusion.7 = f32[8]{0} fusion(%fusion.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(step)/transpose(jvp(fc1/FullyConnected))/dot_general"}
+  %custom-call.3 = f32[8]{0} custom-call(%fusion.7), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(attn0/FlashAttention)/pallas_call[name=flash_fwd]"}
+  %copy.4 = f32[8]{0} copy(%custom-call.3)
+  ROOT %fusion.2 = (f32[8]{0}, f32[8]{0}) fusion(%copy.4, %p1), kind=kLoop, calls=%fused_computation.2
+}
+'''
+
+
+def test_hlo_scopes_reads_every_instructions_scope_from_the_text():
+    got = scopes.hlo_scopes(HLO_TEXT)
+    # its own op_name first; a fusion without one takes its root's, and a
+    # root that is a bitcast or a tuple the nearest above it
+    assert got["fusion.7"] == \
+        "jit(step)/transpose(jvp(fc1/FullyConnected))/dot_general"
+    assert got["fusion.1"] == "jit(step)/jvp()/convert_element_type"
+    assert got["fusion.2"] == "jit(step)/optimizer/update/add"
+    assert got["custom-call.3"].endswith("pallas_call[name=flash_fwd]")
+    # where the program cannot say: None, not a guess from a neighbour
+    assert got["copy.4"] is None and got["p1"] is None
+    assert got["p0"] == "params[\\'w\\']"
+    assert got["mul.3"] == "jit(step)/optimizer/update/mul"   # nested too
+    assert set(got) >= {"param_0", "convert.5", "tuple.9", "fusion.2"}
+    assert [scopes.bucket(got[n]) for n in
+            ("fusion.1", "fusion.7", "fusion.2", "copy.4", "p0",
+             "custom-call.3")] == ["forward", "backward", "optimizer_unfused",
+                                   "unscoped", "unscoped", "forward"]
+
+
+def test_the_train_programs_text_or_the_reason_it_was_not_read(monkeypatch):
+    import types
+
+    import jax
+
+    monkeypatch.setitem(sys.modules, "catalog", catalog)   # checks imports it
+    checks = _module(os.path.join(BENCH, "checks.py"), "bench_checks")
+
+    class Warm:
+        def __init__(self, answer):
+            self.answer = answer
+
+        def as_text(self):
+            if isinstance(self.answer, Exception):
+                raise self.answer
+            return self.answer
+
+    def spy(*programs):
+        return types.SimpleNamespace(tracked=types.SimpleNamespace(
+            _aot=dict(enumerate(programs))))
+
+    assert checks.train_program_text(spy(Warm(HLO_TEXT))) == (HLO_TEXT, None)
+    for broken, why in (
+            (types.SimpleNamespace(tracked=None), "no _tracked handle"),
+            (spy(), "holds 0 warmed"),
+            (spy(Warm("a"), Warm("b")), "holds 2 warmed"),
+            (spy(Warm(None)), "gives no text"),
+            (spy(Warm(jax.errors.JaxRuntimeError("UNIMPLEMENTED: hidden"))),
+             "UNIMPLEMENTED: hidden")):
+        text, reason = checks.train_program_text(broken)
+        assert text is None and why in reason, reason
+    # anything else is a fault of the harness and is not swallowed
+    with pytest.raises(ZeroDivisionError):
+        checks.train_program_text(spy(Warm(ZeroDivisionError())))
+
+
+def test_ms_per_step_by_scope_and_the_four_buckets_on_hand_written_maps():
+    own = {"fusion.1": 0.120, "fusion.7": 0.200, "custom-call.3": 0.040,
+           "fusion.2": 0.020, "copy.4": 0.012, "all-reduce.5": 0.006}
+    run = {
+        # another program ran a ``convert.9`` and a ``fusion.7`` of its own
+        "trace": {"span_steps": 4, "busy_s": 0.4, "program_op_seconds": own,
+                  "op_seconds": dict(own, **{"fusion.7": 0.2015,
+                                             "convert.9": 0.0005})},
+        "hlo_scopes": {
+            "fusion.1": "jit(step)/jvp(c1/Convolution)/conv_general_dilated",
+            "fusion.7": "jit(step)/transpose(jvp(c1/Convolution))/"
+                        "conv_general_dilated",
+            "custom-call.3": "jit(step)/jvp(attn0/FlashAttention)/"
+                             "pallas_call[name=flash_fwd]",
+            "fusion.2": "jit(step)/optimizer/update/add",
+            "copy.4": None,
+            "all-reduce.5": "jit(step)/comm/allreduce/psum",
+            "never_ran.1": "jit(step)/jvp(c2/Convolution)/mul"},
+    }
+    by = {b: scopes.bucket_ms_per_step(run, b) for b in scopes.BUCKETS}
+    assert by == {"forward": pytest.approx(40.0),     # 120 + 40 over 4
+                  "backward": pytest.approx(50.0),
+                  "optimizer_unfused": pytest.approx(5.0),
+                  "unscoped": pytest.approx(5.0)}     # 12 + 6 + 2 over 4
+    # disjoint, and together every instruction that ran: the busy time
+    assert sum(by.values()) == pytest.approx(1e3 * 0.4 / 4)
+    # one kernel, one layer or one phase, each instruction counted once
+    assert scopes.ms_per_step(run, r"attn0/FlashAttention") == \
+        pytest.approx(10.0)
+    assert scopes.ms_per_step(run, r"pallas_call\[name=flash_fwd\]") == \
+        pytest.approx(10.0)
+    assert scopes.ms_per_step(run, r"c1/Convolution") == pytest.approx(80.0)
+    assert scopes.ms_per_step(run, r"Convolution|c1/") == \
+        pytest.approx(80.0)
+    # nothing to read is nothing, never 0
+    assert scopes.ms_per_step(run, r"c2/Convolution") is None
+    assert scopes.ms_per_step({"trace": None, "hlo_scopes": {}}, "x") is None
+    assert scopes.bucket_ms_per_step(dict(run, hlo_scopes=None),
+                                     "forward") is None
+    lone = {"trace": {"span_steps": 1, "op_seconds": {"copy.1": 0.5},
+                      "program_op_seconds": {"copy.1": 0.5}},
+            "hlo_scopes": {"copy.1": None}}
+    assert scopes.bucket_ms_per_step(lone, "optimizer_unfused") is None
+    assert scopes.bucket_ms_per_step(lone, "unscoped") == pytest.approx(500.0)
+    # the four metric files read the four buckets
+    for b in scopes.BUCKETS:
+        metric = catalog.load_metric("layer_metrics", b + "_ms_per_step")
+        assert metric.read(run) == by[b]
+        assert metric.read({"trace": None, "hlo_scopes": None}) is None
 
 
 # -- the runner, in a child process, on added files only -------------------------
@@ -445,6 +709,64 @@ def read(run):
 '''
 
 
+# a configuration whose sample is a sequence of token ids, made of added
+# files only: ids -> Embedding 64 x 16 -> (rows x positions, 16) -> two
+# products a position -> softmax over the 64 ids on (rows x positions,)
+# labels, built from operators the program has
+TOKENS_CONFIG = {
+    "name": "tiny_tokens",
+    "source": "test preset: ids, an embedding and two products a position",
+    "sample": "one sequence of 8 token ids",
+    "builder": {"file": "tiny_tokens_model.py", "call": "build",
+                "kwargs": {"vocab": 64, "width": 16, "hidden": 32}},
+    "input_shape": [8],
+    "vocab_rows": 64,
+    "per_chip_batch": 8,
+    "compute_dtype": "bfloat16",
+    "optimizer": {"name": "sgd", "learning_rate": 0.05, "momentum": 0.9},
+    "initializer": {"name": "Xavier"},
+    "logits": "head_output",
+    "reference": "tiny_tokens.py",
+    "reference_rows": 8,
+    "reference_tolerance": 0.1,
+    "reduced": [],
+    "flops_per_sample": {"layers": [
+        {"op": "matmul", "name": "hidden", "cin": 16, "cout": 32, "rows": 8},
+        {"op": "matmul", "name": "head", "cin": 32, "cout": 64, "rows": 8}]},
+}
+TOKENS_BUILDER = '''
+import mxnet_tpu as mx
+
+
+def build(vocab, width, hidden):
+    sym = mx.symbol
+    rows = sym.Reshape(
+        data=sym.Embedding(data=sym.Variable("data"), input_dim=vocab,
+                           output_dim=width, name="embed"),
+        target_shape=(-1, width), name="rows")
+    hidden = sym.Activation(
+        data=sym.FullyConnected(data=rows, num_hidden=hidden, name="hidden"),
+        act_type="relu", name="hidden_relu")
+    head = sym.FullyConnected(data=hidden, num_hidden=vocab, name="head")
+    return sym.SoftmaxOutput(
+        data=head, name="softmax",
+        label=sym.Reshape(data=sym.Variable("softmax_label"),
+                          target_shape=(-1,), name="label_rows"))
+'''
+TOKENS_REFERENCE = '''
+import jax.numpy as jnp
+
+
+def logits(params, aux, ids):
+    table = params["embed_weight"]
+    x = table[ids].reshape(-1, table.shape[1])
+    h = jnp.maximum(x @ params["hidden_weight"].T + params["hidden_bias"], 0)
+    return h @ params["head_weight"].T + params["head_bias"]
+'''
+TOKEN_RING = {"kind": "token_ring", "ring": 3, "steps_per_epoch": 4,
+              "warmup_steps": 3, "follow_p": 0.5}
+
+
 @pytest.fixture(scope="module")
 def overlay(tmp_path_factory, bench):
     """A checkout-shaped directory: ``benchmark/`` copied as it is, plus
@@ -465,18 +787,33 @@ def overlay(tmp_path_factory, bench):
         {"kind": "device_ring", "ring": 3, "steps_per_epoch": 4,
          "dtype": "float32", "class_shift": 0.5}))
     (here / "layer_metrics" / "steps_in_window.py").write_text(STEPS_METRIC)
+    (here / "configs" / "tiny_tokens.json").write_text(
+        json.dumps(TOKENS_CONFIG))
+    (here / "configs" / "tiny_tokens_model.py").write_text(TOKENS_BUILDER)
+    (here / "configs" / "tiny_tokens.py").write_text(TOKENS_REFERENCE)
+    (here / "traffic" / "tiny_token_ring.json").write_text(
+        json.dumps(TOKEN_RING))
+    (here / "traffic" / "tiny_token_ring_dp.json").write_text(
+        json.dumps(dict(TOKEN_RING, ring=2)))
     added = dict(bench)
     added["configs"] = bench["configs"] + [
         {"name": "tiny_resnet", "source": TINY_CONFIG["source"],
          "file": "benchmark/configs/tiny_resnet.json", "reduced": [],
+         "why": "test preset"},
+        {"name": "tiny_tokens", "source": TOKENS_CONFIG["source"],
+         "file": "benchmark/configs/tiny_tokens.json", "reduced": [],
          "why": "test preset"}]
     added["workloads"] = bench["workloads"] + [
         {"name": "tiny.device", "config": "tiny_resnet",
          "traffic": "tiny_ring", "chips": 1, "why": "test"},
         {"name": "tiny.dp4", "config": "tiny_resnet",
-         "traffic": "tiny_ring_dp", "chips": 4, "why": "test"}]
+         "traffic": "tiny_ring_dp", "chips": 4, "why": "test"},
+        {"name": "tiny_tokens.device", "config": "tiny_tokens",
+         "traffic": "tiny_token_ring", "chips": 1, "why": "test"},
+        {"name": "tiny_tokens.dp4", "config": "tiny_tokens",
+         "traffic": "tiny_token_ring_dp", "chips": 4, "why": "test"}]
     added["per_layer"] = [
-        dict(m, workloads=m["workloads"] + ["tiny.dp4"])
+        dict(m, workloads=m["workloads"] + ["tiny.dp4", "tiny_tokens.dp4"])
         if "workloads" in m else m for m in bench["per_layer"]] + [
         dict(catalog.load_file_module(
             str(here / "layer_metrics" / "steps_in_window.py"),
@@ -569,3 +906,153 @@ def test_runner_exits_nonzero_without_a_tpu(overlay):
                 devices=2)
     assert proc.returncode != 0 and "asks for 4" in proc.stderr
     assert not [ln for ln in proc.stdout.splitlines() if ln.startswith("{")]
+
+
+# -- a sequence model by files and entries alone ---------------------------------
+
+@pytest.mark.parametrize("cell,trace", [
+    ("tiny_tokens.device", 0), ("tiny_tokens.device", 1),
+    ("tiny_tokens.dp4", 0), ("tiny_tokens.dp4", 1)])
+def test_runner_on_a_token_configuration_made_of_added_files(overlay, cell,
+                                                             trace):
+    """Ids in, ``input_shape``, ``matmul`` layers, a ``token_ring`` mix:
+    files and entries only, and no line of the runner names them."""
+    proc = _run(overlay, "--workload", cell, "--seed", str(2 ** 31 + 26),
+                "--seconds", "0.5", "--trace", str(trace),
+                "--rehearse-on-cpu")
+    result, earlier = _result(proc)
+    assert result["correct"] is True, (earlier, proc.stderr[-2000:])
+    assert result["failed"] == 0 and result["attempted"] >= 4
+    assert result["attempted"] % 4 == 0
+    assert result["device"]["count"] == (4 if cell.endswith("dp4") else 1)
+    # every number compared, beside its limit: last in the line and last
+    # on standard error
+    assert list(result)[-1] == "compared"
+    compared = result["compared"]
+    assert all(set(pair) == {"value", "limit"} for pair in compared.values())
+    assert compared["last_loss_over_warmup_loss"]["value"] < 1.0   # it fell
+    assert compared["reference_relative_error"]["value"] < 0.1     # bf16
+    assert compared["train_programs"] == {"value": 1, "limit": 1}
+    assert compared["compiles_in_window"] == {"value": 0, "limit": 0}
+    tail = proc.stderr.strip().splitlines()[-len(compared):]
+    assert [ln.split()[2] for ln in tail] == list(compared), tail
+    losses = [e for e in earlier if "epochs" in e][0]
+    assert losses["warmup_loss"] > losses["epochs"][-1]["loss"]
+    # untrained, the loss is ln 64 = 4.16; the chain is learnable
+    assert losses["warmup_loss"] == pytest.approx(4.16, abs=0.15)
+    got = set(result["metrics"])
+    if trace == 0:
+        assert got == {"samples_per_s_per_chip", "setup_s"}
+    else:
+        assert {"epoch_rate_median", "precompile_s", "compiles_in_window",
+                "write_back_ms", "host_step_ms_p10"} <= got
+        # a CPU trace has no device plane: nothing to read is nothing
+        assert not got & {"forward_ms_per_step", "backward_ms_per_step",
+                          "optimizer_unfused_ms_per_step",
+                          "unscoped_ms_per_step",
+                          "device_step_ms", "mfu_device"}
+        # ... but the train program's text was read, after the window
+        checks = [e for e in earlier if "checks" in e][0]["checks"]
+        assert checks["hlo_instructions"] > 50 and checks["hlo_text_s"] < 5
+        assert checks["hlo_text_not_read"] is None
+
+
+TOKENS_SCRIPT = '''
+import os, sys
+import numpy as np
+bench = sys.argv[1]
+sys.path.insert(0, bench)
+import catalog, flops, scopes, walk
+import jax
+import mxnet_tpu as mx
+
+config = catalog.read_json(os.path.join(bench, "configs", "tiny_tokens.json"))
+symbol = catalog.build_symbol(config["builder"],
+                              os.path.join(bench, "configs"))
+# the walk, by the operators' walkers, gives the file's list
+assert walk.layers_of(symbol, config) == config["flops_per_sample"]["layers"]
+assert flops.train_flops_per_sample(
+    config["flops_per_sample"]["layers"]) == 3 * 2 * 8 * (16 * 32 + 32 * 64)
+# the feeder: seeded, ids and next-token labels, int32, in range
+traffic = catalog.read_json(os.path.join(bench, "traffic",
+                                         "tiny_token_ring.json"))
+feeder = catalog.load_feeder(traffic["kind"])
+feed = feeder.make(traffic, config, jax.devices()[:1], 2 ** 31 + 5, "data",
+                   "softmax_label")
+again = feeder.make(traffic, config, jax.devices()[:1], 2 ** 31 + 5, "data",
+                    "softmax_label")
+other = feeder.make(traffic, config, jax.devices()[:1], 7, "data",
+                    "softmax_label")
+assert feed.batch_rows == 8 and feed.steps_per_epoch == 4
+ring = [(np.asarray(x), np.asarray(y)) for x, y in feed.iter.ring]
+assert len(ring) == 3
+for (x, y), (x2, y2) in zip(ring, again.iter.ring):
+    assert x.dtype == y.dtype == np.int32 and x.shape == y.shape == (8, 8)
+    assert x.min() >= 0 and x.max() < 64 and y.min() >= 0 and y.max() < 64
+    assert (x[:, 1:] == y[:, :-1]).all()          # the label is the next id
+    assert (x == np.asarray(x2)).all() and (y == np.asarray(y2)).all()
+assert not (ring[0][0] == ring[1][0]).all()       # distinct batches
+assert not (ring[0][0] == np.asarray(other.iter.ring[0][0])).all()
+assert (feed.check_rows(5) == ring[0][0][:5]).all()
+assert feed.iter.provide_data == [("data", (8, 8))]
+assert feed.iter.provide_label == [("softmax_label", (8, 8))]
+# about half the steps follow the seeded successor: one successor an id
+pairs = np.concatenate([np.stack([x.ravel(), y.ravel()], 1) for x, y in ring])
+best = {}
+for a, b in pairs:
+    best.setdefault(int(a), []).append(int(b))
+followed = sum(max(np.bincount(v)) for v in best.values()) / len(pairs)
+assert 0.35 < followed < 0.75, followed
+# float32 on the CPU, through the comparison that decides ``correct``: the
+# system and the plain reference agree to rounding over ALL 64 positions
+# (8 sequences of 8), and one wrong position is seen wherever it is
+import checks
+mx.random.seed(0)
+model = mx.FeedForward(symbol, ctx=mx.cpu(), initializer=mx.init.Xavier())
+ids = feed.check_rows(8)
+model._init_params({"data": ids.shape, "softmax_label": ids.shape})
+config_path = os.path.join(bench, "configs", "tiny_tokens.json")
+device = jax.devices()[0]
+err = checks.reference_error(mx, model, symbol, config, config_path, ids,
+                             device, None)
+assert err < 1e-5, err
+reference = open(os.path.join(bench, "configs", config["reference"])).read()
+wrong_dir = sys.argv[2]
+for position in (0, 7, 8, 37, 63):        # 63: last of the last sequence
+    with open(os.path.join(wrong_dir, f"wrong_{position}.py"), "w") as f:
+        f.write(reference.replace(
+            "return h @", f"return jnp.zeros((64, 64)).at[{position}]"
+            ".set(1.0) + h @"))
+    planted = checks.reference_error(
+        mx, model, symbol, dict(config, reference=f"wrong_{position}.py"),
+        os.path.join(wrong_dir, "x.json"), ids, device, None)
+    assert planted > 0.05, (position, planted)
+# fewer rows than were sent is a mismatch, not a shorter comparison
+short = checks.reference_error(mx, model, symbol, config, config_path,
+                               ids[:4], device, None)
+assert short < 1e-5, short                # 4 sequences: 32 positions, all
+with open(os.path.join(wrong_dir, "half.py"), "w") as f:
+    f.write(reference.replace("return h @", "return (h @")
+            .rstrip() + ")[:32]")
+assert checks.reference_error(
+    mx, model, symbol, dict(config, reference="half.py"),
+    os.path.join(wrong_dir, "x.json"), ids, device, None) == float("inf")
+print("relative error", err, "positions", 64)
+'''
+
+
+def test_token_preset_walk_feeder_and_float32_reference(overlay, tmp_path):
+    proc = _spawn([sys.executable, "-c", TOKENS_SCRIPT,
+                   str(overlay / "benchmark"), str(tmp_path)],
+                  cwd=str(overlay), devices=1)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert "relative error" in proc.stdout
+
+
+def test_no_line_of_the_harness_names_the_token_preset():
+    for name in ("run.py", "checks.py", "catalog.py", "walk.py", "scopes.py",
+                 "flops.py", "trace_reduce.py",
+                 os.path.join("feeds", "token_ring.py")):
+        with open(os.path.join(BENCH, name), encoding="utf-8") as f:
+            text = f.read()
+        assert "tiny_tokens" not in text and "tiny_resnet" not in text, name
