@@ -36,10 +36,18 @@ mx.symbol.LinearRegressionOutput <- function(...) mx.symbol.op("LinearRegression
 mx.symbol.LogisticRegressionOutput <- function(...) mx.symbol.op("LogisticRegressionOutput", ...)
 # MAERegressionOutput: Identity forward, sign(pred - label) gradient (L1 regression head;
 mx.symbol.MAERegressionOutput <- function(...) mx.symbol.op("MAERegressionOutput", ...)
+# MixtureOfExperts: Sparse gated feed-forward layer, one rank's share of its experts.
+mx.symbol.MixtureOfExperts <- function(...) mx.symbol.op("MixtureOfExperts", ...)
 # Pooling: Max/avg/sum pooling, NCHW or NHWC per ``layout`` (reference:
 mx.symbol.Pooling <- function(...) mx.symbol.op("Pooling", ...)
+# RMSNorm: Root-mean-square normalisation over the last axis with a learnable
+mx.symbol.RMSNorm <- function(...) mx.symbol.op("RMSNorm", ...)
+# RematBoundary: Identity. Marks the end of a recomputation segment: the executor
+mx.symbol.RematBoundary <- function(...) mx.symbol.op("RematBoundary", ...)
 # Reshape: Reshape to ``target_shape`` (reference: reshape-inl.h; first dim 0 k
 mx.symbol.Reshape <- function(...) mx.symbol.op("Reshape", ...)
+# RotaryAttention: Causal grouped-query attention on projected rows.
+mx.symbol.RotaryAttention <- function(...) mx.symbol.op("RotaryAttention", ...)
 # SliceChannel: Split along axis 1 into ``num_outputs`` equal parts (reference:
 mx.symbol.SliceChannel <- function(...) mx.symbol.op("SliceChannel", ...)
 # SoftmaxOutput: Softmax forward + cross-entropy gradient injection (reference:

@@ -41,6 +41,7 @@ from . import random as _random
 from .base import MXNetError
 from .context import Context, current_context
 from .ndarray import NDArray, zeros
+from .ops.registry import REMAT_KEEP
 from .utils import compile as compile_mod
 
 __all__ = ["Executor", "simple_bind"]
@@ -114,19 +115,26 @@ def _fusion_plan(symbol):
 def _remat_segments(nodes):
     """Partition the topo order into rematerialization segments.
 
-    ``MXNET_TPU_REMAT`` is a regex; every compute node whose name matches
-    CLOSES a segment (the node is the segment's last member). Each closed
-    segment executes under ``jax.checkpoint``: its interior activations are
-    recomputed in the backward pass instead of being saved, trading MXU
-    FLOPs for HBM traffic — the remaining lever on a bandwidth-bound model
-    (doc/performance.md roofline: activations crossing HBM dominate the
-    step; compute floor sits ~3x below the memory floor). For the ResNet
-    zoo the unit-output relus are the natural boundaries:
-    ``MXNET_TPU_REMAT='unit\\d+_out$'`` saves only the per-unit residual
-    streams. The trailing run after the last boundary (head: pool/fc/loss)
-    stays inline.
+    Every compute node that is a boundary CLOSES a segment (the node is
+    the segment's last member). Each closed segment executes under
+    ``jax.checkpoint``: its interior activations are recomputed in the
+    backward pass instead of being saved, trading MXU FLOPs for HBM
+    traffic and footprint. Two things make a node a boundary:
 
-    Returns None when the env var is unset/empty, else a list of
+    - the Symbol says so: the node's operator has ``closes_remat_segment``
+      (``RematBoundary``, an identity a model builder puts where a segment
+      should end: ``models.laguna`` after every decoder layer, where a
+      layer's interior is about 1 GB at 8,192 positions);
+    - ``MXNET_TPU_REMAT``, a regex over node names, for a Symbol that
+      carries no marker. For the ResNet zoo the unit-output relus are the
+      natural boundaries: ``MXNET_TPU_REMAT='unit\\d+_out$'`` saves only
+      the per-unit residual streams (doc/performance.md roofline:
+      activations crossing HBM dominate the step).
+
+    The trailing run after the last boundary (head: pool/fc/loss) stays
+    inline.
+
+    Returns None when there is no boundary at all, else a list of
     ``('inline', topo_idx, node) | ('blk', [(topo_idx, node), ...])``
     segments; each block's external inputs and exports are resolved by
     _build_graph_fn. Variables never join blocks — their env seeds are
@@ -138,9 +146,14 @@ def _remat_segments(nodes):
     from .base import env_str
 
     pat = env_str("MXNET_TPU_REMAT", "")
-    if not pat:
+    rx = re.compile(pat) if pat else None
+
+    def closes(node):
+        return getattr(node.op, "closes_remat_segment", False) \
+            or (rx is not None and rx.search(node.name))
+
+    if rx is None and not any(closes(n) for n in nodes if not n.is_variable):
         return None
-    rx = re.compile(pat)
 
     runs = []  # ('inline', idx, node) | ('blk', [(idx, node), ...])
     cur = []
@@ -149,7 +162,7 @@ def _remat_segments(nodes):
             runs.append(("inline", i, node))
             continue
         cur.append((i, node))
-        if rx.search(node.name):
+        if closes(node):
             runs.append(("blk", cur))
             cur = []
     for i, node in cur:  # tail after the last boundary: head ops, inline
@@ -314,7 +327,13 @@ def _build_graph_fn(symbol, is_train: bool):
             return (tuple(env[r] for r in out_refs),
                     tuple(new_aux.get(a, aux_in[a]) for a in aux_names))
 
-        return jax.checkpoint(block_fn)
+        # what an operator names REMAT_KEEP is kept across the boundary
+        # and not recomputed (ops/pallas/flash_attention.py keeps its
+        # output and row statistics: recomputing them is the forward
+        # kernel again); everything else is, as without a policy
+        return jax.checkpoint(
+            block_fn,
+            policy=jax.checkpoint_policies.save_only_these_names(REMAT_KEEP))
 
     compiled_blocks = []
     for seg in blocks:

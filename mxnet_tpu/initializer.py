@@ -41,6 +41,8 @@ class Initializer:
             self._init_one(name, arr)
         elif name.endswith("moving_avg"):
             self._init_zero(name, arr)
+        elif name.endswith("expert_load"):  # MixtureOfExperts' pick counts
+            self._init_zero(name, arr)
         else:
             self._init_default(name, arr)
 
@@ -112,8 +114,13 @@ class Xavier(Initializer):
 
     def _init_weight(self, _name, arr):
         shape = arr.shape
-        fan_out = shape[0]
-        fan_in = int(np.prod(shape[1:])) if len(shape) > 1 else shape[0]
+        if len(shape) == 3:
+            # a stack of (out, in) matrices (MixtureOfExperts' experts):
+            # each expert's own fan, whatever the number stacked
+            fan_out, fan_in = shape[1], shape[2]
+        else:
+            fan_out = shape[0]
+            fan_in = int(np.prod(shape[1:])) if len(shape) > 1 else shape[0]
         if self.factor_type == "avg":
             factor = (fan_in + fan_out) / 2.0
         elif self.factor_type == "in":

@@ -729,7 +729,12 @@ class FeedForward(BASE_ESTIMATOR):
                     p_c = {k: (v.astype(compute_dtype)
                                if jnp.issubdtype(v.dtype, jnp.floating) else v)
                            for k, v in p.items()}
-                    b_c = {k: (v.astype(compute_dtype) if k in data_names else v)
+                    # floating data only: integer ids above 256 are not
+                    # whole numbers in bfloat16 (predict and eval do the same)
+                    b_c = {k: (v.astype(compute_dtype)
+                               if k in data_names
+                               and jnp.issubdtype(v.dtype, jnp.floating)
+                               else v)
                            for k, v in batch.items()}
                 else:
                     p_c, b_c = p, batch
@@ -1796,6 +1801,32 @@ class FeedForward(BASE_ESTIMATOR):
                 self.arg_params.update(zip(param_names, landed[:n]))
                 self.aux_params.update(zip(aux_names, landed[n:]))
 
+        # an operator may have a line to say an epoch about its auxiliary
+        # states (OpProp.epoch_record; an expert layer's load): once an
+        # epoch, with the write-back landed on the host, one zero-length
+        # record a node. What is kept between calls are the landed arrays
+        # themselves, read only when the record is made
+        recorders = [(n.name, n.op) for n in self.symbol._topo()
+                     if not n.is_variable
+                     and hasattr(n.op, "epoch_record")] \
+            if self.symbol is not None else []
+
+        def _node_aux(name, op):
+            return [self.aux_params[f"{name}_{a}"]
+                    for a in op.list_auxiliary_states()]
+
+        aux_seen = {name: _node_aux(name, op) for name, op in recorders}
+
+        def _epoch_records():
+            for name, op in recorders:
+                before, aux_seen[name] = aux_seen[name], _node_aux(name, op)
+                span, attrs = op.epoch_record(
+                    [a.asnumpy() for a in before],
+                    [a.asnumpy() for a in aux_seen[name]])
+                with telemetry_mod.phase(span, epoch=epoch, node=name,
+                                         **attrs):
+                    pass
+
         def _guard_meta():
             if guard_cfg is None:
                 return {}
@@ -2724,6 +2755,7 @@ class FeedForward(BASE_ESTIMATOR):
                         logger=logger)
 
                 _write_back()
+                _epoch_records()
 
                 if mem_prev is not None:
                     # close the epoch's watermark window: emits the
@@ -3240,7 +3272,7 @@ class FeedForward(BASE_ESTIMATOR):
                     jax.block_until_ready(outs)
                     span.mark("host")
                 nv = rows - batch.pad  # valid rows of the pre-padding batch
-                outs = [NDArray(o[:nv] if nv != o.shape[0] else o)
+                outs = [NDArray(_valid_rows(o, max(rows, target), nv))
                         for o in outs]
                 labels = [NDArray(l.data[:nv] if nv != l.shape[0]
                                   else l.data) for l in batch.label]
@@ -3319,7 +3351,7 @@ class FeedForward(BASE_ESTIMATOR):
             nv = rows - batch.pad
             # predict materializes host outputs by contract; the pull is
             # the product, not an accident
-            outs = [np.asarray(o[:nv] if nv != o.shape[0] else o)  # mxlint: disable=MX309
+            outs = [np.asarray(_valid_rows(o, max(rows, target), nv))  # mxlint: disable=MX309
                     for o in outs]
             if chunks is None:
                 chunks = [[] for _ in outs]
@@ -3399,6 +3431,15 @@ class FeedForward(BASE_ESTIMATOR):
                   batch_end_callback=batch_end_callback, kvstore=kvstore,
                   logger=logger, batch_size=batch_size)
         return model
+
+
+def _valid_rows(out, fed, valid):
+    """An output of a batch fed as ``fed`` rows, cut to its first ``valid``
+    rows. An output with several entries a row (logits of ``(rows x
+    positions, classes)``) keeps every entry of every valid row."""
+    lead = out.shape[0]
+    per_row = lead // fed if fed and lead % fed == 0 else 1
+    return out if valid == fed else out[:valid * per_row]
 
 
 def _pad_rows_np(arrays: dict, extra: int) -> dict:

@@ -12,8 +12,9 @@ from .lstm import lstm_unroll, LSTMState, LSTMParam
 from .lstm_scan import LSTMLM
 from .transformer import TransformerLM, transformer_lm_config
 from .moe_transformer import MoEPipelineLM, moe_pipeline_config
+from .laguna import laguna
 
 __all__ = ["mlp", "lenet", "alexnet", "inception_bn_cifar", "inception_bn",
            "resnet", "resnet50", "lstm_unroll", "LSTMState", "LSTMParam",
            "LSTMLM", "TransformerLM", "transformer_lm_config",
-           "MoEPipelineLM", "moe_pipeline_config"]
+           "MoEPipelineLM", "moe_pipeline_config", "laguna"]

@@ -318,7 +318,8 @@ class ActivationOp(OpProp):
     """Elementwise activations (reference: activation-inl.h + mshadow_op.h)."""
 
     params = {
-        "act_type": (("relu", "sigmoid", "tanh", "softrelu"), REQUIRED, "activation kind")
+        "act_type": (("relu", "sigmoid", "tanh", "softrelu", "silu"), REQUIRED,
+                     "activation kind")
     }
 
     def fwd(self, ins, aux, is_train, rng):
@@ -329,6 +330,8 @@ class ActivationOp(OpProp):
             y = jax.nn.sigmoid(x)
         elif self.act_type == "tanh":
             y = jnp.tanh(x)
+        elif self.act_type == "silu":  # x * sigmoid(x)
+            y = jax.nn.silu(x)
         else:  # softrelu = log(1 + exp(x))
             y = jax.nn.softplus(x)
         return [y], []

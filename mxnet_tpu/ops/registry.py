@@ -29,6 +29,10 @@ __all__ = ["OpProp", "OPS", "register_op", "REQUIRED", "Range", "TupleParam"]
 
 OPS = Registry("operator")
 
+# ``jax.ad_checkpoint.checkpoint_name`` under which an operator marks a value
+# that a recomputation segment of the executor keeps rather than recomputes
+REMAT_KEEP = "mxnet_tpu.remat_keep"
+
 
 class OpProp:
     """Base class for operator properties (metadata + pure-fn kernel).
@@ -39,6 +43,15 @@ class OpProp:
       infer_shape(in_shapes) -> (in_shapes, out_shapes, aux_shapes)
       fwd(ins, aux, is_train, rng) -> (outs, new_aux)
       need_rng     : True if fwd consumes randomness in training mode
+      epoch_record(before, after) -> (span name, attrs)   (optional)
+                   an operator whose auxiliary states are worth a line an
+                   epoch defines it: ``fit`` calls it once an epoch, after
+                   the write-back, with the node's auxiliary states as
+                   numpy arrays (``list_auxiliary_states`` order) as they
+                   were at the previous call (at ``fit``'s start for the
+                   first) and as they are now, and emits one zero-length
+                   telemetry record of that name with ``epoch``, ``node``
+                   and the attrs. ``fit`` knows no operator by name.
     """
 
     params: dict = {}

@@ -61,6 +61,34 @@ def _flash_case(S):
     return jax.grad(loss, argnums=(0, 1, 2)), (q, q, q)
 
 
+def _decoder_attention_case(heads, window, yarn):
+    """The attention operator of the sparse decoder at its published
+    widths (``laguna_xs2.seq8k``): 8,192 positions, heads of 128 over 8
+    key-value heads, the window of 512 or none, the kernels rotating the
+    queries and applying the head gate on rows as the projections leave
+    them. Forward and both backward kernels."""
+    def build(S):
+        from mxnet_tpu.ops import OPS
+
+        rope = dict(rotary_dim=64, rope_theta=500000.0, rope_type="yarn",
+                    rope_factor=64.0, rope_beta_fast=64.0,
+                    rope_attention_factor=1.4158883083359672) if yarn \
+            else dict(rotary_dim=128)
+        op = OPS.create("RotaryAttention", seq_len=8192, num_heads=heads,
+                        num_kv_heads=8, head_dim=128, window=window,
+                        gated=True, **rope)
+        q = S((8192, heads * 128), jnp.bfloat16)
+        kv = S((8192, 8 * 128), jnp.bfloat16)
+
+        def loss(q, k, v, g):
+            return jnp.sum(op.fwd([q, k, v, g], [], True, None)[0][0]
+                           .astype(jnp.float32))
+
+        return jax.grad(loss, argnums=(0, 1, 2, 3)), \
+            (q, kv, kv, S((8192, heads), jnp.bfloat16))
+    return build
+
+
 def _quant_case(mode):
     def build(S):
         spec = comm.CompressionSpec(mode)
@@ -88,6 +116,10 @@ def _int8_mm_case(S):
 
 CASES = {
     "flash": (_flash_case, {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    "flash_decoder_full": (_decoder_attention_case(48, 0, True),
+                           {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    "flash_decoder_window": (_decoder_attention_case(64, 512, False),
+                             {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     "fused_adam": (_adam_case, {"fused_adam"}),
     "quant_int8": (_quant_case("int8"), {"quant_int8"}),
     "quant_twobit": (_quant_case("twobit"), {"quant_twobit"}),
@@ -109,8 +141,11 @@ def test_cases_cover_the_registry():
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_kernel_compiles_for_v5e(v5e, case):
+def test_kernel_compiles_for_v5e(v5e, case, monkeypatch):
     build, names = CASES[case]
+    # an operator has no ``interpret`` argument: off the chip the gate
+    # builds the Mosaic program only when told to
+    monkeypatch.setenv("MXNET_TPU_PALLAS_INTERPRET", "0")
 
     def S(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
