@@ -11,25 +11,36 @@ Replaces the dense ``attention_reference`` einsum path wherever attention is
 the hot op (models/transformer.py); numerics are validated against the dense
 path in tests/test_pallas.py on CPU via interpret mode.
 
-On-chip rates (TPU v5e via tools/bench_flash.py, bf16 operands, s=16k,
-full sweep in FLASH_r03.json; measured bf16 matmul peak 172 TF/s): d=128
-fwd 136 TF/s (79% of matmul peak) / fwd+bwd 133 TF/s at the default
-(block_q=512, block_k=2048); d=64 tops out at 68 TF/s fwd — the QK^T
-contraction dim is half the MXU's 128 lanes, so half rate is the ceiling.
-bf16 numerics vs dense f32: max abs err ~1e-3 fwd, rel ~0.5% on grads.
+On-chip rates, block and sub-tile shapes as swept on a TPU v5e: PERF.md
+(sections 5 and 7). bf16 numerics vs dense f32: max abs err ~1e-3 fwd, rel
+~0.5% on grads.
 
 Grouped heads and windows: ``k``/``v`` may carry fewer heads than ``q``
 (query head ``i`` reads key-value head ``i // (hq / hkv)``; the index maps
 do the sharing, nothing is repeated in HBM), and ``window=W`` with
-``causal=True`` lets query ``t`` see keys ``t - W + 1 .. t``. Key blocks
-outside the causal band or the window are SKIPPED, not masked: the key
-dimension of the grid only spans the blocks a query block can need
-(``_kv_steps``), the index maps clamp to the last needed block so that a
-skipped step moves nothing, and the body runs under ``pl.when``. The
-backward kernels do the same over query blocks; ``flash_bwd_dkv``
+``causal=True`` lets query ``t`` see keys ``t - W + 1 .. t``. The band (the
+causal diagonal, the window's far edge, the padding of the last key block)
+sorts every (query block, key block) tile into one of three classes, from
+the block indices alone:
+
+- outside: no pair of the tile is in the band. Never visited: the key
+  dimension of the grid only spans the blocks a query block can need
+  (``_kv_steps``), and the index maps clamp to the last needed block so
+  that a skipped step moves nothing.
+- inside: every pair is in the band. Computed whole, with no mask work.
+- crossing an edge: walked by sub-tiles of ``_SUB[kernel]`` a side
+  (``_tile_classes``): a sub-tile outside the band is not computed, one
+  inside runs unmasked, and only the ones an edge passes through build a
+  mask, from the terms that edge needs. The offset of a tile's first query
+  from its first key takes few values over a grid, so each pattern is
+  static code under its own ``pl.when`` (``_band_keys``).
+
+The backward kernels do the same over query blocks; ``flash_bwd_dkv``
 accumulates a key-value head's gradient over the query heads that share
-it. Block shapes are chosen in the wrapper from the length and the window
-(``_choose_blocks``) unless a caller passes them.
+it. Block shapes are chosen in the wrapper from the mask
+(``_choose_blocks``) unless a caller passes them. ``band_tiles`` counts
+what a call computes; every kernel's wrapper leaves the count as one
+``flash.band`` record of ``telemetry.phase`` when it is traced.
 """
 
 from __future__ import annotations
@@ -67,18 +78,31 @@ def _cdiv(a, b):
     return -(-a // b)
 
 
+# Block indices are counts, never negative, so the truncating division is
+# the floor. On a traced integer ``//`` and ``%`` lower to a dozen
+# instructions each (signs, compares, a select), and Mosaic's lowering of
+# every one of them is traced anew in every index map of every kernel: it
+# was a third of the time the train program takes to lower (PERF.md, PR 28).
+def _div(a, b):
+    return jax.lax.div(a, jnp.int32(b))
+
+
+def _mod(a, b):
+    return jax.lax.rem(a, jnp.int32(b))
+
+
 def _kv_lo(qi, bq, bk, window):
     """First key block that query block ``qi`` can need."""
     if window is None:
         return 0
-    return jnp.maximum(qi * bq - (window - 1), 0) // bk
+    return _div(jnp.maximum(qi * bq - (window - 1), 0), bk)
 
 
 def _kv_hi(qi, bq, bk, nk, causal):
     """Last key block that query block ``qi`` can need."""
     if not causal:
         return nk - 1
-    return jnp.minimum((qi * bq + bq - 1) // bk, nk - 1)
+    return jnp.minimum(_div(qi * bq + bq - 1, bk), nk - 1)
 
 
 def _kv_steps(bq, bk, nk, causal, window):
@@ -95,14 +119,14 @@ def _q_lo(kj, bq, bk, causal):
     """First query block that can need key block ``kj``."""
     if not causal:
         return 0
-    return (kj * bk) // bq
+    return _div(kj * bk, bq)
 
 
 def _q_hi(kj, bq, bk, nq, window):
     """Last query block that can need key block ``kj``."""
     if window is None:
         return nq - 1
-    return jnp.minimum((kj * bk + bk - 1 + window - 1) // bq, nq - 1)
+    return jnp.minimum(_div(kj * bk + bk - 1 + window - 1, bq), nq - 1)
 
 
 def _q_steps(bq, bk, nq, causal, window):
@@ -119,7 +143,7 @@ def _kv_head(b, hq, hkv):
     of the flattened (batch x query heads) arrays reads."""
     if hq == hkv:
         return b
-    return (b // hq) * hkv + (b % hq) // (hq // hkv)
+    return _div(b, hq) * hkv + _div(_mod(b, hq), hq // hkv)
 
 
 def _q_index(row, qi, hq, packed):
@@ -128,25 +152,211 @@ def _q_index(row, qi, hq, packed):
     d)``) or, ``packed``, side by side on the last one (``(batch, seq,
     heads x d)``, as a projection leaves them: no transpose on the way in
     or out)."""
-    return (row // hq, qi, row % hq) if packed else (row, qi, 0)
+    return (_div(row, hq), qi, _mod(row, hq)) if packed else (row, qi, 0)
 
 
 def _kv_index(row, kj, hkv, packed):
     """The same for a key-value-side array and a row of the flattened
     (batch x key-value heads)."""
-    return (row // hkv, kj, row % hkv) if packed else (row, kj, 0)
+    return (_div(row, hkv), kj, _mod(row, hkv)) if packed else (row, kj, 0)
 
 
-def _block_mask(qi, kj, bq, bk, seq_k, causal, window):
-    """[bq, bk] bool mask for this (query block, key block) tile."""
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = kj * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = k_pos < seq_k  # key-side padding
-    if causal:
-        mask = jnp.logical_and(mask, q_pos >= k_pos)
-    if window is not None:
-        mask = jnp.logical_and(mask, q_pos - k_pos < window)
+# --------------------------------------------------------------------------
+# the band by sub-tiles
+# --------------------------------------------------------------------------
+# A (bq, bk) tile is cut into sub-tiles of (sub_q, sub_k). Over a sub-tile
+# whose first query lies ``d`` positions after its first key, ``q - k``
+# takes every value in d - (sub_k - 1) .. d + (sub_q - 1); the band is
+# 0 <= q - k (causal), q - k < window, k < seq_k. A sub-tile's class is
+# ``None`` (no pair in the band: not computed) or the terms of its mask,
+# ``()`` where every pair is in the band:
+#   ("ge", c)   row - col >= c      the causal diagonal
+#   ("lt", c)   row - col < c       the window's far edge
+#   ("col", c)  col < c             the padding of the last key block
+# with row and col counted from the sub-tile's own corner, so that the
+# sub-tiles an edge cuts alike share one mask.
+
+# side of a sub-tile, a kernel (PERF.md section 7 has the sweep): the
+# forward pass, bound by its vector work, gains most from computing least;
+# the backward kernels, bound by their products, from products of 256 rows
+_SUB = {"flash_fwd": 128, "flash_bwd_dq": 256, "flash_bwd_dkv": 256}
+
+
+def _sub_shape(bq, bk, sub):
+    """Sides of a sub-tile: ``sub``, or the whole block where ``sub`` does
+    not divide it."""
+    return (sub if bq % sub == 0 else bq), (sub if bk % sub == 0 else bk)
+
+
+def _tile_classes(off, keys, bq, bk, sub, causal, window):
+    """Class of every sub-tile of the tile whose first query lies ``off``
+    positions after its first key and whose key block holds ``keys`` keys
+    that exist: a tuple (query strips) of tuples (along the keys)."""
+    sq, sk = _sub_shape(bq, bk, sub)
+    rows = []
+    for a in range(bq // sq):
+        row = []
+        for b in range(bk // sk):
+            d = off + a * sq - b * sk
+            lo, hi = d - (sk - 1), d + (sq - 1)      # the range of q - k
+            left = keys - b * sk                     # keys that exist here
+            if (causal and hi < 0) or left <= 0 or (
+                    window is not None and lo >= window):
+                row.append(None)
+                continue
+            terms = []
+            if causal and lo < 0:
+                terms.append(("ge", -d))
+            if window is not None and hi >= window:
+                terms.append(("lt", window - d))
+            if left < sk:
+                terms.append(("col", left))
+            row.append(tuple(terms))
+        rows.append(tuple(row))
+    return tuple(rows)
+
+
+@functools.lru_cache(maxsize=256)
+def _band_keys(nq, nk, bq, bk, seq_k, sub, causal, window):
+    """``{(off, last): (classes, tiles)}`` over the tiles of an (nq, nk)
+    grid that touch the band. ``(off, last)`` is what a kernel tells a
+    tile's pattern by: the offset of its first query from its first key (0
+    without a causal mask, where no edge depends on it) and whether its
+    key block is a padded last one."""
+    tail = seq_k - (nk - 1) * bk
+    found = {}
+    for qi in range(nq):
+        for kj in range(nk):
+            last = tail < bk and kj == nk - 1
+            key = (qi * bq - kj * bk if causal else 0, last)
+            if key not in found:
+                found[key] = [_tile_classes(key[0], tail if last else bk,
+                                            bq, bk, sub, causal, window), 0]
+            found[key][1] += 1
+    return {key: (classes, n) for key, (classes, n) in found.items()
+            if any(c is not None for row in classes for c in row)}
+
+
+def _all_inside(classes):
+    return all(c == () for row in classes for c in row)
+
+
+def band_tiles(seq_q, seq_k, bq, bk, sub, causal, window):
+    """What one head of a call computes, exact from the shapes: ``tiles``
+    visited, their sub-tiles computed ``unmasked`` / ``masked`` and
+    ``skipped``, and ``ratio``, the products computed over the products
+    inside the band (1.0 would be no waste; padded query rows are
+    computed and are no part of the band)."""
+    import numpy as np
+
+    keys = _band_keys(_cdiv(seq_q, bq), _cdiv(seq_k, bk), bq, bk, seq_k,
+                      sub, bool(causal), window)
+    count = {"tiles": 0, "unmasked": 0, "masked": 0, "skipped": 0}
+    for classes, n in keys.values():
+        flat = [c for row in classes for c in row]
+        count["tiles"] += n
+        count["unmasked"] += n * sum(c == () for c in flat)
+        count["masked"] += n * sum(bool(c) for c in flat)
+        count["skipped"] += n * sum(c is None for c in flat)
+    q = np.arange(seq_q)
+    first = np.maximum(q - window + 1, 0) if window is not None else 0 * q
+    last = np.minimum(q, seq_k - 1) if causal else 0 * q + seq_k - 1
+    inside = int(np.maximum(last - first + 1, 0).sum())
+    sq, sk = _sub_shape(bq, bk, sub)
+    count["ratio"] = (count["unmasked"] + count["masked"]) * sq * sk / inside
+    return count
+
+
+def _band(kernel, bq, bk, seq_q, seq_k, causal, window):
+    """What a kernel's body needs of the band: its ``sub``, whether the
+    last key block is ``padded``, and ``groups``, the distinct patterns of
+    its grid with the ``(off, last)`` of the tiles that have each. Leaves
+    one zero-length ``flash.band`` record a traced call."""
+    from ... import telemetry
+
+    sub = _SUB[kernel]
+    with telemetry.phase("flash.band", kernel=kernel, seq=seq_q, bq=bq,
+                         bk=bk, sub=sub, window=window or 0,
+                         **band_tiles(seq_q, seq_k, bq, bk, sub, causal,
+                                      window)):
+        pass
+    groups = {}
+    for key, (classes, _) in _band_keys(
+            _cdiv(seq_q, bq), _cdiv(seq_k, bk), bq, bk, seq_k, sub, causal,
+            window).items():
+        groups.setdefault(classes, []).append(key)
+    return dict(sub=sub, padded=seq_k % bk != 0, groups=groups)
+
+
+def _strips(classes, bq, bk, sub, by_keys=False):
+    """The computed part of a tile, ``(extent, [(start, pieces)])``: strips
+    of ``extent`` queries (keys with ``by_keys``) from ``start``, each with
+    its ``pieces`` ``(start, extent, terms)`` along the other side, an
+    edge sub-tile by itself and a run of unmasked ones as one piece. A
+    tile inside the band is one strip of one piece."""
+    if _all_inside(classes):
+        return (bk, [(0, [(0, bq, ())])]) if by_keys else \
+            (bq, [(0, [(0, bk, ())])])
+    strip, step = _sub_shape(bq, bk, sub)
+    if by_keys:
+        classes, strip, step = tuple(zip(*classes)), step, strip
+    strips = []
+    for a, line in enumerate(classes):
+        pieces = []
+        for b, terms in enumerate(line):
+            if terms is None:
+                continue
+            if terms == () and pieces and pieces[-1][2] == () \
+                    and pieces[-1][0] + pieces[-1][1] == b * step:
+                pieces[-1] = (pieces[-1][0], pieces[-1][1] + step, ())
+            else:
+                pieces.append((b * step, step, terms))
+        if pieces:
+            strips.append((a * strip, pieces))
+    return strip, strips
+
+
+def _masks(bq, bk, sub, by_keys=False):
+    """``mask(terms)`` for one kernel body: the [sub_q, sub_k] bool array of
+    an edge sub-tile's terms ([sub_k, sub_q] with ``by_keys``, the keys
+    down the rows), built once a body; ``None`` for no terms."""
+    shape = _sub_shape(bq, bk, sub)[::-1 if by_keys else 1]
+    made = {}
+
+    def mask(terms):
+        if terms and terms not in made:
+            row = jax.lax.broadcasted_iota(jnp.int32, shape, int(by_keys))
+            col = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - by_keys)
+            parts = [row - col >= c if kind == "ge" else
+                     row - col < c if kind == "lt" else col < c
+                     for kind, c in terms]
+            made[terms] = functools.reduce(jnp.logical_and, parts)
+        return made.get(terms)
+
     return mask
+
+
+def _on_tile(groups, off, last, needed, causal, padded, body):
+    """Run ``body(classes)`` for the pattern of this step's tile: every
+    pattern an edge passes through under a ``pl.when`` of the few
+    ``(off, last)`` that have it, the tiles inside the band under what is
+    left of ``needed``."""
+    def is_key(key):
+        terms = ([off == key[0]] if causal else []) + (
+            [last if key[1] else jnp.logical_not(last)] if padded else [])
+        return functools.reduce(jnp.logical_and, terms)
+
+    inside = needed
+    for classes, keys in groups.items():
+        if _all_inside(classes):
+            continue
+        hit = functools.reduce(jnp.logical_or, [is_key(k) for k in keys])
+        pl.when(jnp.logical_and(needed, hit))(
+            functools.partial(body, classes))
+        inside = jnp.logical_and(inside, jnp.logical_not(hit))
+    for classes in groups:
+        if _all_inside(classes):
+            pl.when(inside)(functools.partial(body, classes))
 
 
 # --------------------------------------------------------------------------
@@ -170,16 +380,17 @@ def _split_refs(refs, n_in, extras):
     return ins, rope, gate, refs[k:]
 
 
-def _rotated(x, rope):
-    """``x`` (rows, d) rotated to its positions, in ``x``'s dtype."""
+def _rotated(x, rope, rows=slice(None)):
+    """``x``, the ``rows`` of a query block (rows, d), rotated to its
+    positions, in ``x``'s dtype."""
     if rope is None:
         return x
     cos_ref, sin_ref, rot_ref = rope
     turned = jax.lax.dot_general(
         x, rot_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    return (x.astype(jnp.float32) * cos_ref[...]
-            + turned * sin_ref[...]).astype(x.dtype)
+    return (x.astype(jnp.float32) * cos_ref[rows, :]
+            + turned * sin_ref[rows, :]).astype(x.dtype)
 
 
 def _unrotated(g, rope, dtype):
@@ -193,11 +404,12 @@ def _unrotated(g, rope, dtype):
         (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-def _gated(do, gate):
-    """The gradient by the ungated output: ``do`` times the row's gate."""
+def _gated(do, gate, rows=slice(None)):
+    """The gradient by the ungated output: ``do``, the ``rows`` of a query
+    block, times each row's gate."""
     if gate is None:
         return do
-    return (do.astype(jnp.float32) * gate[0]).astype(do.dtype)
+    return (do.astype(jnp.float32) * gate[0, rows, :]).astype(do.dtype)
 
 
 def _extra_specs(extras, d, q_block_map, stat_map, bq):
@@ -218,8 +430,22 @@ def _extra_specs(extras, d, q_block_map, stat_map, bq):
 # forward
 # --------------------------------------------------------------------------
 
-def _fwd_kernel(*refs, scale, causal, window, bq, bk, seq_k, nk, steps,
-                extras):
+def _dot(a, b, contract):
+    """``a`` and ``b`` contracted over the axes ``contract``, float32."""
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _probs(a, b, lse, scale, mask):
+    """The probabilities of a piece from the saved row statistics
+    (backward), zero where ``mask`` is not set: queries ``a`` down the
+    rows and ``lse`` a column, or keys ``a`` and ``lse`` a row."""
+    p = jnp.exp(_dot(a, b, (1, 1)) * scale - lse)
+    return p if mask is None else jnp.where(mask, p, 0.0)
+
+
+def _fwd_kernel(*refs, scale, causal, window, bq, bk, nk, steps, sub, groups,
+                padded, extras):
     (q_ref, k_ref, v_ref), rope, gate, rest = _split_refs(refs, 3, extras)
     o_ref, lse_ref, acc, m_scr, l_scr = rest[:5]
     q_scr = rest[5] if rope is not None else None
@@ -234,30 +460,38 @@ def _fwd_kernel(*refs, scale, causal, window, bq, bk, seq_k, nk, steps,
         if rope is not None:     # once a query block, kept for its steps
             q_scr[...] = _rotated(q_ref[0], rope)
 
-    @pl.when(kj <= _kv_hi(qi, bq, bk, nk, causal))
-    def _body():
-        # matmul operands per the _mxu policy; products accumulate f32
-        q = _mxu(q_ref[0] if rope is None else q_scr[...])
-        k = _mxu(k_ref[0])
-        v = _mxu(v_ref[0])
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        mask = _block_mask(qi, kj, bq, bk, seq_k, causal, window)
-        s = jnp.where(mask, s, NEG_INF)
+    def body(classes):
+        # a strip of queries keeps one running maximum over its pieces;
+        # matmul operands per the _mxu policy, products accumulate f32
+        height, strips = _strips(classes, bq, bk, sub)
+        mask = _masks(bq, bk, sub)
+        for r0, pieces in strips:
+            rows = slice(r0, r0 + height)
+            q = _mxu(q_ref[0, rows, :] if rope is None else q_scr[rows, :])
+            scores = []
+            for k0, n, terms in pieces:
+                s = _dot(q, _mxu(k_ref[0, k0:k0 + n, :]), (1, 1)) * scale
+                scores.append(jnp.where(mask(terms), s, NEG_INF)
+                              if terms else s)
+            m_prev = m_scr[rows, :1]                 # [height, 1]
+            m_new = functools.reduce(jnp.maximum, [m_prev] + [
+                jnp.max(s, axis=-1, keepdims=True) for s in scores])
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = l_scr[rows, :1] * alpha
+            out = acc[rows, :] * alpha
+            for (k0, n, terms), s in zip(pieces, scores):
+                p = jnp.exp(s - m_new)               # [height, n] f32
+                if terms:     # a row with no key yet has m_new = NEG_INF
+                    p = jnp.where(mask(terms), p, 0.0)
+                l_new = l_new + jnp.sum(p, axis=-1, keepdims=True)
+                v = _mxu(v_ref[0, k0:k0 + n, :])
+                out = out + _dot(p.astype(v.dtype), v, (1, 0))
+            acc[rows, :] = out
+            m_scr[rows, :] = jnp.broadcast_to(m_new, (height, _LANES))
+            l_scr[rows, :] = jnp.broadcast_to(l_new, (height, _LANES))
 
-        m_prev = m_scr[:, :1]                    # [bq, 1]
-        m_blk = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_blk)
-        p = jnp.exp(s - m_new)                   # [bq, bk] f32
-        p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)          # [bq, 1]
-        l_new = l_scr[:, :1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc[:] = acc[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+    _on_tile(groups, qi * bq - kj * bk, kj == nk - 1,
+             kj <= _kv_hi(qi, bq, bk, nk, causal), causal, padded, body)
 
     @pl.when(j == steps - 1)
     def _finalize():
@@ -283,16 +517,18 @@ def _kv_block_map(hq, hkv, bq, bk, nk, causal, window, packed):
 
 
 def _flash_fwd_padded(q, k, v, *, scale, causal, window, hq, hkv, bq, bk,
-                      seq_k, interpret, packed=False, rope=None, gate=None):
+                      seq_q, seq_k, interpret, packed=False, rope=None,
+                      gate=None):
     d = q.shape[2] // hq if packed else q.shape[2]
     bh = q.shape[0] * hq if packed else q.shape[0]
     sq = q.shape[1]
     nq, nk = sq // bq, k.shape[1] // bk
     steps = _kv_steps(bq, bk, nk, causal, window)
     extras = (rope is not None, gate is not None)
-    kern = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                             window=window, bq=bq, bk=bk, seq_k=seq_k,
-                             nk=nk, steps=steps, extras=extras)
+    kern = functools.partial(
+        _fwd_kernel, scale=scale, causal=causal, window=window, bq=bq, bk=bk,
+        nk=nk, steps=steps, extras=extras,
+        **_band("flash_fwd", bq, bk, seq_q, seq_k, causal, window))
     kv_map = _kv_block_map(hq, hkv, bq, bk, nk, causal, window, packed)
 
     def q_map(b, i, j):
@@ -332,8 +568,8 @@ def _flash_fwd_padded(q, k, v, *, scale, causal, window, hq, hkv, bq, bk,
 # backward
 # --------------------------------------------------------------------------
 
-def _bwd_dq_kernel(*refs, scale, causal, window, bq, bk, seq_k, nk, steps,
-                   extras):
+def _bwd_dq_kernel(*refs, scale, causal, window, bq, bk, nk, steps, sub,
+                   groups, padded, extras):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), rope, gate, rest = \
         _split_refs(refs, 6, extras)
     dq_ref, dq_acc = rest[:2]
@@ -347,26 +583,27 @@ def _bwd_dq_kernel(*refs, scale, causal, window, bq, bk, seq_k, nk, steps,
         if rope is not None:
             q_scr[...] = _rotated(q_ref[0], rope)
 
-    @pl.when(kj <= _kv_hi(qi, bq, bk, nk, causal))
-    def _body():
-        q = _mxu(q_ref[0] if rope is None else q_scr[...])
-        k = _mxu(k_ref[0])
-        v = _mxu(v_ref[0])
-        do = _mxu(_gated(do_ref[0], gate))
-        lse = lse_ref[0]                         # [bq, 1]
-        delta = delta_ref[0]                     # [bq, 1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        mask = _block_mask(qi, kj, bq, bk, seq_k, causal, window)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * scale).astype(k.dtype)
-        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def body(classes):
+        height, strips = _strips(classes, bq, bk, sub)
+        mask = _masks(bq, bk, sub)
+        for r0, pieces in strips:
+            rows = slice(r0, r0 + height)
+            q = _mxu(q_ref[0, rows, :] if rope is None else q_scr[rows, :])
+            do = _mxu(_gated(do_ref[0, rows, :], gate, rows))
+            lse = lse_ref[0, rows, :]                # [height, 1]
+            delta = delta_ref[0, rows, :]
+            dq = dq_acc[rows, :]
+            for k0, n, terms in pieces:
+                k = _mxu(k_ref[0, k0:k0 + n, :])
+                v = _mxu(v_ref[0, k0:k0 + n, :])
+                p = _probs(q, k, lse, scale, mask(terms))
+                dp = _dot(do, v, (1, 1))
+                ds = (p * (dp - delta) * scale).astype(k.dtype)
+                dq = dq + _dot(ds, k, (1, 0))
+            dq_acc[rows, :] = dq
+
+    _on_tile(groups, qi * bq - kj * bk, kj == nk - 1,
+             kj <= _kv_hi(qi, bq, bk, nk, causal), causal, padded, body)
 
     @pl.when(j == steps - 1)
     def _finalize():
@@ -374,8 +611,8 @@ def _bwd_dq_kernel(*refs, scale, causal, window, bq, bk, seq_k, nk, steps,
             dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, seq_k, nq, steps,
-                    group, extras):
+def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps,
+                    group, sub, groups, padded, extras):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), rope, gate, rest = \
         _split_refs(refs, 6, extras)
     dk_ref, dv_ref, dk_acc, dv_acc = rest
@@ -383,37 +620,44 @@ def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, seq_k, nq, steps,
     # key-value head, and within each the query blocks that can need
     # this key block
     kj, t = pl.program_id(1), pl.program_id(2)
-    qi = _q_lo(kj, bq, bk, causal) + t % steps
+    qi = _q_lo(kj, bq, bk, causal) + _mod(t, steps)
 
     @pl.when(t == 0)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(qi <= _q_hi(kj, bq, bk, nq, window))
-    def _body():
-        # every step has another query block: rotated as it comes
-        q = _mxu(_rotated(q_ref[0], rope))
-        k = _mxu(k_ref[0])
-        v = _mxu(v_ref[0])
-        do = _mxu(_gated(do_ref[0], gate))
-        lse = lse_ref[0]
-        delta = delta_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        mask = _block_mask(qi, kj, bq, bk, seq_k, causal, window)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)        # [bq, bk] f32
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * scale).astype(q.dtype)   # [bq, bk]
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def body(classes):
+        # strips of keys, each over the pieces of queries it needs, the
+        # keys down the rows of every product: nothing is transposed
+        width, strips = _strips(classes, bq, bk, sub, by_keys=True)
+        mask = _masks(bq, bk, sub, by_keys=True)
+        # every step has another query block: what the strips need of it
+        # is rotated as it comes, once
+        lo = min(r0 for _, pieces in strips for r0, _, _ in pieces)
+        hi = max(r0 + n for _, pieces in strips for r0, n, _ in pieces)
+        q_all = _mxu(_rotated(q_ref[0, lo:hi, :], rope, slice(lo, hi)))
+        do_all = _mxu(_gated(do_ref[0, lo:hi, :], gate, slice(lo, hi)))
+        for c0, pieces in strips:
+            cols = slice(c0, c0 + width)
+            k = _mxu(k_ref[0, cols, :])
+            v = _mxu(v_ref[0, cols, :])
+            dk, dv = dk_acc[cols, :], dv_acc[cols, :]
+            for r0, n, terms in pieces:
+                q = q_all[r0 - lo:r0 - lo + n]
+                do = do_all[r0 - lo:r0 - lo + n]
+                p = _probs(k, q, lse_ref[0, 0, :, r0:r0 + n], scale,
+                           mask(terms))              # [width, n] f32
+                dv = dv + _dot(p.astype(do.dtype), do, (1, 0))
+                dp = _dot(v, do, (1, 1))
+                ds = (p * (dp - delta_ref[0, 0, :, r0:r0 + n])
+                      * scale).astype(q.dtype)
+                dk = dk + _dot(ds, q, (1, 0))
+            dk_acc[cols, :] = dk
+            dv_acc[cols, :] = dv
+
+    _on_tile(groups, qi * bq - kj * bk, kj == nk - 1,
+             qi <= _q_hi(kj, bq, bk, nq, window), causal, padded, body)
 
     @pl.when(t == group * steps - 1)
     def _finalize():
@@ -422,8 +666,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, seq_k, nq, steps,
 
 
 def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
-                      hkv, bq, bk, seq_k, interpret, packed=False, rope=None,
-                      gate=None):
+                      hkv, bq, bk, seq_q, seq_k, interpret, packed=False,
+                      rope=None, gate=None):
     """``(dq, dk, dv, delta)``. With a gate ``o`` is the gated output and
     ``delta`` (the row sums of ``do * o``) serves both the kernels and the
     gate's own gradient."""
@@ -446,6 +690,7 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
 
     kv_steps = _kv_steps(bq, bk, nk, causal, window)
     kv_map = _kv_block_map(hq, hkv, bq, bk, nk, causal, window, packed)
+    band = (bq, bk, seq_q, seq_k, causal, window)
 
     def q_of_row(b, i, j):
         return _q_index(b, i, hq, packed)
@@ -455,8 +700,8 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          window=window, bq=bq, bk=bk, seq_k=seq_k, nk=nk,
-                          steps=kv_steps, extras=extras),
+                          window=window, bq=bq, bk=bk, nk=nk, steps=kv_steps,
+                          extras=extras, **_band("flash_bwd_dq", *band)),
         grid=(bh, nq, kv_steps),
         in_specs=[
             pl.BlockSpec((1, bq, d), q_of_row),
@@ -481,8 +726,8 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
         # step t: this key-value head's ``t // q_steps``-th query head;
         # block clamped to the last one needed
         head = b if hq == hkv else \
-            (b // hkv) * hq + (b % hkv) * group + t // q_steps
-        qi = jnp.minimum(_q_lo(j, bq, bk, causal) + t % q_steps,
+            _div(b, hkv) * hq + _mod(b, hkv) * group + _div(t, q_steps)
+        qi = jnp.minimum(_q_lo(j, bq, bk, causal) + _mod(t, q_steps),
                          _q_hi(j, bq, bk, nq, window))
         return head, qi
 
@@ -492,21 +737,30 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
     def stat_map(b, j, t):
         return (*q_row(b, j, t), 0)
 
+    def stat_row_map(b, j, t):
+        return (*q_row(b, j, t), 0, 0)
+
+    def stat_rows(x):
+        # a query block's statistics along the lanes, as flash_bwd_dkv
+        # reads them: the same bytes
+        return x.reshape(bh, nq, 1, bq)
+
     def kv_of_row(b, j, t):
         return _kv_index(b, j, hkv, packed)
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          window=window, bq=bq, bk=bk, seq_k=seq_k, nq=nq,
-                          steps=q_steps, group=group, extras=extras),
+                          window=window, bq=bq, bk=bk, nq=nq, nk=nk,
+                          steps=q_steps, group=group, extras=extras,
+                          **_band("flash_bwd_dkv", *band)),
         grid=(bkv, nk, group * q_steps),
         in_specs=[
             pl.BlockSpec((1, bq, d), q_map),
             pl.BlockSpec((1, bk, d), kv_of_row),
             pl.BlockSpec((1, bk, d), kv_of_row),
             pl.BlockSpec((1, bq, d), q_map),
-            pl.BlockSpec((1, bq, 1), stat_map),
-            pl.BlockSpec((1, bq, 1), stat_map),
+            pl.BlockSpec((1, 1, 1, bq), stat_row_map),
+            pl.BlockSpec((1, 1, 1, bq), stat_row_map),
         ] + _extra_specs(extras, d, lambda b, j, t: (q_row(b, j, t)[1], 0),
                          stat_map, bq),
         out_specs=[
@@ -521,7 +775,7 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(q, k, v, do, lse, delta, *more)
+    )(q, k, v, do, stat_rows(lse), stat_rows(delta), *more)
     return dq, dk, dv, delta
 
 
@@ -570,7 +824,8 @@ def _flash_fwd(q, k, v, rope, gate, cfg):
         jax.nn.sigmoid(gate.astype(jnp.float32)), 1, bq)
     o, lse = _flash_fwd_padded(qp, kp, vp, scale=scale, causal=causal,
                                window=window, hq=hq, hkv=hkv, bq=bq, bk=bk,
-                               seq_k=sk, interpret=interpret, packed=packed,
+                               seq_q=sq, seq_k=sk,
+                               interpret=interpret, packed=packed,
                                rope=ropep, gate=factor)
     # under a recomputation segment (executor._remat_segments) the output
     # and the row statistics are kept: recomputing them is this kernel again
@@ -586,8 +841,8 @@ def _flash_bwd(cfg, res, g):
     gp = _pad_to(_pad_to(g, 2, qp.shape[-1]), 1, bq)  # match residual padding
     dq, dk, dv, delta = _flash_bwd_padded(
         qp, kp, vp, o, lse, gp, scale=scale, causal=causal, window=window,
-        hq=hq, hkv=hkv, bq=bq, bk=bk, seq_k=sk, interpret=interpret,
-        packed=packed, rope=ropep, gate=factor)
+        hq=hq, hkv=hkv, bq=bq, bk=bk, seq_q=sq, seq_k=sk,
+        interpret=interpret, packed=packed, rope=ropep, gate=factor)
     dkv = kp.shape[2] if packed else d
     d_rope = None if rope is None else tuple(jnp.zeros_like(a) for a in rope)
     # o is the gated output: sum(do * o) = s * sum(do * ungated), and the
@@ -600,22 +855,19 @@ def _flash_bwd(cfg, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def _choose_blocks(causal, window):
+def _choose_blocks(causal):
     """Block shapes where the caller names none. Without a mask the blocks
     that reached 64.5 % of the roofline on a v5e (PERF.md, PR 26). Under a
-    causal mask square blocks, so that the blocks above the diagonal are
-    whole and skipped (512 x 2,048 could skip a quarter of them at 4,096
-    positions, and saved 7 %). With a window the block is the window
-    rounded up to a power of two inside 128..512: a query block then needs
-    two or three key blocks whatever the length."""
-    if not causal:
-        return 512, 2048
-    if window is None:
-        return 1024, 1024
-    b = 128
-    while b < min(window, 512):
-        b *= 2
-    return b, b
+    causal mask square blocks of 1,024, so that the blocks above the
+    diagonal are whole and skipped and the offset of a tile's first query
+    from its first key takes few values. A window changes nothing: a tile
+    the band crosses is walked by sub-tiles, so what is computed outside
+    the band does not depend on the block's size, and the larger block
+    spreads a query block's fixed cost (the rotation, the final division,
+    the steps themselves) over more keys. PERF.md section 7 has the sweep
+    (2,048 x 2,048 is 6 % better still for a window of 512 and does not
+    fit VMEM at a head of 256)."""
+    return (1024, 1024) if causal else (512, 2048)
 
 
 def _blocks(q, k, block_q, block_k):
@@ -672,7 +924,8 @@ def flash_block_grads(q, k, v, o, lse, do, causal=False, block_q=512,
     lsep = _pad_to(lse.reshape(b * h, sq, 1), 1, bq)
     dq, dk, dv, _ = _flash_bwd_padded(qp, kp, vp, op, lsep, dop, scale=scale,
                                       causal=causal, window=None, hq=h,
-                                      hkv=h, bq=bq, bk=bk, seq_k=sk,
+                                      hkv=h, bq=bq, bk=bk,
+                                      seq_q=sq, seq_k=sk,
                                       interpret=interpret)
     return (dq[:, :sq, :d].reshape(b, h, sq, d),
             dk[:, :sk, :d].reshape(b, h, sk, d),
@@ -753,7 +1006,7 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
                              "and at least one key")
         if window >= sk:
             window = None   # the causal mask alone
-    chosen = _choose_blocks(causal, window)
+    chosen = _choose_blocks(causal)
     bq = min(chosen[0] if block_q is None else block_q, max(8, sq))
     bk = min(chosen[1] if block_k is None else block_k, max(8, sk))
     cfg = (causal, window, hq, hkv, bq, bk, interpret, heads_last)

@@ -12,6 +12,7 @@ Tiny sizes, seeded weights, float32 unless a test says bfloat16.
 
 import importlib.util
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -283,13 +284,13 @@ def test_no_pick_is_dropped_when_every_row_picks_the_same_experts():
 
 # -- the flash kernel: grouped heads, window, skipped blocks -------------------
 
-def _dense_attention(q, k, v, window):
+def _dense_attention(q, k, v, window, causal=True):
     group = q.shape[1] // k.shape[1]
     k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
     i = jnp.arange(q.shape[2])[:, None]
     j = jnp.arange(k.shape[2])[None, :]
-    seen = i >= j
+    seen = i >= j if causal else jnp.ones((q.shape[2], k.shape[2]), bool)
     if window:
         seen &= i - j < window
     return jnp.einsum("bhqk,bhkd->bhqd",
@@ -310,12 +311,12 @@ def test_flash_kernel_grouped_heads_and_window(heads, window):
     q = jnp.asarray(rng.randn(1, heads, seq, d), jnp.float32)
     k = jnp.asarray(rng.randn(1, 8, seq, d), jnp.float32)
     v = jnp.asarray(rng.randn(1, 8, seq, d), jnp.float32)
-    bq, bk = _choose_blocks(True, window)
-    assert (bq, bk) == ((512, 512) if window else (1024, 1024))
-    # with the window the grid's key dimension is shorter than the
-    # sequence: blocks outside the band are never visited
+    bq, bk = _choose_blocks(True)
+    assert (bq, bk) == (1024, 1024)
+    # with the window the grid's key dimension stays two blocks however
+    # long the sequence: blocks outside the band are never visited
     assert _kv_steps(bq, bk, -(-seq // bk), True, window) == 2
-    assert -(-seq // bk) == (3 if window else 2)
+    assert _kv_steps(bq, bk, 8, True, window) == (2 if window else 8)
     w = jnp.asarray(rng.randn(1, heads, seq, d), jnp.float32)
 
     def ours(q, k, v):
@@ -330,6 +331,159 @@ def test_flash_kernel_grouped_heads_and_window(heads, window):
     for a, b in zip(jax.grad(ours, (0, 1, 2))(q, k, v),
                     jax.grad(dense, (0, 1, 2))(q, k, v)):
         assert rel(a, b) < 1e-4
+
+
+# the tile classes of the band walk: (positions, heads, key-value heads,
+# head size, causal, window, block_q, block_k, sub-tile, rotation and gate
+# in the kernels). ``None`` blocks are the wrapper's own choice, a ``None``
+# sub-tile the module's
+BAND_CASES = {
+    # tiles inside the band, and diagonal tiles walked by sub-tiles
+    "causal": (1536, 2, 1, 8, True, None, None, None, None, False),
+    # the published window at the blocks the wrapper chooses for it
+    "window_512": (1536, 2, 1, 8, True, 512, None, None, None, False),
+    # a window that is no multiple of the sub-tile, one narrower than it
+    "window_200": (1000, 2, 2, 8, True, 200, 256, 256, 128, False),
+    "window_50": (1000, 2, 2, 8, True, 50, 256, 256, 128, False),
+    # a length that is no multiple of the block: a padded last key block
+    "padded_causal": (1000, 2, 2, 8, True, None, 512, 512, 128, False),
+    "padded_window": (900, 2, 1, 8, True, 300, 512, 512, 128, False),
+    # blocks that are not square, either way
+    "wide_keys": (1000, 4, 2, 8, True, None, 256, 512, 128, False),
+    "tall_queries": (1000, 4, 2, 8, True, 300, 512, 256, 128, False),
+    # no mask but the padding: every other tile is inside
+    "not_causal": (1000, 2, 2, 8, False, None, 256, 512, 128, False),
+    # blocks smaller than a sub-tile: a tile is its own sub-tile
+    "small_blocks": (100, 2, 2, 8, True, 30, 64, 32, None, False),
+    # the published head counts over 8, rotation and gate in the kernels
+    "heads_48_of_8": (384, 48, 8, 128, True, None, 256, 256, 128, True),
+    "heads_64_of_8": (384, 64, 8, 128, True, 200, 256, 256, 128, True),
+}
+
+
+def _set_sub_tile(monkeypatch, fa, sub):
+    for kernel in fa._SUB if sub else ():
+        monkeypatch.setitem(fa._SUB, kernel, sub)
+
+
+@pytest.mark.parametrize("case", sorted(BAND_CASES))
+def test_flash_kernels_walk_the_band_by_sub_tiles(case, monkeypatch):
+    """Interpreter mode against the dense masked softmax, output and all
+    three gradients (the gate's too where it is on), one case a tile
+    class."""
+    import sys
+
+    from mxnet_tpu.ops.pallas import flash_attention
+    from mxnet_tpu.ops.pallas.flash_attention import rotary_tables
+
+    fa = sys.modules["mxnet_tpu.ops.pallas.flash_attention"]
+    seq, heads, kv, d, causal, window, bq, bk, sub, in_kernel = \
+        BAND_CASES[case]
+    _set_sub_tile(monkeypatch, fa, sub)
+    rng = np.random.RandomState(7)
+    q, k, v, w = (jnp.asarray(rng.randn(1, n, seq, d), jnp.float32)
+                  for n in (heads, kv, kv, heads))
+    g = jnp.asarray(rng.randn(1, seq, heads), jnp.float32)
+    rope = rotary_tables(seq, d, 1.0 / 10000.0 ** (np.arange(32) / 32.0)) \
+        if in_kernel else None
+
+    def rows(x):      # heads last, as a projection leaves its rows
+        return x.transpose(0, 2, 1, 3)
+
+    def ours(q, k, v, g):
+        if not in_kernel:
+            return flash_attention(q, k, v, causal=causal, window=window,
+                                   block_q=bq, block_k=bk)
+        return rows(flash_attention(
+            rows(q), rows(k), rows(v), causal=True, window=window,
+            block_q=bq, block_k=bk, heads_last=True, rotary=rope, gate=g))
+
+    def dense(q, k, v, g):
+        if not in_kernel:
+            return _dense_attention(q, k, v, window, causal)
+        cos, sin, rot = rope
+        o = _dense_attention(q * cos + (q @ rot) * sin, k, v, window)
+        return o * rows(jax.nn.sigmoid(g)[..., None])
+
+    assert rel(ours(q, k, v, g), dense(q, k, v, g)) < 1e-5
+    wrt = (0, 1, 2, 3) if in_kernel else (0, 1, 2)
+    for a, b in zip(
+            jax.grad(lambda *x: jnp.sum(ours(*x) * w), wrt)(q, k, v, g),
+            jax.grad(lambda *x: jnp.sum(dense(*x) * w), wrt)(q, k, v, g)):
+        assert rel(a, b) < 1e-4
+
+
+@pytest.mark.parametrize("case", sorted(BAND_CASES))
+def test_band_tiles_counts_what_the_mask_says(case, monkeypatch):
+    """``band_tiles`` against a count over the boolean mask itself; no
+    sub-tile that holds a pair of the band is skipped, none that holds a
+    pair outside it runs unmasked; and a traced call leaves the count as
+    one ``flash.band`` record a kernel."""
+    import sys
+
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops.pallas import flash_attention
+
+    fa = sys.modules["mxnet_tpu.ops.pallas.flash_attention"]
+    seq, heads, kv, d, causal, window, *given, sub, _ = BAND_CASES[case]
+    _set_sub_tile(monkeypatch, fa, sub)
+    bq, bk = (min(b or chosen, seq)
+              for b, chosen in zip(given, fa._choose_blocks(causal)))
+    nq, nk = -(-seq // bq), -(-seq // bk)
+    i = np.arange(nq * bq)[:, None]
+    j = np.arange(nk * bk)[None, :]
+    seen = (j < seq) & (i >= (j if causal else 0))
+    if window:
+        seen &= i - j < window
+
+    def count(sub):
+        sq, sk = fa._sub_shape(bq, bk, sub)
+        keys = fa._band_keys(nq, nk, bq, bk, seq, sub, causal, window)
+        want = {"tiles": 0, "unmasked": 0, "masked": 0, "skipped": 0}
+        for qi in range(nq):
+            for kj in range(nk):
+                tile = seen[qi * bq:(qi + 1) * bq, kj * bk:(kj + 1) * bk]
+                key = (qi * bq - kj * bk if causal else 0,
+                       seq % bk != 0 and kj == nk - 1)
+                assert tile.any() == (key in keys), (qi, kj)
+                if not tile.any():
+                    continue
+                want["tiles"] += 1
+                for a, line in enumerate(keys[key][0]):
+                    for b, terms in enumerate(line):
+                        part = tile[a * sq:(a + 1) * sq,
+                                    b * sk:(b + 1) * sk]
+                        where = (qi, kj, a, b)
+                        assert (terms is None) == (not part.any()), where
+                        assert (terms == ()) == part.all(), where
+                        want["unmasked" if part.all() else
+                             "masked" if part.any() else "skipped"] += 1
+        want["ratio"] = (want["unmasked"] + want["masked"]) * sq * sk \
+            / seen[:seq].sum()
+        return want
+
+    want = {sub: count(sub) for sub in set(fa._SUB.values())}
+    for sub, n in want.items():
+        assert fa.band_tiles(seq, seq, bq, bk, sub, causal, window) \
+            == pytest.approx(n)
+        assert n["ratio"] >= 1.0
+
+    if d != 8:
+        return      # the record is the wrapper's, whatever the kernel's extras
+    x = jnp.zeros((1, heads, seq, d), jnp.float32)
+    kvx = jnp.zeros((1, kv, seq, d), jnp.float32)
+    since = time.perf_counter()
+    jax.grad(lambda q: jnp.sum(flash_attention(
+        q, kvx, kvx, causal=causal, window=window, block_q=given[0],
+        block_k=given[1])))(x)
+    records = [r["attrs"] for r in telemetry.span_records(since)
+               if r["name"] == "flash.band"]
+    assert sorted(r["kernel"] for r in records) == sorted(fa._SUB)
+    for r in records:
+        sub = fa._SUB[r["kernel"]]
+        assert {n: r[n] for n in want[sub]} == pytest.approx(want[sub])
+        assert (r["seq"], r["bq"], r["bk"], r["sub"], r["window"]) == (
+            seq, bq, bk, sub, window or 0)
 
 
 def test_flash_kernel_refuses_what_it_cannot_mean():

@@ -89,6 +89,24 @@ def _decoder_attention_case(heads, window, yarn):
     return build
 
 
+def _padded_flash_case(causal, window):
+    """The edge-tile variants that 8,192 positions never reach: 8,000
+    positions of the cell's heads (128 wide, 16 over 8, rows as projected)
+    leave a padded last key block, crossed by the diagonal, by the
+    window's edge too, or by neither."""
+    def build(S):
+        q = S((1, 8000, 16, 128), jnp.bfloat16)
+        kv = S((1, 8000, 8, 128), jnp.bfloat16)
+
+        def loss(q, k, v):
+            o = pk.flash_attention(q, k, v, causal=causal, window=window,
+                                   heads_last=True, interpret=False)
+            return jnp.sum(o.astype(jnp.float32))
+
+        return jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv)
+    return build
+
+
 def _quant_case(mode):
     def build(S):
         spec = comm.CompressionSpec(mode)
@@ -120,6 +138,12 @@ CASES = {
                            {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     "flash_decoder_window": (_decoder_attention_case(64, 512, False),
                              {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    "flash_padded_causal": (_padded_flash_case(True, None),
+                            {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    "flash_padded_window": (_padded_flash_case(True, 512),
+                            {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    "flash_padded_unmasked": (_padded_flash_case(False, None),
+                              {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     "fused_adam": (_adam_case, {"fused_adam"}),
     "quant_int8": (_quant_case("int8"), {"quant_int8"}),
     "quant_twobit": (_quant_case("twobit"), {"quant_twobit"}),
