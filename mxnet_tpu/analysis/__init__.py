@@ -12,7 +12,8 @@ Three passes over three representations of the same program:
           (reference: StaticGraph::InferShape).
   Pass 3  jaxpr audit    (`jaxpr_audit`) — inspects a bound executor's
           traced jaxpr for host transfers, dtype promotions, and per-op
-          FLOP/byte totals (feeds tools/bench_roofline.py).
+          FLOP/byte totals (feeds the MFU accountant and the profiler's
+          roofline rows).
   Pass 4  concurrency    (`concurrency`)  — whole-package model of thread
           entry points and lock scopes: shared-state races (MX701),
           lock-order cycles (MX702), bare cv.wait (MX703), leaked

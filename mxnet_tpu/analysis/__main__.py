@@ -74,7 +74,7 @@ def _parser():
 
 
 def _ensure_shardcheck_devices():
-    """Arm the virtual dp-8 CPU mesh (the bench.py rig). The parent
+    """Arm the virtual dp-8 CPU mesh (the tier-1 tests' rig). The parent
     package import pulls in jax before this runs, but jax reads
     JAX_PLATFORMS / XLA_FLAGS lazily at backend INIT — so setting them
     here still works as long as nothing called jax.devices() yet. A

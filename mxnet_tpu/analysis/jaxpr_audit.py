@@ -8,10 +8,9 @@ what will actually run: the jaxpr XLA compiles. It reports
   MX502  unexpected dtype promotions — e.g. f32 tensors materializing in
          a program the caller intends to run in bf16,
 
-and produces per-primitive FLOP/byte totals in the same spirit as
-``tools/bench_roofline.py``'s per-instruction HBM table (which works on
-optimized HLO post-fusion; this one works pre-XLA, so it bounds the
-*unfused* traffic — the two bracket the roofline).
+and produces per-primitive FLOP/byte totals. It works pre-XLA, so its
+bytes bound the *unfused* traffic; a per-instruction table over the
+optimized HLO bounds it from the other side.
 
 jax is imported lazily (function scope) so importing the analysis package
 never pulls in the tracing machinery until an audit actually runs.
@@ -264,10 +263,10 @@ def audit_executor(executor, is_train=False,
 
 def cost_rows(fn, *example_args, intended_dtype=None,
               attribute_kernels=True):
-    """Per-primitive FLOP/byte rows for an arbitrary traceable callable —
-    the hook tools/bench_roofline.py uses to cross-check its HLO-level
-    accounting against the pre-fusion jaxpr. Registered Pallas kernels
-    land as ``pallas::<name>`` rows priced by the kernel registry."""
+    """Per-primitive FLOP/byte rows for an arbitrary traceable callable:
+    the pre-fusion jaxpr's side of a cross-check against HLO-level
+    accounting. Registered Pallas kernels land as ``pallas::<name>`` rows
+    priced by the kernel registry."""
     import jax
 
     closed = jax.make_jaxpr(fn)(*example_args)

@@ -25,8 +25,8 @@ GIL-plain counter/edge/hold updates (new dict ENTRIES — never-seen
 edges, first holds, cycles, stalls — go through the watcher's private
 raw lock, so readers iterating under it never see a resize; in-place
 updates race benignly and may lose a count, which diagnostics tolerate).
-bench.py ``--lockwatch-bench`` prices the armed pair against a training
-step (<2% acceptance).
+What the armed pair costs against a training step is not measured on a
+chip.
 
 Reentrancy discipline: the watcher never emits to the hub while holding
 its own bookkeeping lock, and a thread inside watcher code sets a

@@ -242,8 +242,8 @@ register_rule(
     "registry, the shared interpret-mode gate, and the catalog/roofline "
     "discipline; a module inside ops/pallas/ that emits a pallas_call "
     "without registering a FLOP/byte model leaves that kernel invisible "
-    "to the jaxpr auditor — the MFU accountant and `bench_roofline "
-    "--jaxpr-table` under-count every program using it (the bug class "
+    "to the jaxpr auditor — the MFU accountant and the jaxpr cost "
+    "table under-count every program using it (the bug class "
     "that hid flash attention's FLOPs from the PR 5 MFU path)",
     "move the kernel into mxnet_tpu/ops/pallas/ and call "
     "registry.register_kernel(name, cost_fn) with the `name=` the "
@@ -310,7 +310,7 @@ register_rule(
     "gates cannot read, un-CRC'd files that read_ledger must treat as "
     "corrupt, and duplicate summary events that skew incident counts",
     "route run records through telemetry.ledger (record_run / "
-    "append_record / publish_bench) and resolve the store directory via "
+    "append_record) and resolve the store directory via "
     "telemetry.ledger.ledger_dir(); a deliberate bypass carries "
     "`# mxlint: disable=MX316` with a justification")
 

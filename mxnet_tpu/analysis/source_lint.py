@@ -51,8 +51,8 @@ Catches, before anything imports or traces:
                telemetry/ledger.py — the cross-run ledger owns the
                RunRecord schema and the atomic CRC'd append, so strays
                produce history the trend/compare gates cannot read
-               (telemetry.ledger.record_run / publish_bench /
-               ledger_dir() are the sanctioned shapes),
+               (telemetry.ledger.record_run / ledger_dir() are the
+               sanctioned shapes),
   MX601-602    robustness hazards (bare ``except:``; ``while True`` retry
                loops that swallow exceptions with no backoff/deadline —
                the loop shape that melts a parameter server under a

@@ -308,8 +308,8 @@ def overlap_efficiency(step_seconds, compute_seconds, comm_seconds) -> float:
     raw value above 1 is measurement skew (e.g. comm that also rode
     under host work outside the measured compute), not extra credit.
     Published as the hub gauge ``comm_overlap_efficiency`` (fit's
-    stale-sync epoch accounting, bench.py --overlap-bench). Returns 0.0
-    when either side is ~zero — nothing to hide, nothing hidden."""
+    stale-sync epoch accounting). Returns 0.0 when either side is ~zero
+    — nothing to hide, nothing hidden."""
     lo = min(float(compute_seconds), float(comm_seconds))
     if lo <= 0.0:
         return 0.0

@@ -24,7 +24,7 @@ Three complementary sources of truth:
      replica groups) into the same row shape, applying the same wire
      factors. This is the cross-check: the plan says what we built, the
      HLO says what XLA actually lowered (extends the test_comm_plan.py
-     machinery; bench --comm-bench asserts the two agree).
+     machinery; tests/test_comm.py asserts the two agree).
 """
 
 from __future__ import annotations
@@ -413,7 +413,7 @@ def hlo_collective_wire_bytes(hlo_text: str,
 # above a slab-sized element threshold measures exactly that — the
 # kernel path's quantize math lives inside per-BLOCK kernel bodies, so
 # its instructions stay under the threshold and the full-slab count
-# drops (asserted by tests/test_pallas_kernels.py and --kernel-bench).
+# drops (asserted by tests/test_pallas_kernels.py).
 
 _GENERIC_INSTR_RE = re.compile(
     r"=\s*((?:pred|bf16|f16|f32|f64|s8|u8|s16|u16|s32|u32|s64|u64)"

@@ -98,9 +98,8 @@ bool DecodeJpeg(const uint8_t* buf, size_t len, std::vector<uint8_t>* out,
 
 // Bilinear resize HWC u8 -> HWC u8. Fixed-point (16.16) with the x-axis
 // taps/weights precomputed once per image instead of per row — the resize
-// is the hottest non-decode stage of the pipeline (IO_SCALING_r03.json
-// puts resize+assembly at ~79% of worker cost), so it avoids all per-pixel
-// float math and recomputation.
+// is the hottest non-decode stage of the pipeline, so it avoids all
+// per-pixel float math and recomputation.
 void ResizeBilinear(const uint8_t* src, int sh, int sw, uint8_t* dst, int dh,
                     int dw) {
   constexpr int kShift = 16;
@@ -306,8 +305,8 @@ class ImagePipeline {
     int64_t start = ticket * B;
     out->pad = int(std::max<int64_t>(0, start + B - n));
     if (skip_work_) return true;  // MXTPU_NATIVE_SKIP_WORK=1: deliver zeroed
-    // batches, measuring only the serial path (ticketing + ordered delivery
-    // memcpy in Next()) for the Amdahl floor in tools/bench_io_scaling.py
+    // batches, leaving only the serial path live (ticketing + ordered
+    // delivery memcpy in Next())
     std::vector<uint8_t> payload, pixels, resized;
     for (int i = 0; i < B; ++i) {
       int64_t idx = order_[(start + i) % n];
@@ -333,9 +332,8 @@ class ImagePipeline {
         // Debug mode (MXTPU_NATIVE_SKIP_DECODE=1): substitute the JPEG
         // decode with a constant-fill of the same nominal geometry, keeping
         // every other stage (record read, CRC, resize, crop, mirror, batch
-        // assembly, delivery) live. tools/bench_io_scaling.py uses this to
-        // measure the pipeline's non-decode cost — the serial floor of the
-        // Amdahl projection.
+        // assembly, delivery) live: what is left is the pipeline's
+        // non-decode cost.
         h = w = std::max({256, cfg_.height, cfg_.width});
         pixels.assign(size_t(h) * w * 3, img_len ? img[0] : 0);
       } else if (!DecodeJpeg(img, img_len, &pixels, &h, &w)) {

@@ -25,9 +25,8 @@ Infrastructure:
   registry            every kernel registers its FLOP/byte model, keyed
                       by its pallas_call ``name=``; the jaxpr auditor
                       attributes kernel regions through it so MFU and
-                      ``bench_roofline --jaxpr-table`` stop
-                      under-counting custom kernels (mxlint MX312 keeps
-                      the discipline).
+                      the jaxpr cost table stop under-counting custom
+                      kernels (mxlint MX312 keeps the discipline).
   _common             the ONE interpret-mode gate: off-TPU backends run
                       every kernel through the Pallas interpreter, so
                       unit tests exercise the real kernel code paths on

@@ -6,15 +6,15 @@ another. This kernel flattens the (param, grad, m, v) pytrees into one
 padded slab and runs the complete Adam update — preprocess, moment
 updates, bias correction, weight step, AdamW's decoupled decay — tile by
 tile through VMEM: inside the kernel every element is read once and
-written once (the registry's byte model prices that floor; bench.py
---kernel-bench measures this rig). Honest accounting: the flatten/
+written once (the registry's byte model prices that floor). Honest
+accounting: the flatten/
 unflatten concatenate+slice passes around the kernel cost HBM copies of
 their own, so the net step-time win over a WELL-fused per-leaf tree is
 workload- and backend-dependent — the kernel's durable wins are the
 single program (one launch, no per-leaf scheduling gaps), the fixed
 pass structure XLA can't unfuse, and the slab layout the sharded
-optimizer work in ROADMAP item 4 builds on. The bench row reports the
-measured delta rather than assuming one.
+optimizer work in ROADMAP item 4 builds on. The delta is not measured
+on a chip (no benchmark cell runs the kernel).
 
 Exact-parity contract: the kernel reproduces ``Adam._apply_one``'s f32
 arithmetic op-for-op (same expressions, same evaluation order), so the

@@ -5,8 +5,8 @@ from their avals, but a ``pallas_call`` is opaque to that arithmetic: its
 inner jaxpr describes ONE grid cell, so recursing into it under-counts by
 the grid size, and the eqn itself prices as an elementwise op. Before
 this registry, flash attention's FLOPs were invisible to the MFU
-accountant and ``bench_roofline --jaxpr-table`` (the PR 5 under-counting
-this module exists to close).
+accountant and the jaxpr cost table (the PR 5 under-counting this
+module exists to close).
 
 The contract (mshadow's kernel-template discipline, applied to cost):
 
@@ -22,8 +22,9 @@ The contract (mshadow's kernel-template discipline, applied to cost):
     (under-counting) path so third-party pallas code never breaks an
     audit.
 
-Registered costs also feed ``bench.py --kernel-bench``'s roofline rows:
-achieved FLOP/s and bytes/s per kernel against the measured machine peak.
+Registered costs also feed roofline rows (``analysis.jaxpr_audit.cost_rows``,
+``telemetry.profiling``): achieved FLOP/s and bytes/s per kernel against
+the machine peak.
 """
 
 from __future__ import annotations

@@ -156,7 +156,7 @@ class Adam(Optimizer):
     blocked Pallas kernel (ops/pallas/adam.py) instead of the per-leaf
     elementwise tree — bitwise-identical results, same
     ``{name: (m, v, t)}`` state layout (checkpoints interchange freely);
-    step-time delta measured per rig by ``bench.py --kernel-bench``.
+    the step-time delta is not measured on a chip.
     None (default) reads the env gate ``MXNET_TPU_FUSED_ADAM``; the
     imperative KVStore path is unaffected.
     """

@@ -150,8 +150,8 @@ class ElasticCoordinator:
         self._hb_thread = None
         self._hb_stop = threading.Event()
         # committed resize records: {"from", "to", "ranks", "reason",
-        # "membership_epoch", "downtime_s"} — bench.py --elastic-bench and
-        # the acceptance tests read these
+        # "membership_epoch", "downtime_s"} — the acceptance tests read
+        # these
         self.history: list = []
 
     @classmethod

@@ -251,8 +251,8 @@ def observe_device_stats(groups, hstate, epoch, step):
 
 def aggregate_events(events):
     """Per-layer aggregate over exported ``health``/``health_anomaly``
-    events — the one table builder behind the ``telemetry health`` CLI
-    and ``bench.py --health-bench``: last + max gradient norm, last
+    events — the one table builder behind the ``telemetry health``
+    CLI: last + max gradient norm, last
     weight norm and update:weight ratio, summed nonfinite elements, and
     the anomaly count attributed to each layer."""
     def _fresh():

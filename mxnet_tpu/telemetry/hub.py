@@ -143,8 +143,7 @@ class MetricsHub:
 
     Thread-safe; every mutation holds one lock for a few dict/deque
     operations (the lock-cheap contract: ``emit`` is a dict build + deque
-    append, measured in single-digit microseconds — bench.py
-    --telemetry-bench asserts it stays under 2% of a smoke-run step)."""
+    append)."""
 
     def __init__(self, ring_size=8192):
         # run identity (ISSUE 20): every hub mints one — unlike trace_id,

@@ -8,7 +8,7 @@ ROADMAP item 4 (profile-guided auto-tuning) needs measured winners
 "persisted keyed by (model fingerprint, world, backend)" and had nothing
 to persist into. This module is that store:
 
-  **RunRecord** — at the end of every ``fit``/``predict``/bench run,
+  **RunRecord** — at the end of every ``fit``/``predict`` run,
   :func:`distill` folds the run's event stream into ONE compact,
   schema-versioned dict: identity (``run_id``/``trace_id``, the
   ``graph_fingerprint`` of the trained symbol, world size, backend, and
@@ -63,13 +63,9 @@ from ..analysis.lockwatch import named_lock
 __all__ = ["LEDGER_SCHEMA", "ledger_dir", "distill", "append_record",
            "record_run", "read_ledger", "match", "metric_direction",
            "trend_gate", "knob_attribution", "best_record",
-           "warm_start_tier", "publish_bench", "BENCH_LEDGER_NAME"]
+           "warm_start_tier"]
 
 LEDGER_SCHEMA = 1
-
-# the per-bench headline aggregation bench.py emits (satellite: the perf
-# trajectory as ONE machine-readable file instead of N ad-hoc JSONs)
-BENCH_LEDGER_NAME = "BENCH_LEDGER_r20.json"
 
 # knob vector keys every fit record carries (absent knobs read as None so
 # compare() can pair records across versions)
@@ -80,7 +76,6 @@ KNOB_KEYS = ("compression", "overlap_bytes", "comm_kernels", "fused_adam",
 _METRIC_WORSE_UP = {
     "step_ms_p50": True, "step_ms_p90": True, "step_ms_p99": True,
     "wall_seconds": True, "wire_bytes": True, "peak_mem_bytes": True,
-    "value": True,            # bench headline (latency-style by default)
     "mfu_pct": False, "measured_mfu_pct": False, "goodput_pct": False,
 }
 
@@ -300,8 +295,8 @@ def append_record(record, directory=None, logger=None):
 
 
 def record_run(kind, directory=None, logger=None, **distill_kwargs):
-    """distill + append in one call — THE end-of-run hook fit/predict/
-    bench use. Fast no-op (no distillation) when the ledger is off."""
+    """distill + append in one call — THE end-of-run hook fit/predict
+    use. Fast no-op (no distillation) when the ledger is off."""
     directory = ledger_dir(directory)
     if directory is None:
         return None
@@ -481,60 +476,3 @@ def warm_start_tier(fingerprint, world, backend=None, directory=None,
             "record_id": best.get("record_id"),
             "runs": len(recs),
             metric: _metric_of(best, metric)}
-
-
-# -- bench integration ---------------------------------------------------------
-
-def publish_bench(result, filename=None, bench_dir=None, smoke=False,
-                  fingerprint=None, logger=None):
-    """The ONE writer every ``bench.py --*-bench`` headline flows
-    through (satellite: no more N ad-hoc JSON files with no history).
-
-    - writes the per-bench ``BENCH_<X>_rNN.json`` (``filename`` under
-      ``bench_dir``; full runs only — smoke keeps CI file-free),
-    - appends a ``kind="bench"`` RunRecord to the ledger when
-      ``MXNET_TPU_LEDGER_DIR`` is configured,
-    - regenerates :data:`BENCH_LEDGER_NAME` — every bench record the
-      ledger holds, one machine-readable trajectory (full runs write it
-      next to the per-bench file; smoke runs write it into the ledger
-      dir when one is configured, so gating tests can assert on it).
-
-    Returns {"bench_path", "record", "ledger_path", "bench_ledger_path"}.
-    """
-    out = {"bench_path": None, "record": None, "ledger_path": None,
-           "bench_ledger_path": None}
-    if filename and bench_dir and not smoke:
-        path = os.path.join(bench_dir, filename)
-        with open(path, "w") as f:
-            json.dump(result, f, indent=2)
-            f.write("\n")
-        out["bench_path"] = path
-
-    headline = {k: result.get(k) for k in
-                ("metric", "value", "unit", "vs_baseline")
-                if k in result}
-    record = distill(
-        "bench", fingerprint=fingerprint,
-        world_size=result.get("world"),
-        completed=True, since_ts=float("inf"),  # no ring events: the
-        # headline row IS the outcome (bench functions own their numbers)
-        knobs={}, extra_outcomes=headline)
-    record["outcomes"]["smoke"] = bool(smoke)
-    out["record"] = record
-
-    directory = ledger_dir()
-    if directory is not None:
-        out["ledger_path"] = append_record(record, directory=directory,
-                                           logger=logger)
-
-    bench_rows = [r for r in read_ledger(directory)
-                  if r.get("kind") == "bench"] if directory else [record]
-    target_dir = bench_dir if (bench_dir and not smoke) else directory
-    if target_dir:
-        bl_path = os.path.join(target_dir, BENCH_LEDGER_NAME)
-        with open(bl_path, "w") as f:
-            json.dump({"ledger_schema": LEDGER_SCHEMA,
-                       "records": bench_rows}, f, indent=1, default=str)
-            f.write("\n")
-        out["bench_ledger_path"] = bl_path
-    return out

@@ -11,7 +11,7 @@ all in the one program. The denominator resolves, in order:
   1. ``MXNET_TPU_PEAK_FLOPS`` — peak FLOP/s **per device**, for a chip
      the table does not know;
   2. :data:`DEVICE_PEAKS`, the published peak keyed by the device's
-     ``device_kind`` — the one table ``bench.py`` reads too;
+     ``device_kind``;
   3. on the CPU backend only, a one-time measured matmul rate (a
      datasheet number would be fiction there; the ratio is rig-relative).
 

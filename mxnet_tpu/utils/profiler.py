@@ -121,8 +121,8 @@ def trace_op_stats(log_dir: str, device_substr: str = "", top: int | None = None
     ``hlo_op``-arg lanes): instruction-id suffixes stripped so repeats
     of the same fusion aggregate, rows sorted by total time. This is the
     op breakdown the profiler UI shows, available programmatically (used
-    to find, e.g., that a ResNet step's time lives in conv+stats fusions
-    — see bench.py notes). Wrapper instructions (``call``/``while``) are
+    to find, e.g., that a ResNet step's time lives in conv+stats
+    fusions). Wrapper instructions (``call``/``while``) are
     kept here — this table is the raw per-instruction view; the
     layer-attributed, double-booking-safe view is
     telemetry.profiling.build_report.
@@ -213,7 +213,7 @@ def profile_step(fn, *args, iters: int = 3, log_dir: str | None = None,
     triggered (warmup included) are logged via :func:`compile_report`, and
     ``return_compile=True`` returns ``(stats, log_dir, compile_delta)``
     with the raw counter deltas (compile count/seconds, cache hits/misses,
-    persistent-cache traffic) for programmatic use (bench --compile-bench).
+    persistent-cache traffic) for programmatic use.
     """
     import logging
 

@@ -202,36 +202,53 @@ def test_allreduce_plan_ratios():
     assert a2a["wire_bytes"] < a2a_int8["wire_bytes"] / 3
 
 
+def _sync_hlo(mode, length):
+    """Optimized HLO of the dp-8 gradient sync of one ``length``-element
+    leaf under ``mode``."""
+    mesh = _mesh8()
+    g = np.random.RandomState(0).randn(8, length).astype(np.float32)
+
+    def body(gs):
+        out = comm.compressed_allreduce({"w": gs[0]}, mode, "dp",
+                                        axis_size=8, average=True)
+        return out["w"][None]
+
+    f = jax.jit(shard_map(body, mesh=mesh, in_specs=P("dp"),
+                          out_specs=P("dp"), check_vma=False))
+    return f.lower(g).compile().as_text()
+
+
 def test_int8_hlo_wire_bytes_cut_at_least_3_5x():
     """ACCEPTANCE: compile the same dp-8 gradient sync uncompressed and
     int8-compressed; the collective-byte tables extracted from the
     optimized HLO must show >= 3.5x fewer wire bytes for int8. (int8/uint8
     payloads are faithfully visible in CPU HLO; bf16 ones are upcast by
     the CPU backend's float normalization — see comm/stats.py.)"""
-    mesh = _mesh8()
     L = 8192
-    g = np.random.RandomState(0).randn(8, L).astype(np.float32)
-
-    def build(mode):
-        def body(gs):
-            out = comm.compressed_allreduce({"w": gs[0]}, mode, "dp",
-                                            axis_size=8, average=True)
-            return out["w"][None]
-
-        f = jax.jit(shard_map(body, mesh=mesh, in_specs=P("dp"),
-                              out_specs=P("dp"), check_vma=False))
-        return f.lower(g).compile().as_text()
-
-    wire_fp32 = comm.hlo_collective_wire_bytes(build(None), 8)
-    wire_int8 = comm.hlo_collective_wire_bytes(build("int8"), 8)
+    hlo_int8 = _sync_hlo("int8", L)
+    wire_fp32 = comm.hlo_collective_wire_bytes(_sync_hlo(None, L), 8)
+    wire_int8 = comm.hlo_collective_wire_bytes(hlo_int8, 8)
     assert wire_fp32 > 0 and wire_int8 > 0
     ratio = wire_fp32 / wire_int8
     assert ratio >= 3.5, f"int8 wire reduction only {ratio:.2f}x"
     # and the closed-form plan agrees with the compiled reality (2%)
     plan = comm.allreduce_plan(L, 8, "int8")
     assert wire_int8 == pytest.approx(plan["wire_bytes"], rel=0.02)
-    table = comm.hlo_collective_table(build("int8"), 8)
+    table = comm.hlo_collective_table(hlo_int8, 8)
     assert {r["op"] for r in table} >= {"all-to-all", "all-gather"}
+
+
+@pytest.mark.parametrize("mode", [None, "bf16", "int8", "twobit"])
+def test_hlo_collective_table_sees_every_mode(mode):
+    """Each compression mode's dp-8 gradient sync shows its collectives in
+    the compiled HLO: the table has rows, their wire bytes are positive,
+    and the closed-form plan prices the same sync above zero. (Only the
+    integer payloads survive CPU lowering byte for byte: the int8 case's
+    agreement with the plan is the test above.)"""
+    table = comm.hlo_collective_table(_sync_hlo(mode, 4096),
+                                      default_group_size=8)
+    assert table and all(r["wire_bytes"] > 0 for r in table), table
+    assert comm.allreduce_plan(4096, 8, mode)["wire_bytes"] > 0
 
 
 # -- make_data_parallel_step ---------------------------------------------------

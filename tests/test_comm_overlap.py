@@ -295,6 +295,18 @@ def test_overlap_hlo_has_independent_collective_pairs():
         if "all-to-all" in r["op"])
     assert hlo_a2a_payload == pytest.approx(plan_a2a, rel=0.05)
 
+    # loss parity of the two schedules: the buckets change where the
+    # quantization scales fall, not what is trained
+    def loss_after(step, call, steps=4):
+        for _ in range(steps):
+            res = step(*call)
+            jax.block_until_ready(res[2])
+            call = (res[0], res[1], data) + tuple(res[3:])
+        return float(np.asarray(res[2]))
+
+    assert abs(loss_after(step_f, (params, {}, data, resid_f))
+               - loss_after(step_o, (params, {}, data, resid_o))) < 1e-5
+
 
 # -- fit(overlap=...) ----------------------------------------------------------
 

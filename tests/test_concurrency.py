@@ -297,6 +297,45 @@ def test_group_server_membership_churn_zero_cycles(watchdog):
     assert lockwatch.report()["cycles"] == []
 
 
+def test_elastic_resize_fit_zero_cycles(watchdog, tmp_path):
+    """A dp-4 fit that shrinks to 3 mid-epoch and regrows to 4, armed:
+    the coordinator, the checkpoint plane, the hub and the feed take
+    their locks across two resizes, the watchdog observes them (the
+    acquire count moves) and sees zero lock-order cycles."""
+    import jax
+
+    from mxnet_tpu.resilience import ElasticCoordinator
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 devices")
+    data = mx.sym.Variable("data")
+    net = mx.sym.Activation(mx.sym.FullyConnected(
+        data, name="fc1", num_hidden=16), name="a1", act_type="tanh")
+    net = mx.sym.SoftmaxOutput(mx.sym.FullyConnected(
+        net, name="fc2", num_hidden=2), name="softmax")
+    rng = np.random.RandomState(0)
+    X = rng.randn(192, 10).astype(np.float32)
+    y = rng.randint(0, 2, (192,)).astype(np.float32)
+    co = ElasticCoordinator(4)
+
+    def drive(param):
+        if param.epoch == 1 and param.nbatch == 1 and co.world_size == 4:
+            co.kill()
+        if param.epoch == 2 and param.nbatch == 1 and co.world_size == 3:
+            co.join_all()
+
+    model = mx.FeedForward(net, ctx=[mx.cpu(i) for i in range(4)],
+                           num_epoch=4, optimizer="sgd", learning_rate=0.05)
+    before = watchdog.acquires
+    model.fit(X, y, batch_size=48, elastic=co,   # 48 % 12 == 0: 4 and 3
+              sharded_checkpoint_dir=str(tmp_path / "ckpt"),
+              batch_end_callback=drive, telemetry=True)
+    assert co.resizes == 2
+    assert [h["to"] for h in co.history] == [3, 4]
+    assert watchdog.acquires > before
+    assert lockwatch.report()["cycles"] == []
+
+
 # -- thread-name contract ------------------------------------------------------
 
 def _names():

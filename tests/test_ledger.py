@@ -6,8 +6,7 @@ through the atomic CRC'd store, concurrent multi-process appends,
 corrupt-record skip-not-fatal reads, the trend gate (exit 3 on an
 injected regression through the CLI), knob attribution across record
 pairs differing in exactly one knob, the FleetController warm-start
-sensor picking the historically best tier, bench publishing through the
-one writer (BENCH_LEDGER_r20.json), and the e2e acceptance: two dp-8
+sensor picking the historically best tier, and the e2e acceptance: two dp-8
 fits differing only in compression tier land as two comparable records
 while the armed zero-recompile epoch stays green with the ledger on.
 """
@@ -276,38 +275,6 @@ def test_warm_start_picks_historically_best_tier(tmp_path, monkeypatch):
                     if dec["outcome"] == "warm_start"]
     finally:
         ctl2.unbind()
-
-
-# -- bench publishing ----------------------------------------------------------
-
-def test_publish_bench_full_and_smoke(tmp_path, monkeypatch):
-    d = str(tmp_path / "ledger")
-    bench_dir = str(tmp_path / "bench")
-    os.makedirs(bench_dir)
-    monkeypatch.setenv("MXNET_TPU_LEDGER_DIR", d)
-    result = {"metric": "widget_bench_ms", "value": 3.5, "unit": "ms",
-              "vs_baseline": 1.2, "detail": {"x": 1}}
-    out = ledger.publish_bench(result, filename="BENCH_WIDGET_r99.json",
-                               bench_dir=bench_dir)
-    assert json.load(open(out["bench_path"]))["value"] == 3.5
-    assert out["record"]["kind"] == "bench"
-    assert out["record"]["outcomes"]["metric"] == "widget_bench_ms"
-    assert out["ledger_path"] is not None
-    combined = json.load(open(out["bench_ledger_path"]))
-    assert os.path.dirname(out["bench_ledger_path"]) == bench_dir
-    assert combined["records"][-1]["outcomes"]["value"] == 3.5
-
-    # smoke: no per-bench artifact; the trajectory regenerates into the
-    # ledger dir (so CI gating can still read it) and marks the record
-    out2 = ledger.publish_bench({"metric": "widget_bench_ms",
-                                 "value": 4.0, "unit": "ms"},
-                                filename="BENCH_WIDGET_r99.json",
-                                bench_dir=bench_dir, smoke=True)
-    assert out2["bench_path"] is None
-    assert os.path.dirname(out2["bench_ledger_path"]) == d
-    assert out2["record"]["outcomes"]["smoke"] is True
-    rows = [r for r in ledger.read_ledger(d) if r["kind"] == "bench"]
-    assert len(rows) == 2
 
 
 # -- e2e acceptance ------------------------------------------------------------

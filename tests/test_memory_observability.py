@@ -427,6 +427,8 @@ def test_zero_recompile_armed_epoch_with_memory_tracking():
     assert len(model.telemetry.steps("step")) == 12
     marks = telemetry.hub().events(kind="memory_watermark")
     assert [e["epoch"] for e in marks] == [0, 1, 2]
+    # the ledger saw the tracked run: every epoch's window held arrays
+    assert all(e["watermark_bytes"] > 0 for e in marks), marks
     assert not telemetry.memory.tracking_enabled()  # fit restored state
 
 

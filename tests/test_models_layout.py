@@ -1,8 +1,7 @@
 """Layout portability of the conv model zoo: NCHW (reference parity) and
 NHWC (TPU fast path) must compute the same function from the same OIHW
 weights — the contract models/resnet.py established, now also carried by
-models/inception.py (the BASELINE anchor architecture bench.py --model
-inception_bn measures)."""
+models/inception.py (the BASELINE anchor architecture)."""
 
 import jax
 import jax.numpy as jnp

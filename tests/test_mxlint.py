@@ -1105,7 +1105,7 @@ def test_fixture_mx316_env_consultation_and_summary_emit():
 
 
 def test_fixture_mx316_sanctioned_paths_clean():
-    # the sanctioned shapes: ledger_dir()/record_run/publish_bench, other
+    # the sanctioned shapes: ledger_dir()/record_run, other
     # env vars, other emit kinds — and monkeypatch.setenv (keyword "key"
     # position is not the getter-call shape MX316 matches)
     src = (

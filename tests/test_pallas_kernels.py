@@ -15,6 +15,8 @@ within a program, which is exactly the context the kernels run in (the
 fused train step) — eager-vs-jit is the comparison that isn't meaningful.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -456,7 +458,7 @@ def test_mfu_accountant_counts_flash():
     assert flops >= 4 * 1 * 2 * 64 * 64 * 32  # the flash_fwd model alone
 
 
-def test_bench_roofline_jaxpr_table_shows_kernels():
+def test_cost_rows_jaxpr_table_shows_kernels():
     rows, totals = jaxpr_audit.cost_rows(
         lambda x: pk.flash_attention(x, x, x, causal=False,
                                      block_q=32, block_k=32),
@@ -467,6 +469,69 @@ def test_bench_roofline_jaxpr_table_shows_kernels():
                                      block_q=32, block_k=32),
         jnp.zeros((1, 1, 64, 32), jnp.float32), attribute_kernels=False)
     assert totals["flops"] > legacy_totals["flops"]
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_family_calls():
+    """One traceable call a kernel family, with its operands: what a
+    roofline table prices. Built once for the seven cases below."""
+    rng = np.random.RandomState(0)
+    q = jnp.asarray(rng.randn(1, 2, 128, 32).astype(np.float32))
+    slab = jnp.asarray(rng.randn(8, 4096).astype(np.float32))
+    spec8 = comm.CompressionSpec("int8", chunk=256)
+    spec2 = comm.CompressionSpec("twobit", threshold=0.5, chunk=256)
+    shapes = {"p0": (256, 64), "p1": (64,), "p2": (64, 32)}
+    params = {n: jnp.asarray(rng.randn(*sh).astype(np.float32))
+              for n, sh in shapes.items()}
+    adam = opt_mod.Adam(lr=1e-3, fused=True)
+    x_mm = jnp.asarray(rng.randn(64, 128).astype(np.float32))
+    w_mm = jnp.asarray(rng.randn(64, 128).astype(np.float32))
+    return {
+        "flash_attention_fwd": (
+            lambda x: pk.flash_attention(x, x, x, causal=True), (q,),
+            {"pallas::flash_fwd"}),
+        "flash_attention_fwd_bwd": (
+            lambda x: jax.grad(lambda y: jnp.sum(
+                pk.flash_attention(y, y, y, causal=True)))(x), (q,),
+            {"pallas::flash_fwd", "pallas::flash_bwd_dq",
+             "pallas::flash_bwd_dkv"}),
+        "quant_int8": (
+            lambda r: pk.fused_quantize(spec8, r, want_dequant=True)[0]["q"],
+            (slab,), {"pallas::quant_int8"}),
+        "quant_twobit": (
+            lambda r: pk.fused_quantize(spec2, r, want_dequant=True)[0]["q"],
+            (slab,), {"pallas::quant_twobit"}),
+        # the payload is an operand, so the row prices the dequant-sum
+        # kernel alone
+        "dequant_sum_int8": (
+            lambda p: pk.fused_dequant_sum(spec8, p),
+            (jax.jit(lambda r: pk.fused_quantize(spec8, r)[0])(slab),),
+            {"pallas::dequant_sum_int8"}),
+        "fused_adam": (
+            lambda p, g, st: pk.fused_adam_apply(
+                adam, p, g, st, jnp.float32(1e-3))[0]["p0"],
+            (params, params, adam.init_state_tree(params)),
+            {"pallas::fused_adam"}),
+        "int8_matmul": (
+            lambda a, w: pk.int8_matmul(a, w), (x_mm, w_mm),
+            {"pallas::int8_matmul"}),
+    }
+
+
+@pytest.mark.parametrize("family", [
+    "flash_attention_fwd", "flash_attention_fwd_bwd", "quant_int8",
+    "quant_twobit", "dequant_sum_int8", "fused_adam", "int8_matmul"])
+def test_cost_rows_price_every_kernel_family(family):
+    """A roofline row a kernel family needs the registry to price it: the
+    call's jaxpr shows the family's kernels as ``pallas::<name>`` rows,
+    each with FLOP and bytes above zero (a kernel without a cost model
+    would be invisible to the MFU accountant)."""
+    fn, operands, want = _kernel_family_calls()[family]
+    rows, _ = jaxpr_audit.cost_rows(fn, *operands)
+    krows = [r for r in rows if r["primitive"].startswith("pallas::")]
+    assert want <= {r["primitive"] for r in krows}, krows
+    for r in krows:
+        assert r["flops"] > 0 and r["bytes"] > 0, r
 
 
 # -- end-to-end: the armed epoch with every kernel on --------------------------

@@ -1,7 +1,7 @@
 """Profiler tests: the trace-digest parser against a synthesized XProf
 export (deterministic), plus a live profile_step smoke on CPU (host traces
 carry no per-op XLA lanes, so stats may be empty there — the parser's op
-rows come from device traces, as used for the bench.py analysis)."""
+rows come from device traces)."""
 
 import gzip
 import json

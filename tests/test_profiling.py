@@ -239,11 +239,13 @@ def test_fit_profile_window_acceptance(tmp_path):
     # real model layers attributed, not just the pseudo-categories
     assert {"fc1", "fc2"} <= set(rep.layers), rep.layers
     assert "comm" in rep.layers  # the int8 sync's device cost is named
+    # the hotspot table is sorted and its head holds device time
+    assert rep.ops and rep.ops[0]["us"] > 0
     # measured roofline: source stamped, models joined, bound classified
     assert rep.roofline, "no measured roofline rows"
     for row in rep.roofline:
         assert row["source"] == "measured"
-        assert row["model_flops"] > 0
+        assert row["model_flops"] > 0 and row["measured_ms_per_step"] > 0
         assert row.get("bound") in ("compute", "bandwidth", None)
     prims = {r["op"] for r in rep.roofline}
     assert "dot_general" in prims
@@ -374,11 +376,21 @@ def test_reused_log_dir_isolates_windows(tmp_path):
     assert os.path.dirname(first.log_dir) == str(tmp_path / "prof")
     assert os.path.dirname(second.log_dir) == str(tmp_path / "prof")
     assert first.steps == second.steps == 3
-    # same program, same window length: the second report must be in the
-    # same ballpark, not a two-window aggregate (the bug read ~2x;
-    # generous margin for shared-box noise)
-    assert second.total_us < 1.75 * first.total_us, \
-        (first.total_us, second.total_us)
+
+    # same program, same window length: each window's directory holds
+    # every instruction of the predict program once a step, not a
+    # two-window aggregate (the bug's reading, which the shared parent
+    # directory still shows: twice the steps)
+    def step_counts(log_dir):
+        rows = profiling.parse_trace_dir(log_dir)
+        return {instr: row["count"] for (module, instr), row in rows.items()
+                if module == "jit_step"}
+
+    one, two = step_counts(first.log_dir), step_counts(second.log_dir)
+    assert one and set(one) == set(two)
+    assert set(one.values()) == set(two.values()) == {3}, (one, two)
+    both = step_counts(str(tmp_path / "prof"))
+    assert set(both) == set(one) and set(both.values()) == {6}, both
 
 
 def test_short_predict_closes_partial_window():
