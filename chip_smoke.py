@@ -433,6 +433,44 @@ def kernels_int8_matmul(covered):
     covered |= {"int8_matmul"}
 
 
+def kernels_moe(covered):
+    """The expert layer's two kernels at the widths of ``laguna_xs2``
+    (8,192 rows, top-8, hidden 2,048, bf16) with an eighth of the picks
+    held: the gather bit for bit, the float32 sum to one bf16 rounding."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mxnet_tpu.ops.pallas.moe import moe_combine, moe_dispatch
+
+    rows, k, width = 8192, 8, 2048
+    rng = np.random.default_rng(5)
+    key = np.where(rng.random(rows * k) < 0.125,
+                   rng.integers(0, 32, rows * k), 32)
+    order = jnp.asarray(np.argsort(key, kind="stable"), jnp.int32)
+    n = int((key < 32).sum())
+    token, count = order // k, jnp.int32(n)
+    x = jnp.asarray(rng.standard_normal((rows, width)), jnp.bfloat16)
+    scale = jnp.asarray(rng.random(rows * k), jnp.float32)
+    xs = compiled_call(moe_dispatch, x, token, count, scale)
+    want = (x[token].astype(jnp.float32) * scale[:, None]) \
+        .astype(jnp.bfloat16)
+    check(exactly_equal(xs[:n], want[:n]),
+          "expert dispatch equals the scaled gather on the held entries")
+    held = (jnp.arange(rows * k) < n)[:, None]
+    y = jnp.where(held, xs, jnp.nan)      # nothing behind the count is read
+    back = compiled_call(lambda y, t, c, w: moe_combine(y, t, c, rows, w),
+                         y, token, count, scale)
+    want = jnp.zeros((rows, width), jnp.float32).at[token].add(
+        jnp.where(held, y.astype(jnp.float32) * scale[:, None], 0))
+    err = rel_err(back, want)
+    log(f"expert dispatch + combine, {n} of {rows * k} entries held: "
+        f"rel err of the sum {err:.2e}")
+    check(err <= 4e-3, "expert combine within one bf16 rounding of the "
+          "float32 sum")
+    covered |= {"moe_dispatch", "moe_combine"}
+
+
 def phase_kernels(sizes):
     from mxnet_tpu.ops.pallas import kernel_names
 
@@ -441,6 +479,7 @@ def phase_kernels(sizes):
     kernels_comm(sizes, covered)
     kernels_adam(covered)
     kernels_int8_matmul(covered)
+    kernels_moe(covered)
     check(covered == set(kernel_names()),
           f"every registered kernel ran: missing "
           f"{sorted(set(kernel_names()) - covered)}, unknown "

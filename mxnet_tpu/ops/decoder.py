@@ -216,54 +216,66 @@ class RotaryAttentionOp(OpProp):
 
 # -- experts ------------------------------------------------------------------
 # The picks live in an expanded space of rows * top_k entries, sorted by
-# expert. Going there and back is a permutation (each sorted entry is read
-# exactly once on the way back), so both directions and both gradients are
-# gathers: AD's transpose of a gather would be a scatter-add.
+# expert, of which the first ``n`` hold a pick on an expert kept here. Going
+# there and back is a permutation (each sorted entry is read exactly once on
+# the way back), so each direction's gradient is the other direction: the
+# two kernels of ops/pallas/moe.py, whose work follows ``n``. What lies
+# behind ``n`` in the space is never written and never read.
+
+def _kernels():
+    from .pallas import moe     # the package imports this module's own
+
+    return moe
+
 
 @jax.custom_vjp
-def _rows_to_sorted(x, order, slots):
-    """``x[order // top_k]``: (rows, w) -> (rows * top_k, w). ``order``
-    (rows * top_k,) is the pick each sorted entry holds, ``slots`` (rows,
-    top_k) its inverse: where each of a row's picks landed."""
-    return x[order // slots.shape[1]]
+def _rows_to_sorted(x, order, slots, n):
+    """``x[order // top_k]`` for the first ``n`` sorted entries: (rows, w)
+    -> (rows * top_k, w). ``order`` (rows * top_k,) is the pick each sorted
+    entry holds, ``slots`` (rows, top_k) its inverse: where each of a row's
+    picks landed."""
+    return _rows_to_sorted_fwd(x, order, slots, n)[0]
 
 
-def _rows_to_sorted_fwd(x, order, slots):
-    return x[order // slots.shape[1]], slots
+def _rows_to_sorted_fwd(x, order, slots, n):
+    token = order // slots.shape[1]
+    return _kernels().moe_dispatch(x, token, n), (token, slots, n)
 
 
-def _rows_to_sorted_bwd(slots, g):
-    return g[slots].astype(jnp.float32).sum(axis=1).astype(g.dtype), \
-        None, None
+def _rows_to_sorted_bwd(res, g):
+    token, slots, n = res
+    return _kernels().moe_combine(g, token, n, slots.shape[0]), \
+        None, None, None
 
 
 _rows_to_sorted.defvjp(_rows_to_sorted_fwd, _rows_to_sorted_bwd)
 
 
-def _combine(y, weight, slots):
-    return jnp.einsum("rk,rkw->rw", weight.astype(jnp.float32),
-                      y[slots].astype(jnp.float32)).astype(y.dtype)
-
-
 @jax.custom_vjp
-def _sorted_to_rows(y, weight, order, slots):
-    """``sum_j weight[r, j] * y[slots[r, j]]``: (rows * top_k, w) -> (rows,
-    w), accumulated in float32."""
-    return _combine(y, weight, slots)
+def _sorted_to_rows(y, weight, order, slots, n):
+    """``sum_j weight[r, j] * y[slots[r, j]]`` over the picks with
+    ``slots[r, j] < n``: (rows * top_k, w) -> (rows, w), accumulated in
+    float32."""
+    return _sorted_to_rows_fwd(y, weight, order, slots, n)[0]
 
 
-def _sorted_to_rows_fwd(y, weight, order, slots):
-    return _combine(y, weight, slots), (y, weight, order, slots)
+def _sorted_to_rows_fwd(y, weight, order, slots, n):
+    rows, k = slots.shape
+    token, by_entry = order // k, weight.reshape(-1)[order]
+    return _kernels().moe_combine(y, token, n, rows, by_entry), \
+        (y, token, by_entry, slots, n)
 
 
 def _sorted_to_rows_bwd(res, g):
-    y, weight, order, slots = res
-    dy = (g[order // slots.shape[1]].astype(jnp.float32)
-          * weight.reshape(-1)[order][:, None].astype(jnp.float32)
-          ).astype(y.dtype)
-    dweight = jnp.einsum("rw,rkw->rk", g.astype(jnp.float32),
-                         y[slots].astype(jnp.float32)).astype(weight.dtype)
-    return dy, dweight, None, None
+    y, token, by_entry, slots, n = res
+    dispatch = _kernels().moe_dispatch
+    dy = dispatch(g, token, n, by_entry)
+    # the routing weights' gradient: a pass over the whole space, made only
+    # where the weights are trained (unused, it is no part of the program)
+    dot = jnp.sum(dispatch(g, token, n).astype(jnp.float32)
+                  * y.astype(jnp.float32), axis=1)
+    dweight = jnp.where(slots < n, dot[slots], 0.0).astype(by_entry.dtype)
+    return dy, dweight, None, None, None
 
 
 _sorted_to_rows.defvjp(_sorted_to_rows_fwd, _sorted_to_rows_bwd)
@@ -302,6 +314,9 @@ class MixtureOfExpertsOp(OpProp):
     dropped whatever the load. With ``shared_width`` a shared expert
     (``shared_{gate,up,down}_weight``) sees every row. Output (rows,
     hidden): this rank's part of the routed sum, plus the shared expert.
+    Rows go to the sorted space and come back through the two kernels of
+    ops/pallas/moe.py, whose work is the picks held here in this step; the
+    space behind them is unwritten memory that nothing reads.
 
     ``train_router=False`` cuts the routing weights out of the gradient
     (the router's weight then gets a zero gradient). It is for a rank that
@@ -384,12 +399,16 @@ class MixtureOfExpertsOp(OpProp):
 
         The picks live in a sorted space of rows x top_k entries, those on
         experts held here first, by expert. No pick is dropped, so the
-        space is all of it whatever the load; the grouped products do the
-        rows that exist, the gathers around them move the whole space
-        (a space sized by the step's own count needs control flow in the
-        program, which the device-time accounting by instruction cannot
-        see into: ROADMAP B3)."""
+        space is all of it whatever the load, and the work is the load's:
+        the two kernels that take rows there and back move the ``n``
+        entries that hold a pick of this step (``n = sum(sizes)``, read on
+        the device: no control flow in the program), the grouped products
+        do the rows of their groups. Behind ``n`` the space is unwritten
+        memory that nothing reads; the elementwise product between the
+        grouped products runs over it and its result there is as
+        meaningless."""
         rows, k, held = x.shape[0], self.top_k, self.experts_held
+        self._step_space = rows * k      # for the epoch's record
         local = experts - self.first_expert
         here = (local >= 0) & (local < held)
         # picks on experts held elsewhere sort behind every group
@@ -399,21 +418,24 @@ class MixtureOfExpertsOp(OpProp):
             jnp.arange(rows * k, dtype=jnp.int32),
             unique_indices=True).reshape(rows, k)     # pick -> sorted entry
         sizes = _count(key, held)
-        inside = (jnp.arange(rows * k) < jnp.sum(sizes))[:, None]
-        # entries behind the last group belong to no product: whatever the
-        # grouped product leaves there is cut off on both sides
-        xs = jnp.where(inside, _rows_to_sorted(x, order, slots), 0)
+        n = jnp.sum(sizes)
+        xs = _rows_to_sorted(x, order, slots, n)
 
         def grouped(a, w):
             with jax.named_scope("grouped"):
+                # the product leaves the kernel as the activations' type
+                # (accumulated in float32 inside it: bit for bit what a
+                # float32 result rounded afterwards is, PERF.md section 6,
+                # PR 30), so no float32 copy of the space is written and
+                # converted, forward or backward
                 return jax.lax.ragged_dot(
                     a, jnp.swapaxes(w, 1, 2).astype(a.dtype), sizes,
-                    preferred_element_type=jnp.float32).astype(a.dtype)
+                    preferred_element_type=a.dtype)
 
         h = jax.nn.silu(grouped(xs, gate_w)) * grouped(xs, up_w)
-        ys = jnp.where(inside, grouped(jnp.where(inside, h, 0), down_w), 0)
-        return _sorted_to_rows(ys, jnp.where(here, weights, 0.0), order,
-                               slots)
+        return _sorted_to_rows(grouped(h, down_w),
+                               jnp.where(here, weights, 0.0), order, slots,
+                               n)
 
     def fwd(self, ins, aux, is_train, rng):
         x, router_w, gate_w, up_w, down_w = ins[:5]
@@ -432,11 +454,17 @@ class MixtureOfExpertsOp(OpProp):
         """This epoch's picks (``OpProp.epoch_record``): over all experts
         ``picks_all`` and ``tokens``, over those held here ``picks_held``,
         the busiest one's ``max_held`` and how many had a pick at all,
-        ``experts_hit``."""
+        ``experts_hit``; ``space``, the entries of the sorted space over
+        the epoch's steps (rows x top_k a step), of which the kernels move
+        ``picks_held``, by tiles of ``tile`` sorted entries (the step this
+        operator was last traced for)."""
         counts = (after[0].astype(np.int64) - before[0].astype(np.int64)) \
             % self.LOAD_WRAP
         held = counts[self.first_expert:self.first_expert + self.experts_held]
         return "fit.epoch.expert_load", {
+            "space": float(counts.sum()),
+            "tile": _kernels().space_tile(
+                getattr(self, "_step_space", 1 << 30)),
             "tokens": float(counts.sum()) / self.top_k,
             "picks_held": float(held.sum()),
             "picks_all": float(counts.sum()),

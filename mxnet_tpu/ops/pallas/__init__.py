@@ -19,6 +19,9 @@ Kernels (catalog: doc/developer-guide/kernels.md):
                       bitwise parity with the per-leaf optimizer.
   matmul              int8 matmul (per-channel scales, int32 accumulate)
                       for the serving/predict path.
+  moe                 an expert layer's rows to its expert-sorted space and
+                      back, the work following the count of picks held
+                      in the step (a prefetched scalar), not the space.
 
 Infrastructure:
 
@@ -52,6 +55,7 @@ from .matmul import (  # noqa: F401
     int8_predict_scope,
     quantize_channels,
 )
+from .moe import moe_combine, moe_dispatch  # noqa: F401
 from .registry import (  # noqa: F401
     KernelCost,
     attribute_eqn,
@@ -68,6 +72,7 @@ __all__ = [
     "fused_adam_apply", "fused_resolve",
     "int8_matmul", "quantize_channels", "int8_predict_scope",
     "int8_predict_active",
+    "moe_dispatch", "moe_combine",
     "KernelCost", "register_kernel", "kernel_cost", "kernel_names",
     "kernels", "attribute_eqn", "catalog",
     "use_interpret", "resolve_interpret",

@@ -282,6 +282,169 @@ def test_no_pick_is_dropped_when_every_row_picks_the_same_experts():
     assert rel(out, REF.gated_ffn(x, *ws[4:])) < 1e-5
 
 
+# -- the kernels that move rows to the sorted space and back -------------------
+# ``(rows, top_k, width, dtype, held experts, load)``: ``load`` says how many
+# of the rows x top_k picks fall on an expert held here, and on which
+EXPERT_SPACE_CASES = {
+    "drawn": (300, 4, 32, "float32", 8, ("share", 0.5)),
+    "drawn_bf16": (300, 4, 32, "bfloat16", 8, ("share", 0.5)),
+    "an_eighth_bf16": (512, 8, 160, "bfloat16", 4, ("share", 0.125)),
+    "none_held": (300, 4, 32, "float32", 8, ("count", 0)),
+    "none_held_bf16": (64, 4, 32, "bfloat16", 8, ("count", 0)),
+    "all_held": (300, 4, 32, "float32", 8, ("count", 1200)),
+    "all_held_bf16": (300, 4, 32, "bfloat16", 8, ("count", 1200)),
+    "one_expert_takes_all": (300, 4, 32, "float32", 8, ("one", 700)),
+    "on_a_tile_boundary": (300, 4, 32, "float32", 8, ("count", 1024)),
+    "one_off_a_tile_boundary": (300, 4, 32, "bfloat16", 8, ("count", 1025)),
+    "an_odd_count_bf16": (300, 4, 32, "bfloat16", 8, ("count", 77)),
+    "rows_no_multiple_of_anything": (37, 3, 24, "float32", 5, ("share", 0.4)),
+    "rows_no_multiple_of_anything_bf16": (37, 3, 24, "bfloat16", 5,
+                                          ("share", 0.4)),
+    "all_of_an_odd_space": (37, 3, 24, "float32", 5, ("count", 111)),
+    "wider_than_a_chunk": (4200, 2, 1100, "float32", 4, ("share", 0.1)),
+    "clean_behind_the_count": (300, 4, 32, "float32", 8, ("share", 0.5)),
+}
+
+
+def _sorted_space(rng, rows, k, held, load):
+    """``order``, ``slots``, ``sizes``, ``n`` as ``routed`` makes them, from
+    a drawn key a pick: an expert held here, or ``held`` for elsewhere."""
+    kind, amount = load
+    space = rows * k
+    n = int(round(amount * space)) if kind == "share" else amount
+    key = np.full(space, held, np.int32)
+    here = rng.permutation(space)[:n]
+    key[here] = 2 if kind == "one" else rng.randint(0, held, n)
+    order = np.argsort(key, kind="stable").astype(np.int32)
+    slots = np.empty(space, np.int32)
+    slots[order] = np.arange(space, dtype=np.int32)
+    sizes = np.bincount(key, minlength=held + 1)[:held]
+    assert sizes.sum() == n
+    return jnp.asarray(order), jnp.asarray(slots.reshape(rows, k)), n
+
+
+@pytest.mark.parametrize("case", sorted(EXPERT_SPACE_CASES))
+def test_the_expert_kernels_match_the_plain_gathers(case):
+    """Both custom VJPs of ``ops/decoder.py``, forward and gradients,
+    against the gathers over the whole space that they replaced. Behind the
+    count everything the kernels are handed is NaN (``clean_...`` excepted)
+    and everything they leave unwritten is NaN under the interpreter: what
+    comes out must be finite and the reference's, the routing weights'
+    gradient included (what ``train_router`` true asks for; false leaves
+    it unused)."""
+    from mxnet_tpu.ops import decoder as dec
+
+    rows, k, width, dtype, held, load = EXPERT_SPACE_CASES[case]
+    dtype = jnp.dtype(dtype)
+    rng = np.random.RandomState(len(case))
+    order, slots, n = _sorted_space(rng, rows, k, held, load)
+    space = rows * k
+    inside = (jnp.arange(space) < n)[:, None]
+    poison = 0.0 if case.startswith("clean") else jnp.nan
+    exact = dict(rtol=0, atol=0)
+    close = dict(rtol=1e-5, atol=1e-6) if dtype == jnp.float32 \
+        else dict(rtol=2e-2, atol=1e-3)     # one rounding of a float32 sum
+
+    def same(got, want, **tol):
+        got = np.asarray(got, np.float32)
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(got, np.asarray(want, np.float32), **tol)
+
+    def draw(*shape):
+        return jnp.asarray(rng.randn(*shape), dtype)
+
+    # rows -> sorted: a gather, bit for bit; its gradient a sum over a
+    # row's held picks
+    x, g = draw(rows, width), jnp.where(inside, draw(space, width), poison)
+    out, vjp = jax.vjp(lambda x: dec._rows_to_sorted(x, order, slots, n), x)
+    same(out[:n], x[order // k][:n], **exact)
+    tile = min(1024, space + (-space % 16 if dtype.itemsize == 2 else 0))
+    same(out[n:-(-max(n, 1) // tile) * tile], 0, **exact)
+    same(vjp(g.astype(dtype))[0],
+         jnp.where(inside, g, 0)[slots].astype(jnp.float32).sum(axis=1)
+         .astype(dtype), **close)
+
+    # sorted -> rows: the weighted sum in float32; its gradients the
+    # scaled gather and a dot product a pick
+    y = jnp.where(inside, draw(space, width), poison).astype(dtype)
+    weight = jnp.where(slots < n, jnp.asarray(rng.rand(rows, k), jnp.float32),
+                       0.0)
+
+    def plain_sum(y, weight):
+        return jnp.einsum("rk,rkw->rw", weight,
+                          jnp.where(inside, y, 0)[slots].astype(jnp.float32)
+                          ).astype(dtype)
+
+    out, vjp = jax.vjp(
+        lambda y, w: dec._sorted_to_rows(y, w, order, slots, n), y, weight)
+    plain, plain_vjp = jax.vjp(plain_sum, y, weight)
+    same(out, plain, **close)
+    g = draw(rows, width)
+    (dy, dweight), (plain_dy, plain_dweight) = vjp(g), plain_vjp(g)
+    same(dy[:n], plain_dy[:n], **close)
+    same(dweight, jnp.where(slots < n, plain_dweight, 0),
+         **dict(close, atol=1e-4 * width))
+
+
+def test_the_expert_operator_holds_no_control_flow():
+    """No ``cond``, ``while`` or ``scan`` in the operator's forward or
+    gradient outside a kernel's body: the benchmark sums device time by
+    instruction and counts an instruction inside a conditional or a loop
+    twice (PERF.md section 7), so the work follows the step's count inside
+    the kernels only."""
+    rng = np.random.RandomState(6)
+    ws = _moe_weights(rng)
+    x = jnp.asarray(rng.randn(40, 32), jnp.float32)
+    load0 = jnp.zeros((16,), jnp.float32)
+
+    def found(jaxpr, names):
+        for eqn in jaxpr.eqns:
+            names.add(eqn.primitive.name)
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                found(sub, names)
+        return names
+
+    for train_router in (True, False):
+        op = OPS.create("MixtureOfExperts", num_experts=16, experts_held=8,
+                        first_expert=4, top_k=4, expert_width=24,
+                        scaling=2.5, shared_width=24,
+                        train_router=train_router)
+        held = [ws[0]] + [w[4:12] for w in ws[1:4]] + ws[4:]
+
+        def out(x, *ws):
+            return jnp.sum(op.fwd([x, *ws], [load0], True, None)[0][0])
+
+        for fn in (out, jax.grad(out, tuple(range(8)))):
+            names = found(jax.make_jaxpr(fn)(x, *held).jaxpr, set())
+            assert "pallas_call" in names and "ragged_dot_general" in names
+            assert not names & {"cond", "while", "scan"}, names
+
+
+def test_a_traced_expert_kernel_leaves_its_space_record():
+    """One zero-length ``moe.space`` record a traced call of each kernel:
+    the step's rows and ``top_k``, the sorted entries a tile and the most
+    tiles a call can run (the step's count decides how many it does)."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops.pallas import moe
+
+    rows, k, width = 700, 4, 32
+    token = jnp.zeros((rows * k,), jnp.int32)
+    mark = len(telemetry.span_records())
+    jax.eval_shape(lambda x, n: moe.moe_dispatch(x, token, n),
+                   jnp.zeros((rows, width)), jnp.int32(0))
+    jax.eval_shape(lambda y, n: moe.moe_combine(y, token, n, rows),
+                   jnp.zeros((rows * k, width)), jnp.int32(0))
+    records = [r for r in telemetry.span_records()[mark:]
+               if r["name"] == "moe.space"]
+    assert [r["attrs"] for r in records] == [
+        {"kernel": kernel, "rows": rows, "top_k": k,
+         "tile": moe.space_tile(rows * k), "tiles_max": 3}
+        for kernel in ("moe_dispatch", "moe_combine")]
+    assert moe.space_tile(rows * k) == 1024 and moe.space_tile(40) == 40
+
+
 # -- the flash kernel: grouped heads, window, skipped blocks -------------------
 
 def _dense_attention(q, k, v, window, causal=True):
@@ -815,6 +978,9 @@ def test_fit_emits_one_expert_load_record_a_node_and_epoch():
         a = r["attrs"]
         assert a["tokens"] == tokens and a["picks_all"] == 4 * tokens
         assert a["experts_held"] == 8
+        # the sorted space of the epoch's steps, and the kernels' tile: a
+        # step's whole space here, so one tile a step whatever the load
+        assert a["space"] == 4 * tokens and a["tile"] == 4 * batch * T
         assert 0 < a["picks_held"] < a["picks_all"]
         assert a["picks_held"] / 8 <= a["max_held"] <= a["picks_held"]
         assert a["picks_held"] / a["max_held"] <= a["experts_hit"] <= 8
@@ -886,3 +1052,4 @@ def test_the_load_count_is_exact_across_its_wrap(start):
     assert attrs["max_held"] == counts[2:6].max()
     assert attrs["experts_hit"] == np.count_nonzero(counts[2:6])
     assert attrs["experts_held"] == 4
+    assert attrs["space"] == 3 * 6 * 2 and attrs["tile"] == 6 * 2

@@ -107,6 +107,27 @@ def _padded_flash_case(causal, window):
     return build
 
 
+def _expert_space_case(S):
+    """The expert layer's kernels at the widths of ``laguna_xs2.seq8k``:
+    8,192 rows, top-8, hidden 2,048, bfloat16; each kernel both ways it
+    is used (the gather with and without a scale, the sum with and
+    without weights)."""
+    from mxnet_tpu.ops.pallas.moe import moe_combine, moe_dispatch
+
+    rows, k, width = 8192, 8, 2048
+
+    def both(x, y, token, n, scale):
+        return (moe_dispatch(x, token, n, interpret=False),
+                moe_dispatch(x, token, n, scale, interpret=False),
+                moe_combine(y, token, n, rows, interpret=False),
+                moe_combine(y, token, n, rows, scale, interpret=False))
+
+    return both, (S((rows, width), jnp.bfloat16),
+                  S((rows * k, width), jnp.bfloat16),
+                  S((rows * k,), jnp.int32), S((), jnp.int32),
+                  S((rows * k,)))
+
+
 def _quant_case(mode):
     def build(S):
         spec = comm.CompressionSpec(mode)
@@ -144,6 +165,7 @@ CASES = {
                             {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     "flash_padded_unmasked": (_padded_flash_case(False, None),
                               {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    "expert_space": (_expert_space_case, {"moe_dispatch", "moe_combine"}),
     "fused_adam": (_adam_case, {"fused_adam"}),
     "quant_int8": (_quant_case("int8"), {"quant_int8"}),
     "quant_twobit": (_quant_case("twobit"), {"quant_twobit"}),
