@@ -13,7 +13,12 @@ never by editing the runner.
                  a (data, label) batch as it is handed over,
                  ``steps_per_epoch``, ``batch_rows``, ``check_rows(n)``)
   metric         ``end_to_end/<name>.py`` or ``layer_metrics/<name>.py``
-                 (``METRIC`` and ``read(run)``)
+                 (``METRIC`` and ``read(run)``). The cells a metric is
+                 read in, its ``workloads`` list, live in
+                 ``BENCHMARK.json`` only: a reader's ``METRIC`` never has
+                 the key, so a later PR appends its cell to the list there
+                 and the reader takes that cell's sizes from
+                 ``run["config"]``
   operator       ``walkers/<Operator>.py`` (``layers(node, in_shapes,
                  out_shapes)``): what a node of that operator adds to the
                  FLOP recipe, found by ``walk.py``

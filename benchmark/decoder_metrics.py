@@ -2,9 +2,19 @@
 attention, an expert layer): device time under an operator's scope
 (``scopes.ms_per_step``), the kernels' shares of their rooflines
 (``kernel_costs.py``) and the experts' load from the program's
-``fit.epoch.expert_load`` records. A metric file names its configuration and
-calls one of these; each returns ``None`` where there is nothing to read (no
-trace, no HLO text, a program without the records), never 0.
+``fit.epoch.expert_load`` records. A metric file calls one of these with
+the run alone; the sizes are the cell's own, ``run["config"]`` (the
+configuration's file as ``catalog.find_cell`` read it), so one metric name
+serves every decoder cell that ``BENCHMARK.json`` lists under it. Each
+returns ``None`` where there is nothing to read (no trace, no HLO text, a
+program without the records, a configuration that is no decoder's), never 0.
+
+Keys read from a configuration, as a published ``config.json`` writes them:
+``num_hidden_layers``; ``layer_types`` (absent: every layer is
+``full_attention``); ``num_attention_heads_per_layer[l]`` where present, else
+``num_attention_heads``; ``num_key_value_heads``; ``head_dim``;
+``sliding_window`` (null or absent: 0); ``hidden_size``;
+``moe_intermediate_size``; and the harness's ``input_shape``.
 
 Scopes (the train program's HLO, read on the chip): the executor emits the
 attention operator under ``layer<l>_attn/RotaryAttention`` (forward
@@ -17,7 +27,6 @@ whose instructions (``ragged-dot-none.<n>``) carry the ``op_name``
 ``ragged-dot-none`` and no scope, so they are found by that name.
 """
 
-import json
 import os
 import runpy
 
@@ -28,48 +37,52 @@ COSTS = runpy.run_path(os.path.join(HERE, "kernel_costs.py"))
 
 GROUPED = r"^ragged-dot"
 FLASH = r"\)?/flash_(?:fwd|bwd_dq|bwd_dkv)/pallas_call"   # jvp(<scope>)/...
-
-
-def config(name):
-    with open(os.path.join(HERE, "configs", name + ".json"),
-              encoding="utf-8") as f:
-        return json.load(f)
+FULL, SLIDING = "full_attention", "sliding_attention"
 
 
 def attention_layers(cfg, kind):
-    return [l for l in range(cfg["num_hidden_layers"])
-            if cfg["layer_types"][l] == kind]
+    """The layers of ``kind`` (``FULL`` / ``SLIDING``); none where the
+    configuration has no decoder layers."""
+    depth = cfg.get("num_hidden_layers", 0)
+    types = cfg.get("layer_types") or [FULL] * depth
+    return [l for l in range(depth) if types[l] == kind]
 
 
-def attention_scope(cfg, kind):
-    layers = "|".join(str(l) for l in attention_layers(cfg, kind))
-    return rf"layer(?:{layers})_attn/RotaryAttention"
+def attention_scope(layers):
+    return rf"layer(?:{'|'.join(map(str, layers))})_attn/RotaryAttention"
 
 
-def attention_ms(run, name, kind):
+def attention_ms(run, kind):
     """Device ms a step under the attention operator's scope over the
     layers of ``kind``: rotary positions, the kernels forward and backward
     (the recomputed forward included), the gate."""
-    return SCOPES["ms_per_step"](run, attention_scope(config(name), kind))
+    layers = attention_layers(run["config"], kind)
+    if not layers:
+        return None
+    return SCOPES["ms_per_step"](run, attention_scope(layers))
 
 
-def attention_roofline_pct(run, name, kind):
+def attention_roofline_pct(run, kind):
     """The least time the chip could take for the attention kernels of the
     layers of ``kind``, forward and backward once each, over the time
     their kernels took in a step. The forward kernel runs twice a step
     (each decoder layer is recomputed in the backward pass): the second
     run counts in the time and not in the work."""
-    cfg = config(name)
-    took = SCOPES["ms_per_step"](run, attention_scope(cfg, kind) + FLASH)
-    if not took or not run.get("peak"):
+    cfg = run["config"]
+    layers = attention_layers(cfg, kind)
+    if not layers or not run.get("peak"):
         return None
+    took = SCOPES["ms_per_step"](run, attention_scope(layers) + FLASH)
+    if not took:
+        return None
+    heads = cfg.get("num_attention_heads_per_layer") \
+        or [cfg["num_attention_heads"]] * cfg["num_hidden_layers"]
+    window = (cfg.get("sliding_window") or 0) if kind == SLIDING else 0
     least = 0.0
-    for l in attention_layers(cfg, kind):
+    for l in layers:
         for cost in COSTS["flash_attention"](
-                cfg["num_attention_heads_per_layer"][l],
-                cfg["num_key_value_heads"], cfg["input_shape"][0],
-                cfg["head_dim"], cfg["sliding_window"]
-                if kind == "sliding_attention" else 0):
+                heads[l], cfg["num_key_value_heads"], cfg["input_shape"][0],
+                cfg["head_dim"], cfg["head_dim"], window):
             least += COSTS["roofline_seconds"](cost, run["peak"])
     return 100.0 * 1e3 * least * run["per_chip_batch"] / took
 
@@ -116,16 +129,17 @@ def moe_picks_held_per_token(run):
         / sum(a["tokens"] for a in loads)
 
 
-def moe_grouped_roofline_pct(run, name):
+def moe_grouped_roofline_pct(run):
     """The least time for the grouped products of every expert layer,
     forward and backward once each, at the rows a step of the traced
     epochs really routed here, over the time the grouped products took in
     a step (the recomputed forward's included in the time only)."""
     took = SCOPES["ms_per_step"](run, GROUPED)
     loads = expert_load(run)
-    if not took or not loads or not run.get("peak"):
+    cfg = run["config"]
+    if not took or not loads or not run.get("peak") \
+            or "moe_intermediate_size" not in cfg:
         return None
-    cfg = config(name)
     least = 0.0
     for a in loads:          # one entry a node and traced epoch
         if not a["picks_held"]:

@@ -16,18 +16,24 @@ def kv_mean(q_len, window):
     return window - window * (window - 1) / (2 * q_len)
 
 
-def flash_attention(heads, kv_heads, q_len, head_dim, window, itemsize=2):
+def flash_attention(heads, kv_heads, q_len, qk_dim, v_dim, window,
+                    itemsize=2):
     """``(forward, backward)``, each ``{"flops", "bytes"}``, of one causal
-    grouped-query attention over one sequence. Forward reads q, k, v and
-    writes o and the row statistics; backward reads q, k, v, o, do and the
-    statistics and writes dq, dk, dv."""
-    q = heads * q_len * head_dim * itemsize
-    kv = kv_heads * q_len * head_dim * itemsize
+    grouped-query attention over one sequence whose queries and keys are
+    ``qk_dim`` wide and whose values and outputs ``v_dim`` (``flops.py``'s
+    ``attention`` layer: the two sizes differ under latent attention).
+    Forward reads q, k, v and writes o and the row statistics; backward
+    reads q, k, v, o, do and the statistics and writes dq, dk, dv."""
+    q = heads * q_len * qk_dim * itemsize
+    o = heads * q_len * v_dim * itemsize
+    k = kv_heads * q_len * qk_dim * itemsize
+    v = kv_heads * q_len * v_dim * itemsize
     stats = heads * q_len * 4
-    forward_flops = 2 * heads * q_len * kv_mean(q_len, window) * 2 * head_dim
-    return ({"flops": forward_flops, "bytes": 2 * q + 2 * kv + stats},
+    forward_flops = 2 * (heads * q_len * kv_mean(q_len, window)
+                         * (qk_dim + v_dim))
+    return ({"flops": forward_flops, "bytes": q + k + v + o + stats},
             {"flops": 2 * forward_flops,
-             "bytes": 4 * q + 4 * kv + 2 * stats})
+             "bytes": 2 * (q + k + v + o) + 2 * stats})
 
 
 def grouped_product(rows, cin, cout, experts, itemsize=2, out_itemsize=4):

@@ -1,4 +1,6 @@
-"""The flash kernels of the full-attention layers against their roofline:
+"""The flash kernels of the full-attention layers of the cell's own
+configuration (``run["config"]``: its heads, widths, length and window)
+against their roofline:
 ``max(FLOP / peak, bytes / bandwidth)`` of forward and backward once each
 (``kernel_costs.py``: the causal half of the products only) over the time
 the kernels took in a step, the recomputed forward in the time alone.
@@ -17,9 +19,8 @@ METRIC = {
     "source": "device_trace",
     "layer": "graph to XLA (symbol.py, executor.py, ops/)",
     "moves": "samples_per_s_per_chip",
-    "workloads": ["laguna_xs2.seq8k"],
 }
 
 
 def read(run):
-    return DECODER["attention_roofline_pct"](run, "laguna_xs2", "full_attention")
+    return DECODER["attention_roofline_pct"](run, DECODER["FULL"])

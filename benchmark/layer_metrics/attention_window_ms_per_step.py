@@ -1,6 +1,7 @@
 """Device milliseconds a step under the attention operator's scope over the
-configuration's sliding-window layers: rotary positions, the flash kernels
-forward and backward (the recomputed forward included) and the head gate.
+sliding-window layers of the cell's own configuration (``run["config"]``):
+rotary positions, the flash kernels forward and backward (the recomputed
+forward included) and the head gate where it has one.
 """
 
 import os
@@ -16,9 +17,8 @@ METRIC = {
     "source": "device_trace",
     "layer": "graph to XLA (symbol.py, executor.py, ops/)",
     "moves": "samples_per_s_per_chip",
-    "workloads": ["laguna_xs2.seq8k"],
 }
 
 
 def read(run):
-    return DECODER["attention_ms"](run, "laguna_xs2", "sliding_attention")
+    return DECODER["attention_ms"](run, DECODER["SLIDING"])

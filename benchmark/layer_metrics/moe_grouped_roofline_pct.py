@@ -1,7 +1,8 @@
 """The expert layers' grouped products against their roofline at the rows a
 step of the traced epochs really routed here (the program's
-``fit.epoch.expert_load`` records), forward and backward once each, over
-the time the grouped products took in a step.
+``fit.epoch.expert_load`` records) and the widths of the cell's own
+configuration (``run["config"]``), forward and backward once each, over the
+time the grouped products took in a step.
 """
 
 import os
@@ -17,9 +18,8 @@ METRIC = {
     "source": "device_trace",
     "layer": "graph to XLA (symbol.py, executor.py, ops/)",
     "moves": "samples_per_s_per_chip",
-    "workloads": ["laguna_xs2.seq8k"],
 }
 
 
 def read(run):
-    return DECODER["moe_grouped_roofline_pct"](run, "laguna_xs2")
+    return DECODER["moe_grouped_roofline_pct"](run)

@@ -17,7 +17,6 @@ METRIC = {
     "source": "device_trace",
     "layer": "graph to XLA (symbol.py, executor.py, ops/)",
     "moves": "samples_per_s_per_chip",
-    "workloads": ["laguna_xs2.seq8k"],
 }
 
 

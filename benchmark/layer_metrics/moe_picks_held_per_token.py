@@ -18,7 +18,6 @@ METRIC = {
     "source": "program_span",
     "layer": "graph to XLA (symbol.py, executor.py, ops/)",
     "moves": "samples_per_s_per_chip",
-    "workloads": ["laguna_xs2.seq8k"],
 }
 
 

@@ -305,7 +305,7 @@ def main(argv=None):
         "rows": rows, "epoch_seconds": seconds, "epoch_rates": rates,
         "window_seconds": window_s,
         "samples_per_epoch": samples_per_epoch, "chips": chips,
-        "per_chip_batch": int(config["per_chip_batch"]),
+        "config": config, "per_chip_batch": int(config["per_chip_batch"]),
         "steps_per_epoch": feed.steps_per_epoch,
         "setup_s": setup_s, "peak_bytes": peak_bytes,
         "precompile_s": precompile_s, "plan_bytes": plan_bytes,

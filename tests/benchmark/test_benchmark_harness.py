@@ -13,6 +13,7 @@ import re
 import shutil
 import subprocess
 import sys
+import types
 
 import pytest
 
@@ -67,7 +68,20 @@ def bench():
 
 # -- BENCHMARK.json against its contract and against the files ---------------
 
-def test_benchmark_json_keys_names_and_units(bench):
+@pytest.fixture(scope="module", params=["repository", "later_pr"])
+def tree(request, bench):
+    """A checkout whose ``BENCHMARK.json`` and files the contract tests
+    read: the repository's own, and the tree a later PR makes of it by
+    adding files and entries (``overlay``, below)."""
+    if request.param == "repository":
+        return types.SimpleNamespace(root=ROOT, bench=bench, later=False)
+    root = str(request.getfixturevalue("overlay"))
+    return types.SimpleNamespace(root=root, later=True,
+                                 bench=catalog.load_benchmark(root))
+
+
+def test_benchmark_json_keys_names_and_units(tree):
+    bench = tree.bench
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
     assert 1 <= bench["run_seconds"] <= 51
@@ -99,25 +113,31 @@ def test_benchmark_json_keys_names_and_units(bench):
     assert len(pairs) == len(set(pairs))
     four = [w for w in bench["workloads"] if w["chips"] == 4]
     assert all(w["chips"] in (1, 4) for w in bench["workloads"])
-    assert len(four) <= max(1, len(bench["workloads"]) // 4)
+    if not tree.later:      # the later tree rehearses the four-chip path
+        assert len(four) <= max(1, len(bench["workloads"]) // 4)   # twice
     for folder in bench["paths"]:
-        for base, _, files in os.walk(os.path.join(ROOT, folder)):
+        for base, _, files in os.walk(os.path.join(tree.root, folder)):
             if "__pycache__" in base:
                 continue
             for f in files:
-                rel = os.path.relpath(os.path.join(base, f), ROOT)
+                rel = os.path.relpath(os.path.join(base, f), tree.root)
                 assert re.fullmatch(r"[A-Za-z0-9_.\-/]+", rel), rel
 
 
-def test_every_entry_has_its_file_and_they_agree(bench):
+def test_every_entry_has_its_file_and_they_agree(tree):
+    bench, here = tree.bench, os.path.join(tree.root, "benchmark")
     e2e = {m["name"] for m in bench["end_to_end"]}
     cells = {w["name"] for w in bench["workloads"]}
     for m in bench["end_to_end"]:
-        meta = catalog.load_metric("end_to_end", m["name"]).METRIC
+        meta = catalog.load_metric("end_to_end", m["name"], here=here).METRIC
         assert {k: m[k] for k in meta} == meta
     for m in bench["per_layer"]:
-        meta = catalog.load_metric("layer_metrics", m["name"]).METRIC
+        meta = catalog.load_metric("layer_metrics", m["name"],
+                                   here=here).METRIC
         assert {k: m[k] for k in meta} == meta
+        # the cells a metric is read in are the entry's alone: a later PR
+        # appends its cell there without touching the reader's file
+        assert "workloads" not in meta, m["name"]
         assert set(m) - {"workloads"} == {"name", "unit", "better", "source",
                                           "layer", "moves"}
         assert m["moves"] in e2e
@@ -127,7 +147,7 @@ def test_every_entry_has_its_file_and_they_agree(bench):
     used = {w["config"] for w in bench["workloads"]}
     assert used == {c["name"] for c in bench["configs"]}
     for w in bench["workloads"]:
-        found = catalog.find_cell(bench, w["name"])
+        found = catalog.find_cell(bench, w["name"], root=tree.root, here=here)
         config = found["config"]
         assert found["config_entry"]["file"].startswith(
             tuple(p + "/" for p in bench["paths"]))
@@ -136,12 +156,12 @@ def test_every_entry_has_its_file_and_they_agree(bench):
         assert os.path.isfile(os.path.join(
             os.path.dirname(found["config_path"]), config["reference"]))
         assert os.path.isfile(os.path.join(
-            BENCH, "feeds", found["traffic"]["kind"] + ".py"))
+            here, "feeds", found["traffic"]["kind"] + ".py"))
     with pytest.raises(catalog.BenchmarkError):
-        catalog.find_cell(bench, "no.such.cell")
+        catalog.find_cell(bench, "no.such.cell", root=tree.root, here=here)
     with pytest.raises(catalog.BenchmarkError):
-        catalog.peak_for("cpu")
-    assert catalog.peak_for("TPU v5 lite")["bf16_flops"] == 197e12
+        catalog.peak_for("cpu", here=here)
+    assert catalog.peak_for("TPU v5 lite", here=here)["bf16_flops"] == 197e12
 
 
 # -- the rate: all the window's samples over all its seconds -------------------
@@ -766,6 +786,77 @@ def logits(params, aux, ids):
 TOKEN_RING = {"kind": "token_ring", "ring": 3, "steps_per_epoch": 4,
               "warmup_steps": 3, "follow_p": 0.5}
 
+# what the next ``model_config`` PR brings, played here: a SECOND decoder
+# configuration beside the one the benchmark has, of added files and
+# entries alone. Another pattern than that one's cut (sliding, full,
+# sliding, full; every layer sparse), no head gate, no shared expert,
+# widths that are no power of two, and its keys as another family's
+# ``config.json`` writes them: one ``num_attention_heads``, no list a layer
+FULL, SLIDING = "full_attention", "sliding_attention"
+DECODER_SIZES = {
+    "hidden_size": 48, "head_dim": 16, "num_key_value_heads": 2,
+    "layer_types": [SLIDING, FULL, SLIDING, FULL],
+    "mlp_layer_types": ["sparse"] * 4, "sliding_window": 4,
+    "num_experts_per_tok": 2, "moe_intermediate_size": 24,
+    "shared_expert_intermediate_size": 0, "moe_routed_scaling_factor": 1.0,
+    "rms_norm_eps": 1e-6, "gating": False,
+    "rope_parameters": {
+        FULL: {"rope_type": "default", "rope_theta": 10000,
+               "partial_rotary_factor": 0.5},
+        SLIDING: {"rope_type": "default", "rope_theta": 100,
+                  "partial_rotary_factor": 1}},
+}
+DECODER_CONFIG = dict(
+    DECODER_SIZES,
+    name="tiny_decoder",
+    source="test preset: a second decoder, sliding/full by turns, top-2 "
+           "of 8 experts (4 held), no gate, no shared expert",
+    sample="one sequence of 32 token ids",
+    builder={"import": "mxnet_tpu.models:laguna", "kwargs": dict(
+        DECODER_SIZES, seq_len=32, layers=4, vocab_rows=80, num_experts=8,
+        experts_held=4, first_expert=0, train_router=False,
+        num_attention_heads_per_layer=[6] * 4)},
+    input_shape=[32], vocab_rows=80, vocab_size=80, per_chip_batch=2,
+    num_hidden_layers=4, num_attention_heads=6, num_experts=4,
+    first_expert=0, train_router=False,
+    compute_dtype=None, optimizer={"name": "adam", "learning_rate": 0.002},
+    initializer={"name": "Xavier"}, logits="head_output",
+    reference="tiny_decoder.py", reference_rows=2, reference_tolerance=1e-4,
+    reduced=[])
+DECODER_METRICS = [
+    "attention_window_ms_per_step", "attention_full_ms_per_step",
+    "attention_window_roofline_pct", "attention_full_roofline_pct",
+    "moe_ms_per_step", "moe_grouped_roofline_pct", "moe_load_max_over_mean",
+    "moe_picks_held_per_token"]
+# its reference: the decoder's plain reference that is there, reading this
+# configuration's file, one head count and no shared expert
+DECODER_REFERENCE_EDITS = [
+    ('"laguna_xs2.json"', '"tiny_decoder.json"'),
+    ('cfg["num_attention_heads_per_layer"][l]', 'cfg["num_attention_heads"]'),
+    ('out = gated_ffn(x, p[prefix + "shared_gate_weight"],\n'
+     '                    p[prefix + "shared_up_weight"],\n'
+     '                    p[prefix + "shared_down_weight"])',
+     "out = jnp.zeros_like(x)")]
+HIT_METRIC = '''
+import os
+import runpy
+
+DECODER = runpy.run_path(os.path.join(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__))), "decoder_metrics.py"))
+
+METRIC = {"name": "moe_experts_hit_pct", "unit": "%", "better": "higher",
+          "source": "program_span",
+          "layer": "graph to XLA (symbol.py, executor.py, ops/)",
+          "moves": "samples_per_s_per_chip"}
+
+
+def read(run):
+    loads = DECODER["expert_load"](run)
+    if not loads:
+        return None
+    return min(100.0 * a["experts_hit"] / a["experts_held"] for a in loads)
+'''
+
 
 @pytest.fixture(scope="module")
 def overlay(tmp_path_factory, bench):
@@ -795,6 +886,19 @@ def overlay(tmp_path_factory, bench):
         json.dumps(TOKEN_RING))
     (here / "traffic" / "tiny_token_ring_dp.json").write_text(
         json.dumps(dict(TOKEN_RING, ring=2)))
+    decoder = dict(DECODER_CONFIG)
+    decoder["flops_per_sample"] = {"layers": walk.layers_of(
+        catalog.build_symbol(decoder["builder"], str(here / "configs")),
+        decoder)}
+    (here / "configs" / "tiny_decoder.json").write_text(json.dumps(decoder))
+    reference = (here / "configs" / "laguna_xs2.py").read_text()
+    for was, becomes in DECODER_REFERENCE_EDITS:
+        assert reference.count(was) == 1, was
+        reference = reference.replace(was, becomes)
+    (here / "configs" / "tiny_decoder.py").write_text(reference)
+    (here / "traffic" / "tiny_decoder_ring.json").write_text(
+        json.dumps(TOKEN_RING))
+    (here / "layer_metrics" / "moe_experts_hit_pct.py").write_text(HIT_METRIC)
     added = dict(bench)
     added["configs"] = bench["configs"] + [
         {"name": "tiny_resnet", "source": TINY_CONFIG["source"],
@@ -802,6 +906,9 @@ def overlay(tmp_path_factory, bench):
          "why": "test preset"},
         {"name": "tiny_tokens", "source": TOKENS_CONFIG["source"],
          "file": "benchmark/configs/tiny_tokens.json", "reduced": [],
+         "why": "test preset"},
+        {"name": "tiny_decoder", "source": DECODER_CONFIG["source"],
+         "file": "benchmark/configs/tiny_decoder.json", "reduced": [],
          "why": "test preset"}]
     added["workloads"] = bench["workloads"] + [
         {"name": "tiny.device", "config": "tiny_resnet",
@@ -811,14 +918,21 @@ def overlay(tmp_path_factory, bench):
         {"name": "tiny_tokens.device", "config": "tiny_tokens",
          "traffic": "tiny_token_ring", "chips": 1, "why": "test"},
         {"name": "tiny_tokens.dp4", "config": "tiny_tokens",
-         "traffic": "tiny_token_ring_dp", "chips": 4, "why": "test"}]
+         "traffic": "tiny_token_ring_dp", "chips": 4, "why": "test"},
+        {"name": "tiny_decoder.device", "config": "tiny_decoder",
+         "traffic": "tiny_decoder_ring", "chips": 1, "why": "test"}]
+    # a cell's name appended to the lists that are there (the decoder
+    # metrics' too: a cell that is no decoder's reads nothing there), the
+    # new decoder cell's to the eight decoder lists, new metrics at the end
     added["per_layer"] = [
-        dict(m, workloads=m["workloads"] + ["tiny.dp4", "tiny_tokens.dp4"])
+        dict(m, workloads=m["workloads"] + ["tiny.dp4", "tiny_tokens.dp4"]
+             + ["tiny_decoder.device"] * (m["name"] in DECODER_METRICS))
         if "workloads" in m else m for m in bench["per_layer"]] + [
         dict(catalog.load_file_module(
-            str(here / "layer_metrics" / "steps_in_window.py"),
-            "steps_in_window").METRIC,
-             workloads=["tiny.device"])]
+            str(here / "layer_metrics" / (name + ".py")), name).METRIC,
+             workloads=[cell])
+        for name, cell in (("steps_in_window", "tiny.device"),
+                           ("moe_experts_hit_pct", "tiny_decoder.device"))]
     (root / "BENCHMARK.json").write_text(json.dumps(added))
     yield root
     for p, content in before.items():     # nothing that was there changed
@@ -957,6 +1071,52 @@ def test_runner_on_a_token_configuration_made_of_added_files(overlay, cell,
         assert checks["hlo_text_not_read"] is None
 
 
+# -- a second decoder by files and entries alone ---------------------------------
+
+@pytest.mark.parametrize("trace", [0, 1])
+def test_runner_on_a_second_decoder_made_of_added_files(overlay, bench,
+                                                        trace):
+    """What the next ``model_config`` PR does: a decoder configuration
+    beside the one that is there, its reference, a cell on a ``token_ring``
+    mix, the cell's name appended to the eight decoder metrics' lists and
+    one metric of its own. No file that was there is edited (the fixture
+    checks every byte), and the cell runs to ``correct``."""
+    later = catalog.load_benchmark(str(overlay))
+    listed = {m["name"] for m in catalog.metrics_for(
+        later, "per_layer", "tiny_decoder.device")}
+    assert set(DECODER_METRICS) | {"moe_experts_hit_pct"} <= listed
+    assert "steps_in_window" not in listed
+    # a cell that was there reports what it reported, under the same names
+    for w in bench["workloads"]:
+        assert [m["name"] for m in catalog.metrics_for(
+            later, "per_layer", w["name"])] == [m["name"] for m in
+            catalog.metrics_for(bench, "per_layer", w["name"])], w["name"]
+    proc = _run(overlay, "--workload", "tiny_decoder.device", "--seed",
+                str(2 ** 31 + 31), "--seconds", "0.5", "--trace", str(trace),
+                "--rehearse-on-cpu", devices=1)
+    result, earlier = _result(proc)
+    assert result["correct"] is True, (earlier, proc.stderr[-2000:])
+    assert result["failed"] == 0 and result["attempted"] >= 4
+    compared = result["compared"]
+    assert compared["reference_relative_error"]["value"] < 1e-4   # float32
+    assert compared["last_loss_over_warmup_loss"]["value"] < 1.0
+    assert compared["train_programs"] == {"value": 1, "limit": 1}
+    got = set(result["metrics"])
+    if trace == 0:
+        assert got == {"samples_per_s_per_chip", "setup_s"}
+        return
+    # the program's records are read on the CPU as on the chip; a CPU trace
+    # has no device plane, so the device-trace readers read nothing
+    assert {"moe_load_max_over_mean", "moe_picks_held_per_token",
+            "moe_experts_hit_pct", "write_back_ms"} <= got
+    assert not got & {"attention_window_ms_per_step", "moe_ms_per_step",
+                      "attention_full_roofline_pct",
+                      "moe_grouped_roofline_pct", "steps_in_window"}
+    picks = result["metrics"]["moe_picks_held_per_token"]["value"]
+    assert 0.2 < picks < 1.8          # 2 picks x 4 of 8 held: 1 expected
+    assert 0 < result["metrics"]["moe_experts_hit_pct"]["value"] <= 100
+
+
 TOKENS_SCRIPT = '''
 import os, sys
 import numpy as np
@@ -1056,3 +1216,13 @@ def test_no_line_of_the_harness_names_the_token_preset():
         with open(os.path.join(BENCH, name), encoding="utf-8") as f:
             text = f.read()
         assert "tiny_tokens" not in text and "tiny_resnet" not in text, name
+    # nor a configuration of the benchmark: only ``configs/`` and the tests
+    # name one, so a reader serves whichever cell lists it
+    names = [c["name"] for c in catalog.load_benchmark(ROOT)["configs"]]
+    for folder in ("", "layer_metrics", "end_to_end", "feeds", "walkers"):
+        for name in sorted(os.listdir(os.path.join(BENCH, folder))):
+            if name.endswith(".py"):
+                with open(os.path.join(BENCH, folder, name),
+                          encoding="utf-8") as f:
+                    text = f.read()
+                assert not [n for n in names + ["laguna"] if n in text], name

@@ -15,8 +15,8 @@ import sys
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
 BENCH = os.path.join(ROOT, "benchmark")
 sys.path.insert(0, BENCH)
 
@@ -50,11 +50,22 @@ PUBLISHED = {
     "num_attention_heads_per_layer": [48, 64, 64, 64] * 10,
 }
 CUT = {"num_hidden_layers": 5, "num_experts": 32, "vocab_size": 12544}
-NEW_METRICS = [
-    "attention_window_ms_per_step", "attention_full_ms_per_step",
-    "attention_window_roofline_pct", "attention_full_roofline_pct",
-    "moe_ms_per_step", "moe_grouped_roofline_pct", "moe_load_max_over_mean",
-    "moe_picks_held_per_token"]
+# what the benchmark held when this cell was accepted (PR 27), by name: a
+# later PR appends cells, metrics and names to the lists and edits none
+ACCEPTED_CELLS = {
+    "resnet50.device": ("resnet50", "device_ring", 1),
+    "inception_bn.device": ("inception_bn", "device_ring", 1),
+    "resnet50.dp4": ("resnet50", "device_ring_dp", 4),
+    "laguna_xs2.seq8k": ("laguna_xs2", "token_ring_8k", 1)}
+METRICS_BEFORE = [
+    "epoch_tail_ms", "epoch_rate_median", "epoch_rate_min_over_median",
+    "step_gap_ms_p50", "precompile_s", "compiles_in_window",
+    "device_step_ms", "mfu_device", "collective_ms_per_step",
+    "collective_exposed_ms_per_step", "plan_mb", "device_idle_pct",
+    "write_back_ms", "epoch_tail_host_ms", "epoch_tail_unnamed_ms",
+    "host_step_ms_p10", "feed_wait_ms_per_step", "init_params_s",
+    "fit_start_s", "forward_ms_per_step", "backward_ms_per_step",
+    "optimizer_unfused_ms_per_step", "unscoped_ms_per_step"]
 
 
 @pytest.fixture(scope="module")
@@ -73,6 +84,15 @@ def _load(path, name):
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+# the harness's own test file, for the tree a later PR makes of this one by
+# additions (``overlay``) and the two trees the contract is held on (``tree``)
+harness = _load(os.path.join(HERE, "test_benchmark_harness.py"),
+                "bench_harness_tests_of_laguna")
+overlay, tree = harness.overlay, harness.tree
+NEW_METRICS = harness.DECODER_METRICS       # the eight this cell brought
+ACCEPTED_METRICS = METRICS_BEFORE + NEW_METRICS
 
 
 def test_the_file_holds_the_published_values(config):
@@ -125,31 +145,42 @@ def test_the_builder_defaults_are_the_published_sizes():
         assert ROPE_PARAMETERS[kind] == PUBLISHED["rope_parameters"][kind]
 
 
-def test_entries_of_the_benchmark(bench, config):
-    entry = [c for c in bench["configs"] if c["name"] == "laguna_xs2"]
-    assert len(entry) == 1 and bench["configs"][-1] is entry[0]
-    assert entry[0]["reduced"] == config["reduced"]
-    assert entry[0]["source"] == config["source"] == \
+def test_entries_of_the_benchmark(tree, config):
+    """Laguna's own entries and the accepted cells and metrics, each found
+    by its NAME: in the repository's tree, and in the tree a later PR
+    makes by appending cells, metrics and names to the lists."""
+    bench = tree.bench
+    (entry,) = [c for c in bench["configs"] if c["name"] == "laguna_xs2"]
+    assert entry["reduced"] == config["reduced"]
+    assert entry["source"] == config["source"] == \
         "https://huggingface.co/poolside/Laguna-XS.2/blob/main/config.json"
-    assert entry[0]["file"] == "benchmark/configs/laguna_xs2.json"
-    cell = bench["workloads"][-1]
-    assert cell == dict(cell, name="laguna_xs2.seq8k", config="laguna_xs2",
-                        traffic="token_ring_8k", chips=1)
-    traffic = catalog.read_json(os.path.join(BENCH, "traffic",
-                                             "token_ring_8k.json"))
+    assert entry["file"] == "benchmark/configs/laguna_xs2.json"
+    cells = {w["name"]: w for w in bench["workloads"]}
+    assert len(cells) == len(bench["workloads"])
+    cell = cells["laguna_xs2.seq8k"]
+    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
+    traffic = catalog.read_json(os.path.join(
+        tree.root, "benchmark", "traffic", cell["traffic"] + ".json"))
     assert {k: traffic[k] for k in ("kind", "ring", "steps_per_epoch",
                                     "warmup_steps", "follow_p")} == {
         "kind": "token_ring", "ring": 8, "steps_per_epoch": 64,
         "warmup_steps": 16, "follow_p": 0.5}
-    assert [m["name"] for m in bench["per_layer"][-8:]] == NEW_METRICS
-    for m in bench["per_layer"][-8:]:
-        assert m["workloads"] == ["laguna_xs2.seq8k"]
+    metrics = {m["name"]: m for m in bench["per_layer"]}
+    for name in NEW_METRICS:
+        m = metrics[name]
+        assert "laguna_xs2.seq8k" in m["workloads"]
         assert m["moves"] == "samples_per_s_per_chip"
         assert m["layer"] == "graph to XLA (symbol.py, executor.py, ops/)"
-    # the accepted cells and metrics are where and as they were
-    assert [w["name"] for w in bench["workloads"][:3]] == [
-        "resnet50.device", "inception_bn.device", "resnet50.dp4"]
-    assert len(bench["per_layer"]) == 23 + 8 and bench["run_seconds"] == 24
+    # the accepted cells and metrics are as they were, whatever came after
+    for name, (of, mix, chips) in ACCEPTED_CELLS.items():
+        assert (cells[name]["config"], cells[name]["traffic"],
+                cells[name]["chips"]) == (of, mix, chips), name
+    assert set(ACCEPTED_METRICS) <= set(metrics)
+    assert len(ACCEPTED_METRICS) == 23 + 8 and bench["run_seconds"] == 24
+    # the cell reports its 31 metrics, and none that a later cell brought
+    assert [m["name"] for m in catalog.metrics_for(
+        bench, "per_layer", "laguna_xs2.seq8k")] == [
+        n for n in ACCEPTED_METRICS if not n.startswith("collective_")]
 
 
 def test_the_walk_gives_the_files_recipe_and_the_issues_count(config):
@@ -204,7 +235,7 @@ def test_kernel_cost_functions_count_as_flops_py_counts():
     costs = _load(os.path.join(BENCH, "kernel_costs.py"), "kernel_costs")
     assert costs.kv_mean(8192, 0) == 4096.5
     assert costs.kv_mean(8192, 512) == 496.03125
-    forward, backward = costs.flash_attention(64, 8, 8192, 128, 512)
+    forward, backward = costs.flash_attention(64, 8, 8192, 128, 128, 512)
     entry = {"op": "attention", "heads": 64, "qk_dim": 128, "v_dim": 128,
              "q_len": 8192, "kv_mean": 496.03125}
     assert forward["flops"] == flops.layer_forward_flops(entry)
@@ -212,7 +243,7 @@ def test_kernel_cost_functions_count_as_flops_py_counts():
     q, kv = 64 * 8192 * 128 * 2, 8 * 8192 * 128 * 2
     assert forward["bytes"] == 2 * q + 2 * kv + 64 * 8192 * 4
     # a windowed kernel is credited with a sixteenth of the dense products
-    dense = costs.flash_attention(64, 8, 8192, 128, 0)[0]["flops"]
+    dense = costs.flash_attention(64, 8, 8192, 128, 128, 0)[0]["flops"]
     assert 8.2 < dense / forward["flops"] < 8.3
     product = costs.grouped_product(8192, 2048, 512, 32)
     assert product["flops"] == 2 * 8192 * 2048 * 512
@@ -244,10 +275,11 @@ def _records(loads):
     return records
 
 
-def test_readers_of_the_new_metrics_on_a_hand_written_run(config):
-    readers = _load(os.path.join(BENCH, "decoder_metrics.py"),
-                    "decoder_metrics")
-    peak = catalog.peak_for("TPU v5 lite")
+def _hand_written_run(config, per_chip_batch):
+    """A traced run as ``run.py`` hands it to the readers, written by
+    hand: flash kernels and other instructions under the attention scopes
+    of layers 0, 1, 2 and 10, an expert layer's gather, a grouped product
+    and a projection, three epoch stamps."""
     fwd = "jit(step)/jvp(layer{}_attn/RotaryAttention)/"
     bwd = "jit(step)/transpose(jvp(jvp()))/checkpoint/layer{}_attn/" \
           "RotaryAttention/"
@@ -265,34 +297,49 @@ def test_readers_of_the_new_metrics_on_a_hand_written_run(config):
                "fusion.1": 0.064, "flash_fwd.0": 0.640,
                "flash_fwd.10": 9.0, "fusion.2": 0.192,
                "ragged-dot-none.3": 0.064, "fusion.3": 5.0}
-    run = {"trace": {"program_op_seconds": seconds, "op_seconds": seconds,
-                     "span_steps": 64},
-           "hlo_scopes": scopes, "peak": peak, "per_chip_batch": 1,
-           "steps_per_epoch": 64, "traced_epochs": [0, 1],
-           "rows": [{"entry": 9.1, "exit": 9.2}, {"entry": 19.1,
-                                                  "exit": 19.2},
-                    {"entry": 29.1, "exit": 29.2}]}
+    return {"trace": {"program_op_seconds": seconds, "op_seconds": seconds,
+                      "span_steps": 64},
+            "hlo_scopes": scopes, "peak": catalog.peak_for("TPU v5 lite"),
+            "config": config, "per_chip_batch": per_chip_batch,
+            "steps_per_epoch": 64, "traced_epochs": [0, 1],
+            "rows": [{"entry": 9.1, "exit": 9.2}, {"entry": 19.1,
+                                                   "exit": 19.2},
+                     {"entry": 29.1, "exit": 29.2}]}
+
+
+def _two_epochs(balanced, skewed):
+    """Two traced epochs of two expert nodes, one node of the first
+    skewed."""
+    return {1: {"layer1_moe": balanced, "layer2_moe": skewed},
+            2: {"layer1_moe": balanced, "layer2_moe": balanced}}
+
+
+LAGUNA_BALANCED = {"tokens": 64 * 8192.0, "picks_held": 64 * 8192.0,
+                   "picks_all": 64 * 65536.0, "max_held": 64 * 320.0,
+                   "experts_hit": 32, "experts_held": 32}
+LAGUNA_LOADS = _two_epochs(LAGUNA_BALANCED, dict(
+    LAGUNA_BALANCED, picks_held=64 * 4096.0, max_held=64 * 2048.0,
+    experts_hit=2))
+
+
+def test_readers_of_the_new_metrics_on_a_hand_written_run(config):
+    readers = _load(os.path.join(BENCH, "decoder_metrics.py"),
+                    "decoder_metrics")
+    run = _hand_written_run(config, 1)
+    peak = run["peak"]
     # layers 1-3 are the sliding ones, 0 and 4 the full ones; layer 10 is
     # no layer of this cut
-    assert readers.attention_ms(run, "laguna_xs2", SLIDING) == \
+    assert readers.attention_ms(run, SLIDING) == \
         pytest.approx(1e3 * (0.128 + 0.256 + 0.064) / 64)
-    assert readers.attention_ms(run, "laguna_xs2", FULL) == \
-        pytest.approx(10.0)
+    assert readers.attention_ms(run, FULL) == pytest.approx(10.0)
     assert readers.moe_ms(run) == pytest.approx(1e3 * 0.256 / 64)
     costs = readers.COSTS
     least = 3 * sum(costs["roofline_seconds"](c, peak) for c in
-                    costs["flash_attention"](64, 8, 8192, 128, 512))
-    assert readers.attention_roofline_pct(run, "laguna_xs2", SLIDING) == \
+                    costs["flash_attention"](64, 8, 8192, 128, 128, 512))
+    assert readers.attention_roofline_pct(run, SLIDING) == \
         pytest.approx(100 * least / ((0.128 + 0.256) / 64))
 
-    balanced = {"tokens": 64 * 8192.0, "picks_held": 64 * 8192.0,
-                "picks_all": 64 * 65536.0, "max_held": 64 * 320.0,
-                "experts_hit": 32, "experts_held": 32}
-    skewed = dict(balanced, picks_held=64 * 4096.0, max_held=64 * 2048.0,
-                  experts_hit=2)
-    loads = {1: {"layer1_moe": balanced, "layer2_moe": skewed},
-             2: {"layer1_moe": balanced, "layer2_moe": balanced}}
-    readers.SPANS["program_records"] = lambda: (_records(loads), 0)
+    readers.SPANS["program_records"] = lambda: (_records(LAGUNA_LOADS), 0)
     assert readers.moe_picks_held_per_token(run) == pytest.approx(
         (3 * 8192 + 4096) / (4 * 8192))
     assert readers.moe_load_max_over_mean(run) == pytest.approx(
@@ -306,18 +353,129 @@ def test_readers_of_the_new_metrics_on_a_hand_written_run(config):
                    for c in part)
 
     want = (3 * least_of(8192, 32) + least_of(4096, 2)) / 2
-    assert readers.moe_grouped_roofline_pct(run, "laguna_xs2") == \
+    assert readers.moe_grouped_roofline_pct(run) == \
         pytest.approx(100 * want / (0.064 / 64))
     # nothing to read is nothing, never 0
     readers.SPANS["program_records"] = lambda: None
     assert readers.moe_picks_held_per_token(run) is None
-    assert readers.moe_grouped_roofline_pct(run, "laguna_xs2") is None
+    assert readers.moe_grouped_roofline_pct(run) is None
     empty = dict(run, trace=None)
-    assert readers.attention_ms(empty, "laguna_xs2", FULL) is None
+    assert readers.attention_ms(empty, FULL) is None
     assert readers.moe_ms(dict(run, hlo_scopes=None)) is None
     for name in NEW_METRICS:
         module = catalog.load_metric("layer_metrics", name)
         assert module.read(empty) is None, name
+    # keys a published config.json may lack, read as its family writes
+    # them: no list of layer types is full attention in every layer, a
+    # null window is none, one head count stands for every layer
+    plain = {k: v for k, v in config.items() if k != "layer_types"}
+    assert readers.attention_layers(plain, FULL) == [0, 1, 2, 3, 4]
+    assert readers.attention_ms(dict(run, config=plain), SLIDING) is None
+    assert readers.attention_ms(dict(run, config=plain), FULL) == \
+        pytest.approx(1e3 * (0.128 + 0.256 + 0.064 + 0.640) / 64)
+    one_count = {k: v for k, v in config.items()
+                 if k != "num_attention_heads_per_layer"}
+    least = 3 * sum(costs["roofline_seconds"](c, peak) for c in
+                    costs["flash_attention"](48, 8, 8192, 128, 128, 512))
+    assert readers.attention_roofline_pct(
+        dict(run, config=one_count), SLIDING) == \
+        pytest.approx(100 * least / ((0.128 + 0.256) / 64))
+    least = 3 * sum(costs["roofline_seconds"](c, peak) for c in
+                    costs["flash_attention"](64, 8, 8192, 128, 128, 0))
+    assert readers.attention_roofline_pct(
+        dict(run, config=dict(config, sliding_window=None)), SLIDING) == \
+        pytest.approx(100 * least / ((0.128 + 0.256) / 64))
+
+
+# what the eight metric FILES must give on the run above, read in a cell
+# of each configuration of the later PR's tree. ``laguna_xs2``: the numbers
+# of the test above. ``tiny_decoder``: layers 0 and 2 are the sliding ones,
+# 1 and 3 the full ones (Laguna's pattern would read 7 and 10 ms), 6 heads
+# over 2 of 16, 32 positions, a window of 4, 2 sequences a step, experts
+# of 48 x 24, 4 held
+LATER_CELLS = {
+    "laguna_xs2": {
+        "batch": 1, "loads": LAGUNA_LOADS, "experts": (2048, 512),
+        "attention_window_ms_per_step": 1e3 * (0.128 + 0.256 + 0.064) / 64,
+        "attention_full_ms_per_step": 10.0,
+        "window": (3, (64, 8, 8192, 128, 128, 512), 0.128 + 0.256),
+        "full": (2, (48, 8, 8192, 128, 128, 0), 0.640),
+        "grouped": ((8192, 32), (4096, 2)),
+        "moe_load_max_over_mean": 2048 * 32 / 4096,
+        "moe_picks_held_per_token": (3 * 8192 + 4096) / (4 * 8192)},
+    "tiny_decoder": {
+        "batch": 2, "experts": (48, 24),
+        "loads": _two_epochs(
+            {"tokens": 64 * 64.0, "picks_held": 64 * 64.0,
+             "picks_all": 64 * 128.0, "max_held": 64 * 20.0,
+             "experts_hit": 4, "experts_held": 4},
+            {"tokens": 64 * 64.0, "picks_held": 64 * 32.0,
+             "picks_all": 64 * 128.0, "max_held": 64 * 16.0,
+             "experts_hit": 2, "experts_held": 4}),
+        "attention_window_ms_per_step": 1e3 * (0.640 + 0.064) / 64,
+        "attention_full_ms_per_step": 1e3 * (0.128 + 0.256) / 64,
+        "window": (2, (6, 2, 32, 16, 16, 4), 0.640),
+        "full": (2, (6, 2, 32, 16, 16, 0), 0.128 + 0.256),
+        "grouped": ((64, 4), (32, 2)),
+        "moe_load_max_over_mean": 16 * 4 / 32,
+        "moe_picks_held_per_token": (3 * 64 + 32) / (4 * 64)},
+}
+
+
+@pytest.mark.parametrize("name", sorted(LATER_CELLS))
+def test_the_metric_files_price_the_cell_they_are_read_in(name, overlay):
+    """In the tree a later PR makes, the eight metric files are listed for
+    two decoder cells under one name each, and each reads the sizes of
+    the cell it is read in from ``run["config"]``: given another cell's
+    configuration this test fails."""
+    here = str(overlay / "benchmark")
+    case = LATER_CELLS[name]
+    cfg = catalog.read_json(os.path.join(here, "configs", name + ".json"))
+    run = _hand_written_run(cfg, case["batch"])
+    costs = _load(os.path.join(here, "kernel_costs.py"), "kernel_costs")
+    peak = run["peak"]
+
+    def attention_pct(layers, flash, kernels_s):
+        least = layers * sum(costs.roofline_seconds(c, peak)
+                             for c in costs.flash_attention(*flash))
+        return 100 * least * case["batch"] / (kernels_s / 64)
+
+    def least_of(rows, experts):
+        return sum(costs.roofline_seconds(c, peak) for part in
+                   costs.gated_experts(rows, *case["experts"], experts)
+                   for c in part)
+
+    balanced, skewed = case["grouped"]
+    want = dict(
+        {k: case[k] for k in NEW_METRICS if k in case},
+        attention_window_roofline_pct=attention_pct(*case["window"]),
+        attention_full_roofline_pct=attention_pct(*case["full"]),
+        moe_ms_per_step=1e3 * 0.256 / 64,
+        moe_grouped_roofline_pct=100 * (
+            3 * least_of(*balanced) + least_of(*skewed)) / 2 / (0.064 / 64))
+    assert sorted(want) == sorted(NEW_METRICS)
+    convnet = dict(run, config={"image": [224, 224, 3],
+                                "per_chip_batch": 256})
+    got = {}
+    for metric in NEW_METRICS:
+        module = catalog.load_metric("layer_metrics", metric, here=here)
+        assert "workloads" not in module.METRIC
+        spans = module.DECODER["SPANS"]
+        spans["program_records"] = lambda: (_records(case["loads"]), 0)
+        got[metric] = module.read(run)
+        # a cell whose configuration is no decoder's: nothing, never 0
+        # (the three that read no size read the run's records and scopes)
+        if metric not in ("moe_ms_per_step", "moe_load_max_over_mean",
+                          "moe_picks_held_per_token"):
+            assert module.read(convnet) is None, metric
+        spans["program_records"] = lambda: None
+        assert module.read(dict(run, trace=None)) is None, metric
+    assert got == pytest.approx(want)
+    # the two cells' numbers differ wherever a size is read
+    other = LATER_CELLS[sorted(set(LATER_CELLS) - {name})[0]]
+    for metric in ("attention_window_ms_per_step",
+                   "attention_full_ms_per_step", "moe_load_max_over_mean"):
+        assert want[metric] != pytest.approx(other[metric]), metric
 
 
 # -- the runner on the cell, at a tiny preset on the CPU -------------------------
@@ -339,7 +497,7 @@ TINY = {
 
 
 @pytest.fixture(scope="module")
-def overlay(tmp_path_factory, bench, config):
+def tiny_checkout(tmp_path_factory, bench, config):
     """A checkout-shaped directory: ``benchmark/`` as it is, the
     configuration's file with tiny sizes under its own name (the reference
     and the metric files read it by that name), a short traffic mix."""
@@ -370,14 +528,14 @@ def overlay(tmp_path_factory, bench, config):
 
 
 @pytest.mark.parametrize("trace", [0, 1])
-def test_runner_on_the_cell_at_a_tiny_preset(overlay, trace):
+def test_runner_on_the_cell_at_a_tiny_preset(tiny_checkout, trace):
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
         [sys.executable, os.path.join("benchmark", "run.py"), "--workload",
          "laguna_xs2.seq8k", "--seed", str(2 ** 31 + 27), "--seconds", "0.5",
          "--trace", str(trace), "--rehearse-on-cpu"],
-        cwd=overlay, env=env, capture_output=True, text=True, timeout=900)
+        cwd=tiny_checkout, env=env, capture_output=True, text=True, timeout=900)
     assert proc.returncode == 0, proc.stderr[-3000:]
     lines = [json.loads(ln) for ln in proc.stdout.splitlines()
              if ln.startswith("{")]
