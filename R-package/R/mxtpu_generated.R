@@ -8,6 +8,8 @@
 mx.symbol.Activation <- function(...) mx.symbol.op("Activation", ...)
 # BatchNorm: Batch normalization with running-stat aux state (reference:
 mx.symbol.BatchNorm <- function(...) mx.symbol.op("BatchNorm", ...)
+# BlockDiffusionAttention: Grouped-query attention under the mask of block-diffusion training
+mx.symbol.BlockDiffusionAttention <- function(...) mx.symbol.op("BlockDiffusionAttention", ...)
 # BlockGrad: Identity forward, zero gradient (reference: block_grad-inl.h) —
 mx.symbol.BlockGrad <- function(...) mx.symbol.op("BlockGrad", ...)
 # Concat: Concatenate along ``dim`` (reference: concat-inl.h, default channel 
@@ -36,6 +38,8 @@ mx.symbol.LinearRegressionOutput <- function(...) mx.symbol.op("LinearRegression
 mx.symbol.LogisticRegressionOutput <- function(...) mx.symbol.op("LogisticRegressionOutput", ...)
 # MAERegressionOutput: Identity forward, sign(pred - label) gradient (L1 regression head;
 mx.symbol.MAERegressionOutput <- function(...) mx.symbol.op("MAERegressionOutput", ...)
+# MaskedDiffusionOutput: Loss head of masked (absorbing-state) diffusion within blocks, the
+mx.symbol.MaskedDiffusionOutput <- function(...) mx.symbol.op("MaskedDiffusionOutput", ...)
 # MixtureOfExperts: Sparse gated feed-forward layer, one rank's share of its experts.
 mx.symbol.MixtureOfExperts <- function(...) mx.symbol.op("MixtureOfExperts", ...)
 # Pooling: Max/avg/sum pooling, NCHW or NHWC per ``layout`` (reference:

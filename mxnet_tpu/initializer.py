@@ -9,6 +9,7 @@ moving stats). Sampling uses the framework PRNG (mxnet_tpu.random), so
 from __future__ import annotations
 
 import math
+import re
 
 import numpy as np
 
@@ -16,7 +17,8 @@ from . import random as _random
 from .base import MXNetError
 from .ndarray import NDArray
 
-__all__ = ["Initializer", "Uniform", "Normal", "Xavier", "One", "Zero", "Constant"]
+__all__ = ["Initializer", "Uniform", "Normal", "Xavier", "One", "Zero", "Constant",
+           "Mixed"]
 
 
 class Initializer:
@@ -41,7 +43,9 @@ class Initializer:
             self._init_one(name, arr)
         elif name.endswith("moving_avg"):
             self._init_zero(name, arr)
-        elif name.endswith("expert_load"):  # MixtureOfExperts' pick counts
+        elif name.endswith(("expert_load", "mask_count")):
+            # counts kept as auxiliary states: MixtureOfExperts' picks,
+            # MaskedDiffusionOutput's rows
             self._init_zero(name, arr)
         else:
             self._init_default(name, arr)
@@ -163,3 +167,44 @@ class Constant(Initializer):
 
     def _init_default(self, _name, arr):
         arr[:] = self.value
+
+
+class _Fill(Constant):
+    """``Mixed``'s bare number: the value, whatever the name's suffix."""
+
+    def __call__(self, _name, arr):
+        arr[:] = self.value
+
+
+class Mixed(Initializer):
+    """Initializers by name (reference: initializer.py ``Mixed``): the
+    first of ``patterns`` (regular expressions, searched in the name) that
+    matches gives a parameter its initializer; end with ``".*"``, a name
+    that matches none is an error. An initializer may be given as what a
+    configuration file can hold: a dict ``{"name": "Xavier", ...}`` of a
+    class of this module and its arguments, or a bare number, which fills
+    the matching arrays with itself whatever their suffix (a norm's
+    ``gamma`` that starts elsewhere than at one)."""
+
+    def __init__(self, patterns, initializers):
+        if len(patterns) != len(initializers):
+            raise MXNetError(f"Mixed: {len(patterns)} patterns for "
+                             f"{len(initializers)} initializers")
+        self.map = [(re.compile(p), self._made(i))
+                    for p, i in zip(patterns, initializers)]
+
+    @staticmethod
+    def _made(spec):
+        if isinstance(spec, dict):
+            spec = dict(spec)
+            return globals()[spec.pop("name")](**spec)
+        if isinstance(spec, (int, float)):
+            return _Fill(spec)
+        return spec
+
+    def __call__(self, name, arr):
+        for pattern, init in self.map:
+            if pattern.search(name):
+                return init(name, arr)
+        raise MXNetError(f"Mixed: no pattern matches {name!r}; end the "
+                         "patterns with \".*\"")

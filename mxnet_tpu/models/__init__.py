@@ -13,8 +13,9 @@ from .lstm_scan import LSTMLM
 from .transformer import TransformerLM, transformer_lm_config
 from .moe_transformer import MoEPipelineLM, moe_pipeline_config
 from .laguna import laguna
+from .sdar import sdar
 
 __all__ = ["mlp", "lenet", "alexnet", "inception_bn_cifar", "inception_bn",
            "resnet", "resnet50", "lstm_unroll", "LSTMState", "LSTMParam",
            "LSTMLM", "TransformerLM", "transformer_lm_config",
-           "MoEPipelineLM", "moe_pipeline_config", "laguna"]
+           "MoEPipelineLM", "moe_pipeline_config", "laguna", "sdar"]

@@ -12,6 +12,13 @@ through ``FeedForward.fit`` (doc/developer-guide/decoder-ops.md).
                     of every head (default or YaRN frequencies), an optional
                     sliding window, an optional sigmoid gate a head. The
                     products run in ops/pallas/flash_attention.py.
+  BlockDiffusionAttention
+                    the attention of block-diffusion training: rows are a
+                    sequence's noisy copy followed by its clean copy, both
+                    at positions ``0 .. seq_len - 1``; a clean query sees
+                    the clean keys of its block and the blocks before it,
+                    a noisy query the clean keys of the blocks before its
+                    own and the noisy keys of its own block.
   MixtureOfExperts  a router over ALL ``num_experts``, top-k, normalised and
                     scaled weights, the ``experts_held`` experts from
                     ``first_expert`` on computed here as grouped products
@@ -214,6 +221,76 @@ class RotaryAttentionOp(OpProp):
         return [o.reshape(batch * t, self.num_heads * d)], []
 
 
+@register_op("BlockDiffusionAttention")
+class BlockDiffusionAttentionOp(RotaryAttentionOp):
+    """Grouped-query attention under the mask of block-diffusion training
+    (BD3-LM, Arriola et al., arXiv:2503.09573).
+
+    A sequence of ``seq_len`` positions, cut into blocks of
+    ``block_length``, comes as ``2 * seq_len`` rows: its noisy copy (some
+    positions replaced by the mask token), then its clean copy; noisy row
+    ``i`` and clean row ``i`` carry the SAME rotary position ``i``. With
+    ``b(i) = i // block_length``, in one softmax a query:
+
+    - a clean query ``i`` sees the clean keys ``j`` with ``b(j) <= b(i)``,
+    - a noisy query ``i`` sees the clean keys with ``b(j) < b(i)`` and the
+      noisy keys with ``b(j) == b(i)`` (its own block, both directions),
+
+    and no query any other key. ``query`` (rows, num_heads * head_dim),
+    ``key`` / ``value`` (rows, num_kv_heads * head_dim), rows = batch * 2 *
+    seq_len; output as ``RotaryAttention``'s. The products run in
+    ops/pallas/flash_attention.py (``step=block_length, halves=2``): no
+    score matrix is ever held, and what the mask hides is not computed."""
+
+    params = {**{k: v for k, v in RotaryAttentionOp.params.items()
+                 if k not in ("window", "gated")},
+              "block_length": (Range(int, lo=1), REQUIRED,
+                               "positions a block; divides seq_len")}
+    window, gated = 0, False
+
+    def __init__(self, **kwargs):
+        super().__init__(**kwargs)
+        if self.seq_len % self.block_length:
+            raise MXNetError(
+                f"BlockDiffusionAttention: blocks of {self.block_length} do "
+                f"not divide a sequence of {self.seq_len}")
+
+    def infer_shape(self, in_shapes):
+        rows = next((s[0] for s in in_shapes if s is not None), None)
+        if rows is None or rows % (2 * self.seq_len):
+            raise MXNetError(
+                f"BlockDiffusionAttention: {rows} rows are not whole pairs "
+                f"of a noisy and a clean copy of {self.seq_len} positions")
+        q = (rows, self.num_heads * self.head_dim)
+        kv = (rows, self.num_kv_heads * self.head_dim)
+        return [q, kv, kv], [q], []
+
+    def fwd(self, ins, aux, is_train, rng):
+        from .pallas import flash_attention
+        from .pallas.flash_attention import rotary_tables
+
+        t, d = self.seq_len, self.head_dim
+        pairs = ins[0].shape[0] // (2 * t)
+        q = ins[0].reshape(pairs, 2 * t, self.num_heads, d)
+        k = ins[1].reshape(pairs, 2 * t, self.num_kv_heads, d)
+        v = ins[2].reshape(pairs, 2 * t, self.num_kv_heads, d)
+        rope = rotary_tables(t, d, self.inv_freq(),
+                             self.rope_attention_factor) \
+            if self.rotary_dim else None
+
+        def rotated(x):     # both copies at the positions of one
+            return rotate_heads(x.reshape(2 * pairs, t, -1, d),
+                                rope).reshape(x.shape)
+
+        if rope is not None:
+            k = rotated(k)
+            if d % 128:
+                q, rope = rotated(q), None
+        o = flash_attention(q, k, v, causal=True, heads_last=True,
+                            rotary=rope, step=self.block_length, halves=2)
+        return [o.reshape(2 * pairs * t, self.num_heads * d)], []
+
+
 # -- experts ------------------------------------------------------------------
 # The picks live in an expanded space of rows * top_k entries, sorted by
 # expert, of which the first ``n`` hold a pick on an expert kept here. Going
@@ -304,8 +381,9 @@ class MixtureOfExpertsOp(OpProp):
     """Sparse gated feed-forward layer, one rank's share of its experts.
 
     ``data`` (rows, hidden). ``router_weight`` (num_experts, hidden): scores
-    ``sigmoid(x W_r^T)`` over ALL experts, the ``top_k`` largest picked,
-    weights ``scaling * s_e / sum of the picked s``. ``gate_weight`` /
+    ``sigmoid(x W_r^T)`` (or, ``score="softmax"``, the softmax of the
+    logits over ALL experts, in float32) over ALL experts, the ``top_k``
+    largest picked, weights ``scaling * s_e / sum of the picked s``. ``gate_weight`` /
     ``up_weight`` (experts_held, width, hidden) and ``down_weight``
     (experts_held, hidden, width) are experts ``first_expert`` ..
     ``first_expert + experts_held - 1``: the picks that fall on them are
@@ -344,6 +422,8 @@ class MixtureOfExpertsOp(OpProp):
         "top_k": (Range(int, lo=1), REQUIRED, "experts a row picks"),
         "expert_width": (Range(int, lo=1), REQUIRED, "width of an expert"),
         "scaling": (float, 1.0, "multiplies the normalised weights"),
+        "score": (("sigmoid", "softmax"), "sigmoid",
+                  "the router's score function, over all experts"),
         "shared_width": (Range(int, lo=0), 0,
                          "width of the shared expert; 0 = none"),
         "train_router": (bool, True,
@@ -384,9 +464,11 @@ class MixtureOfExpertsOp(OpProp):
     def route(self, x, router_w):
         """``(experts, weights)``, both (rows, top_k): the picks and their
         normalised, scaled weights (float32)."""
-        scores = jax.nn.sigmoid(jax.lax.dot_general(
+        logits = jax.lax.dot_general(
             x, router_w.astype(x.dtype), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32))
+            preferred_element_type=jnp.float32)
+        scores = jax.nn.sigmoid(logits) if self.score == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
         picked, experts = jax.lax.top_k(scores, self.top_k)
         weights = self.scaling * picked / jnp.sum(picked, axis=-1,
                                                   keepdims=True)

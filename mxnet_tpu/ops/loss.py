@@ -17,11 +17,24 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .registry import OpProp, register_op
+from ..base import MXNetError
+from .registry import OpProp, Range, REQUIRED, register_op
 
 
 def _softmax(x, axis):
     return jax.nn.softmax(x.astype(jnp.float32), axis=axis).astype(x.dtype)
+
+
+def _cross_entropy(out, label):
+    """``-log p[label]`` a row of an already-computed softmax ``out``
+    ((batch, C) or multi-output (batch, C, ...), label (batch, ...)),
+    summed over a row's positions: (batch,) float32."""
+    # idx[:, None] expands the class axis for both shapes
+    p = out.astype(jnp.float32)
+    idx = label.astype(jnp.int32)
+    nll = -jnp.log(jnp.take_along_axis(p, idx[:, None], axis=1)[:, 0]
+                   + 1e-12)
+    return nll.reshape(nll.shape[0], -1).sum(axis=1)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -123,16 +136,120 @@ class SoftmaxOutputOp(OpProp):
         """Cross-entropy of the already-computed softmax output — the loss
         whose gradient this head injects (sum over valid rows, scaled like
         the injected gradient)."""
-        # p: (batch, C) or multi-output (batch, C, ...), label (batch, ...)
-        # — idx[:, None] expands the class axis for both shapes
-        p = out.astype(jnp.float32)
-        idx = label.astype(jnp.int32)
-        nll = -jnp.log(jnp.take_along_axis(p, idx[:, None], axis=1)[:, 0]
-                       + 1e-12)
-        nll = nll.reshape(nll.shape[0], -1).sum(axis=1)
+        nll = _cross_entropy(out, label)
         if mask is not None:
             nll = nll * mask
         return jnp.sum(nll) * self.grad_scale
+
+
+@register_op("MaskedDiffusionOutput")
+class MaskedDiffusionOutputOp(OpProp):
+    """Loss head of masked (absorbing-state) diffusion within blocks, the
+    training objective of a block-diffusion decoder (BD3-LM, Arriola et
+    al., arXiv:2503.09573).
+
+    ``data`` (rows, classes): logits on the noisy rows; ``label``: the
+    clean ids; ``noisy``: the ids the model saw, ``mask_id`` where a
+    position was masked (same shape as ``label``, its last axis whole
+    blocks of ``block_length``; no gradient). Forward is the softmax, read
+    like ``SoftmaxOutput``'s. The injected gradient is ``(p - onehot) *
+    w_i`` with ``w_i = [noisy_i == mask_id] * block_length / masks in i's
+    block``: the block's ``1 / t`` at the masked share ``t`` it was drawn
+    at; an unmasked row has weight 0, injects nothing and counts for
+    nothing. ``loss_value`` (the health stream's) is the unweighted
+    cross-entropy of every row.
+
+    Auxiliary state ``mask_count`` (4,): rows, masked rows, blocks and the
+    sum of the weights seen by the training steps, modulo ``LOAD_WRAP``
+    (whole numbers a float32 holds exactly, as ``MixtureOfExperts``'
+    ``expert_load``); ``epoch_record`` makes ``fit``'s
+    ``fit.epoch.diffusion_mask`` line of them."""
+
+    LOAD_WRAP = 1 << 23
+
+    params = {
+        "mask_id": (Range(int, lo=0), REQUIRED, "the id of a masked position"),
+        "block_length": (Range(int, lo=1), REQUIRED, "positions a block"),
+        "grad_scale": (float, 1.0,
+                       "multiplier applied to the injected gradient"),
+    }
+    is_loss = True
+
+    def list_arguments(self):
+        return ["data", "label", "noisy"]
+
+    def list_auxiliary_states(self):
+        return ["mask_count"]
+
+    def infer_shape(self, in_shapes):
+        d = self._known(in_shapes, 0)
+        ids = next((tuple(s) for s in in_shapes[1:] if s is not None), None)
+        if ids is None:
+            raise MXNetError("MaskedDiffusionOutput: neither the label's "
+                             "nor the noisy ids' shape is known")
+        size = 1
+        for n in ids:
+            size *= n
+        if size != d[0] or ids[-1] % self.block_length:
+            raise MXNetError(
+                f"MaskedDiffusionOutput: ids {ids} against {d[0]} rows of "
+                f"logits and blocks of {self.block_length}")
+        return [d, ids, ids], [d], [(4,)]
+
+    def infer_dtype(self, in_dtypes):
+        # integer ids beside float logits, as Embedding
+        import numpy as np
+
+        data = np.dtype(in_dtypes[0]) if in_dtypes[0] is not None \
+            else np.dtype("float32")
+        ids = [np.dtype(t) if t is not None else np.dtype("int32")
+               for t in in_dtypes[1:]]
+        return [data, *ids], [data], [np.dtype("float32")]
+
+    def weights(self, noisy):
+        """``(w, blocks with a mask)``: a weight a row (float32, the rows
+        as the logits') and the count the weights add up to a block of."""
+        masked = (noisy.astype(jnp.int32) == self.mask_id).reshape(
+            -1, self.block_length)
+        count = jnp.sum(masked, axis=1, keepdims=True)
+        w = jnp.where(masked, self.block_length
+                      / jnp.maximum(count, 1).astype(jnp.float32), 0.0)
+        return w.reshape(-1), jnp.sum(count > 0)
+
+    def fwd(self, ins, aux, is_train, rng):
+        data, label, noisy = ins
+        w, nonempty = self.weights(jax.lax.stop_gradient(noisy))
+        # a weight a row is a validity mask that is not 0 or 1: a row of
+        # weight 0 injects no gradient and counts for nothing
+        out = _softmax_output_masked(data, label.reshape(-1), w,
+                                     self.grad_scale, False)
+        count = aux[0]
+        if is_train:
+            seen = jnp.stack([jnp.asarray(n, count.dtype) for n in (
+                w.shape[0], jnp.sum(w > 0), w.shape[0] // self.block_length,
+                self.block_length * nonempty)])
+            count = (count + seen) % self.LOAD_WRAP
+        return [out], [count]
+
+    def loss_value(self, out, label, mask=None):
+        """The health stream's scalar: the cross-entropy of EVERY row at
+        weight 1. Its caller has the label alone, not the noisy ids, so
+        this is not the weighted sum whose gradient the head injects
+        (masked rows only, at a block's weight): a spike shows in both."""
+        del mask
+        return jnp.sum(_cross_entropy(out, label.reshape(-1))) \
+            * self.grad_scale
+
+    def epoch_record(self, before, after):
+        """This epoch's rows (``OpProp.epoch_record``): ``rows`` the head
+        saw, ``masked`` of them, the ``blocks`` they lie in and
+        ``weight_sum``, ``block_length`` a block that had a mask."""
+        rows, masked, blocks, weight_sum = (
+            (after[0].astype("int64") - before[0].astype("int64"))
+            % self.LOAD_WRAP).tolist()
+        return "fit.epoch.diffusion_mask", {
+            "rows": rows, "masked": masked, "blocks": blocks,
+            "weight_sum": weight_sum, "block_length": self.block_length}
 
 
 def _regression_vjp(transform, grad_fn):

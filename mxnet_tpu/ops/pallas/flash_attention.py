@@ -35,6 +35,19 @@ the block indices alone:
   from its first key takes few values over a grid, so each pattern is
   static code under its own ``pl.when`` (``_band_keys``).
 
+A staircase (``step=B`` with ``causal=True``) moves the causal edge from
+the diagonal to blocks of ``B`` positions: query ``t`` sees the keys of its
+own block and of every block before it, ``k // B <= t // B`` (``step=1`` is
+the diagonal). ``halves=2`` is the mask of block-diffusion training
+(Arriola et al., arXiv:2503.09573): queries and keys are a sequence's noisy
+copy followed by its clean copy, ``2 * seq`` positions; a clean query sees
+the clean keys under the staircase, a noisy query the clean keys of the
+blocks BEFORE its own (``k // B < t // B``) and the noisy keys of its own
+block, one softmax over both. A query block's own noisy block is one more
+key step of the grid, whose tile is the block diagonal; every tile is still
+outside, inside or walked by sub-tiles, its pattern told by one more term,
+the ``kind`` of its quadrant.
+
 The backward kernels do the same over query blocks; ``flash_bwd_dkv``
 accumulates a key-value head's gradient over the query heads that share
 it. Block shapes are chosen in the wrapper from the mask
@@ -98,11 +111,14 @@ def _kv_lo(qi, bq, bk, window):
     return _div(jnp.maximum(qi * bq - (window - 1), 0), bk)
 
 
-def _kv_hi(qi, bq, bk, nk, causal):
-    """Last key block that query block ``qi`` can need."""
+def _kv_hi(qi, bq, bk, nk, causal, before=0):
+    """Last key block that query block ``qi`` can need; ``before`` (0 or a
+    staircase's step, static or traced) where a query sees only the blocks
+    before its own: the block's last query then sees ``before`` keys fewer
+    (blocks are whole steps, and longer than one)."""
     if not causal:
         return nk - 1
-    return jnp.minimum(_div(qi * bq + bq - 1, bk), nk - 1)
+    return jnp.minimum(_div(qi * bq + bq - 1 - before, bk), nk - 1)
 
 
 def _kv_steps(bq, bk, nk, causal, window):
@@ -115,11 +131,12 @@ def _kv_steps(bq, bk, nk, causal, window):
     return min(nk, _cdiv(bq + window - 1, bk) + (1 if bq % bk else 0))
 
 
-def _q_lo(kj, bq, bk, causal):
-    """First query block that can need key block ``kj``."""
+def _q_lo(kj, bq, bk, causal, before=0):
+    """First query block that can need key block ``kj`` (``before`` as in
+    ``_kv_hi``: the block's first key is seen ``before`` queries later)."""
     if not causal:
         return 0
-    return _div(kj * bk, bq)
+    return _div(kj * bk + before, bq)
 
 
 def _q_hi(kj, bq, bk, nq, window):
@@ -170,16 +187,27 @@ def _kv_index(row, kj, hkv, packed):
 # 0 <= q - k (causal), q - k < window, k < seq_k. A sub-tile's class is
 # ``None`` (no pair in the band: not computed) or the terms of its mask,
 # ``()`` where every pair is in the band:
-#   ("ge", c)   row - col >= c      the causal diagonal
+#   ("ge", c)   row - col >= c      the causal edge
 #   ("lt", c)   row - col < c       the window's far edge
 #   ("col", c)  col < c             the padding of the last key block
+#   ("eq", c)   row - col == c      a query's own block (``halves=2``)
 # with row and col counted from the sub-tile's own corner, so that the
-# sub-tiles an edge cuts alike share one mask.
+# sub-tiles an edge cuts alike share one mask. Under a staircase of ``step``
+# positions "ge" and "eq" compare the FIRST positions of the blocks that
+# row and col lie in (``_masks``); blocks, sub-tiles and sequences are whole
+# steps, so a corner is a block's first position and ``q - k`` of the
+# blocks' firsts takes the multiples of ``step`` in d - (sub_k - step) ..
+# d + (sub_q - step). Step 1 is the diagonal.
 
 # side of a sub-tile, a kernel (PERF.md section 7 has the sweep): the
 # forward pass, bound by its vector work, gains most from computing least;
 # the backward kernels, bound by their products, from products of 256 rows
 _SUB = {"flash_fwd": 128, "flash_bwd_dq": 256, "flash_bwd_dkv": 256}
+
+# a tile's quadrant under ``halves=2``: a clean query on clean keys (the
+# one kind there is with one half), a noisy query on the clean keys before
+# its block, a noisy query on its own block's noisy keys
+_CLEAN, _BEFORE, _OWN = 0, 1, 2
 
 
 def _sub_shape(bq, bk, sub):
@@ -188,25 +216,34 @@ def _sub_shape(bq, bk, sub):
     return (sub if bq % sub == 0 else bq), (sub if bk % sub == 0 else bk)
 
 
-def _tile_classes(off, keys, bq, bk, sub, causal, window):
+def _tile_classes(off, keys, bq, bk, sub, causal, window, step=1,
+                  kind=_CLEAN):
     """Class of every sub-tile of the tile whose first query lies ``off``
     positions after its first key and whose key block holds ``keys`` keys
     that exist: a tuple (query strips) of tuples (along the keys)."""
     sq, sk = _sub_shape(bq, bk, sub)
+    # the least q - k (of the blocks' first positions) a pair in the band has
+    least = step if kind == _BEFORE else 0
     rows = []
     for a in range(bq // sq):
         row = []
         for b in range(bk // sk):
             d = off + a * sq - b * sk
-            lo, hi = d - (sk - 1), d + (sq - 1)      # the range of q - k
+            lo, hi = d - (sk - step), d + (sq - step)  # the range of q - k
             left = keys - b * sk                     # keys that exist here
-            if (causal and hi < 0) or left <= 0 or (
+            if kind == _OWN:
+                # off is 0 and the tile square: a block lies in one sub-tile
+                # (what a padded query sees of the padding is nobody's)
+                row.append(None if a != b or left <= 0 else
+                           (("eq", 0),) if step < sq else ())
+                continue
+            if (causal and hi < least) or left <= 0 or (
                     window is not None and lo >= window):
                 row.append(None)
                 continue
             terms = []
-            if causal and lo < 0:
-                terms.append(("ge", -d))
+            if causal and lo < least:
+                terms.append(("ge", least - d))
             if window is not None and hi >= window:
                 terms.append(("lt", window - d))
             if left < sk:
@@ -217,22 +254,35 @@ def _tile_classes(off, keys, bq, bk, sub, causal, window):
 
 
 @functools.lru_cache(maxsize=256)
-def _band_keys(nq, nk, bq, bk, seq_k, sub, causal, window):
+def _band_keys(nq, nk, bq, bk, seq_k, sub, causal, window, step=1, halves=1):
     """``{(off, last): (classes, tiles)}`` over the tiles of an (nq, nk)
     grid that touch the band. ``(off, last)`` is what a kernel tells a
     tile's pattern by: the offset of its first query from its first key (0
     without a causal mask, where no edge depends on it) and whether its
-    key block is a padded last one."""
+    key block is a padded last one. With ``halves=2`` the grid is a half's
+    and the keys are ``(off, last, kind)``, a tile for each quadrant that
+    holds one and a query block's own."""
     tail = seq_k - (nk - 1) * bk
     found = {}
+
+    def add(key, kind):
+        if key not in found:
+            found[key] = [_tile_classes(key[0], tail if key[1] else bk, bq,
+                                        bk, sub, causal, window, step, kind),
+                          0]
+        found[key][1] += 1
+
     for qi in range(nq):
         for kj in range(nk):
             last = tail < bk and kj == nk - 1
             key = (qi * bq - kj * bk if causal else 0, last)
-            if key not in found:
-                found[key] = [_tile_classes(key[0], tail if last else bk,
-                                            bq, bk, sub, causal, window), 0]
-            found[key][1] += 1
+            if halves == 1:
+                add(key, _CLEAN)
+                continue
+            add(key + (_CLEAN,), _CLEAN)
+            add(key + (_BEFORE,), _BEFORE)
+        if halves == 2:
+            add((0, tail < bk and qi == nk - 1, _OWN), _OWN)
     return {key: (classes, n) for key, (classes, n) in found.items()
             if any(c is not None for row in classes for c in row)}
 
@@ -241,16 +291,17 @@ def _all_inside(classes):
     return all(c == () for row in classes for c in row)
 
 
-def band_tiles(seq_q, seq_k, bq, bk, sub, causal, window):
+def band_tiles(seq_q, seq_k, bq, bk, sub, causal, window, step=1, halves=1):
     """What one head of a call computes, exact from the shapes: ``tiles``
     visited, their sub-tiles computed ``unmasked`` / ``masked`` and
     ``skipped``, and ``ratio``, the products computed over the products
     inside the band (1.0 would be no waste; padded query rows are
-    computed and are no part of the band)."""
+    computed and are no part of the band). ``seq_q`` and ``seq_k`` are a
+    half's with ``halves=2``, the count both halves'."""
     import numpy as np
 
     keys = _band_keys(_cdiv(seq_q, bq), _cdiv(seq_k, bk), bq, bk, seq_k,
-                      sub, bool(causal), window)
+                      sub, bool(causal), window, step, halves)
     count = {"tiles": 0, "unmasked": 0, "masked": 0, "skipped": 0}
     for classes, n in keys.values():
         flat = [c for row in classes for c in row]
@@ -260,32 +311,47 @@ def band_tiles(seq_q, seq_k, bq, bk, sub, causal, window):
         count["skipped"] += n * sum(c is None for c in flat)
     q = np.arange(seq_q)
     first = np.maximum(q - window + 1, 0) if window is not None else 0 * q
-    last = np.minimum(q, seq_k - 1) if causal else 0 * q + seq_k - 1
+    # the last key of the block a query lies in (itself at step 1)
+    edge = q - q % step + step - 1
+    last = np.minimum(edge, seq_k - 1) if causal else 0 * q + seq_k - 1
     inside = int(np.maximum(last - first + 1, 0).sum())
+    if halves == 2:     # a noisy query: the blocks before its own, and its own
+        inside += int(np.minimum(edge + 1 - step, seq_k).sum()) + seq_q * step
     sq, sk = _sub_shape(bq, bk, sub)
     count["ratio"] = (count["unmasked"] + count["masked"]) * sq * sk / inside
     return count
 
 
-def _band(kernel, bq, bk, seq_q, seq_k, causal, window):
+def _band(kernel, bq, bk, seq_q, seq_k, causal, window, step=1, halves=1):
     """What a kernel's body needs of the band: its ``sub``, whether the
     last key block is ``padded``, and ``groups``, the distinct patterns of
-    its grid with the ``(off, last)`` of the tiles that have each. Leaves
-    one zero-length ``flash.band`` record a traced call."""
+    its grid with the ``(off, last)`` (and ``kind``) of the tiles that have
+    each. Leaves one zero-length ``flash.band`` record a traced call."""
     from ... import telemetry
 
     sub = _SUB[kernel]
+    if step > 1 or halves == 2:
+        sides = (bq, bk, seq_q, seq_k, *_sub_shape(bq, bk, sub))
+        if not causal or window is not None or any(n % step for n in sides) \
+                or min(bq, bk) <= step or (halves == 2 and bq != bk):
+            raise ValueError(
+                f"flash_attention: a staircase of step {step} over "
+                f"{halves} halves needs causal=True, no window, and "
+                f"sequences, blocks {bq} x {bk} and sub-tiles of whole "
+                "steps (equal blocks, longer than a step, over two halves)")
     with telemetry.phase("flash.band", kernel=kernel, seq=seq_q, bq=bq,
-                         bk=bk, sub=sub, window=window or 0,
+                         bk=bk, sub=sub, window=window or 0, step=step,
+                         halves=halves,
                          **band_tiles(seq_q, seq_k, bq, bk, sub, causal,
-                                      window)):
+                                      window, step, halves)):
         pass
     groups = {}
     for key, (classes, _) in _band_keys(
             _cdiv(seq_q, bq), _cdiv(seq_k, bk), bq, bk, seq_k, sub, causal,
-            window).items():
+            window, step, halves).items():
         groups.setdefault(classes, []).append(key)
-    return dict(sub=sub, padded=seq_k % bk != 0, groups=groups)
+    return dict(sub=sub, padded=seq_k % bk != 0, groups=groups, step=step,
+                halves=halves)
 
 
 def _strips(classes, bq, bk, sub, by_keys=False):
@@ -316,7 +382,16 @@ def _strips(classes, bq, bk, sub, by_keys=False):
     return strip, strips
 
 
-def _masks(bq, bk, sub, by_keys=False):
+def _block_first(x, step):
+    """The first position of the block of ``step`` that ``x`` lies in."""
+    if step == 1:
+        return x
+    if step & (step - 1) == 0:
+        return x & jnp.int32(-step)
+    return x - _mod(x, step)
+
+
+def _masks(bq, bk, sub, by_keys=False, step=1):
     """``mask(terms)`` for one kernel body: the [sub_q, sub_k] bool array of
     an edge sub-tile's terms ([sub_k, sub_q] with ``by_keys``, the keys
     down the rows), built once a body; ``None`` for no terms."""
@@ -327,7 +402,9 @@ def _masks(bq, bk, sub, by_keys=False):
         if terms and terms not in made:
             row = jax.lax.broadcasted_iota(jnp.int32, shape, int(by_keys))
             col = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - by_keys)
-            parts = [row - col >= c if kind == "ge" else
+            stair = _block_first(row, step) - _block_first(col, step)
+            parts = [stair >= c if kind == "ge" else
+                     stair == c if kind == "eq" else
                      row - col < c if kind == "lt" else col < c
                      for kind, c in terms]
             made[terms] = functools.reduce(jnp.logical_and, parts)
@@ -336,14 +413,15 @@ def _masks(bq, bk, sub, by_keys=False):
     return mask
 
 
-def _on_tile(groups, off, last, needed, causal, padded, body):
+def _on_tile(groups, off, last, needed, causal, padded, body, kind=None):
     """Run ``body(classes)`` for the pattern of this step's tile: every
     pattern an edge passes through under a ``pl.when`` of the few
     ``(off, last)`` that have it, the tiles inside the band under what is
-    left of ``needed``."""
+    left of ``needed``. ``kind``: the tile's quadrant over two halves."""
     def is_key(key):
         terms = ([off == key[0]] if causal else []) + (
-            [last if key[1] else jnp.logical_not(last)] if padded else [])
+            [last if key[1] else jnp.logical_not(last)] if padded else []) + (
+            [kind == key[2]] if kind is not None else [])
         return functools.reduce(jnp.logical_and, terms)
 
     inside = needed
@@ -357,6 +435,48 @@ def _on_tile(groups, off, last, needed, causal, padded, body):
     for classes in groups:
         if _all_inside(classes):
             pl.when(inside)(functools.partial(body, classes))
+
+
+def _kv_step(i, j, bq, bk, nq, nk, causal, window, step, halves):
+    """Step ``j`` of query block ``i`` on the forward / dq grid: ``(qi, kj,
+    kind, needed)``, the query and key block within their halves, the
+    tile's quadrant (``None`` with one half) and whether the step has a
+    tile. Over two halves step 0 is a noisy query block's own noisy block
+    (nothing for a clean one) and step ``j`` the clean key block ``j - 1``."""
+    if halves == 1:
+        kj = _kv_lo(i, bq, bk, window) + j
+        return i, kj, None, kj <= _kv_hi(i, bq, bk, nk, causal)
+    clean = (i >= nq).astype(jnp.int32)
+    qi = i - clean * nq
+    own = jnp.logical_and(clean == 0, j == 0)
+    kj = jnp.where(own, qi, j - 1)
+    needed = jnp.logical_or(own, jnp.logical_and(
+        j >= 1, kj <= _kv_hi(qi, bq, bk, nk, True, (1 - clean) * step)))
+    return qi, kj, jnp.where(own, _OWN, 1 - clean), needed
+
+
+def _q_step(kjg, s, bq, bk, nq, nk, causal, window, step, halves):
+    """Step ``s`` (of one query head) of key block ``kjg`` on the dkv grid:
+    ``(query block in the array, qi, kj, kind, needed)``, clamped to the
+    last step that has a tile. Over two halves a clean key block meets the
+    noisy query blocks that see it, then the clean ones; a noisy key block
+    its own query block alone."""
+    if halves == 1:
+        qi = _q_lo(kjg, bq, bk, causal) + s
+        hi = _q_hi(kjg, bq, bk, nq, window)
+        return jnp.minimum(qi, hi), qi, kjg, None, qi <= hi
+    clean = (kjg >= nk).astype(jnp.int32)
+    kj = kjg - clean * nk
+    lo_noisy, lo_clean = _q_lo(kj, bq, bk, True, step), \
+        _q_lo(kj, bq, bk, True)
+    noisy, both = nq - lo_noisy, 2 * nq - lo_noisy - lo_clean
+    at = jnp.minimum(s, both - 1)
+    by_noisy = (at < noisy).astype(jnp.int32)
+    qi = jnp.where(clean == 1, jnp.where(
+        by_noisy == 1, lo_noisy + at, lo_clean + at - noisy), kj)
+    kind = jnp.where(clean == 1, by_noisy, _OWN)   # _BEFORE is 1
+    needed = jnp.where(clean == 1, s < both, s == 0)
+    return qi + clean * (1 - by_noisy) * nq, qi, kj, kind, needed
 
 
 # --------------------------------------------------------------------------
@@ -444,13 +564,14 @@ def _probs(a, b, lse, scale, mask):
     return p if mask is None else jnp.where(mask, p, 0.0)
 
 
-def _fwd_kernel(*refs, scale, causal, window, bq, bk, nk, steps, sub, groups,
-                padded, extras):
+def _fwd_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps, sub,
+                groups, padded, extras, step, halves):
     (q_ref, k_ref, v_ref), rope, gate, rest = _split_refs(refs, 3, extras)
     o_ref, lse_ref, acc, m_scr, l_scr = rest[:5]
     q_scr = rest[5] if rope is not None else None
-    qi, j = pl.program_id(1), pl.program_id(2)
-    kj = _kv_lo(qi, bq, bk, window) + j
+    j = pl.program_id(2)
+    qi, kj, kind, needed = _kv_step(pl.program_id(1), j, bq, bk, nq, nk,
+                                    causal, window, step, halves)
 
     @pl.when(j == 0)
     def _init():
@@ -464,7 +585,7 @@ def _fwd_kernel(*refs, scale, causal, window, bq, bk, nk, steps, sub, groups,
         # a strip of queries keeps one running maximum over its pieces;
         # matmul operands per the _mxu policy, products accumulate f32
         height, strips = _strips(classes, bq, bk, sub)
-        mask = _masks(bq, bk, sub)
+        mask = _masks(bq, bk, sub, step=step)
         for r0, pieces in strips:
             rows = slice(r0, r0 + height)
             q = _mxu(q_ref[0, rows, :] if rope is None else q_scr[rows, :])
@@ -490,8 +611,8 @@ def _fwd_kernel(*refs, scale, causal, window, bq, bk, nk, steps, sub, groups,
             m_scr[rows, :] = jnp.broadcast_to(m_new, (height, _LANES))
             l_scr[rows, :] = jnp.broadcast_to(l_new, (height, _LANES))
 
-    _on_tile(groups, qi * bq - kj * bk, kj == nk - 1,
-             kj <= _kv_hi(qi, bq, bk, nk, causal), causal, padded, body)
+    _on_tile(groups, qi * bq - kj * bk, kj == nk - 1, needed, causal,
+             padded, body, kind)
 
     @pl.when(j == steps - 1)
     def _finalize():
@@ -504,32 +625,58 @@ def _fwd_kernel(*refs, scale, causal, window, bq, bk, nk, steps, sub, groups,
         lse_ref[0] = (m_scr[:, :1] + jnp.log(l_safe)).astype(jnp.float32)
 
 
-def _kv_block_map(hq, hkv, bq, bk, nk, causal, window, packed):
+def _kv_block_map(hq, hkv, bq, bk, nq, nk, causal, window, packed, step=1,
+                  halves=1):
     """Index map of a key/value block on a (row, query block, step) grid:
     the step's key block, clamped to the last one needed so that a skipped
-    step fetches nothing new."""
+    step fetches nothing new. Over two halves the clean keys follow the
+    noisy ones in the array, and a clean query block's idle step 0 stays
+    on the block of its step 1."""
     def index(b, i, j):
-        kj = jnp.minimum(_kv_lo(i, bq, bk, window) + j,
-                         _kv_hi(i, bq, bk, nk, causal))
+        if halves == 1:
+            kj = jnp.minimum(_kv_lo(i, bq, bk, window) + j,
+                             _kv_hi(i, bq, bk, nk, causal))
+        else:
+            qi, _, kind, _ = _kv_step(i, j, bq, bk, nq, nk, causal, window,
+                                      step, halves)
+            kj = jnp.where(kind == _OWN, qi, nk + jnp.minimum(
+                jnp.maximum(j - 1, 0), _kv_hi(
+                    qi, bq, bk, nk, True, jnp.where(kind == _CLEAN, 0, step))))
         return _kv_index(_kv_head(b, hq, hkv), kj, hkv, packed)
+
+    return index
+
+
+def _table_map(nq, halves, block):
+    """Index map of the rotary tables' block: ``block(*grid indices)`` is
+    the query block in the array, and both halves have the positions of
+    one."""
+    def index(*at):
+        qi = block(*at)
+        return (qi if halves == 1 else _mod(qi, nq), 0)
 
     return index
 
 
 def _flash_fwd_padded(q, k, v, *, scale, causal, window, hq, hkv, bq, bk,
                       seq_q, seq_k, interpret, packed=False, rope=None,
-                      gate=None):
+                      gate=None, step=1, halves=1):
+    """``seq_q`` / ``seq_k`` and the blocks counted below are a half's;
+    the arrays and the grid hold ``halves`` of them."""
     d = q.shape[2] // hq if packed else q.shape[2]
     bh = q.shape[0] * hq if packed else q.shape[0]
     sq = q.shape[1]
-    nq, nk = sq // bq, k.shape[1] // bk
-    steps = _kv_steps(bq, bk, nk, causal, window)
+    nq, nk = sq // bq // halves, k.shape[1] // bk // halves
+    # over two halves one step more: a noisy query block's own block
+    steps = _kv_steps(bq, bk, nk, causal, window) + halves - 1
     extras = (rope is not None, gate is not None)
     kern = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, window=window, bq=bq, bk=bk,
-        nk=nk, steps=steps, extras=extras,
-        **_band("flash_fwd", bq, bk, seq_q, seq_k, causal, window))
-    kv_map = _kv_block_map(hq, hkv, bq, bk, nk, causal, window, packed)
+        nq=nq, nk=nk, steps=steps, extras=extras,
+        **_band("flash_fwd", bq, bk, seq_q, seq_k, causal, window, step,
+                halves))
+    kv_map = _kv_block_map(hq, hkv, bq, bk, nq, nk, causal, window, packed,
+                           step, halves)
 
     def q_map(b, i, j):
         return _q_index(b, i, hq, packed)
@@ -539,12 +686,14 @@ def _flash_fwd_padded(q, k, v, *, scale, causal, window, hq, hkv, bq, bk,
 
     o, lse = pl.pallas_call(
         kern,
-        grid=(bh, nq, steps),
+        grid=(bh, halves * nq, steps),
         in_specs=[
             pl.BlockSpec((1, bq, d), q_map),
             pl.BlockSpec((1, bk, d), kv_map),
             pl.BlockSpec((1, bk, d), kv_map),
-        ] + _extra_specs(extras, d, lambda b, i, j: (i, 0), stat_map, bq),
+        ] + _extra_specs(extras, d, _table_map(nq, halves,
+                                               lambda b, i, j: i),
+                         stat_map, bq),
         out_specs=[
             pl.BlockSpec((1, bq, d), q_map),
             pl.BlockSpec((1, bq, 1), stat_map),
@@ -568,14 +717,15 @@ def _flash_fwd_padded(q, k, v, *, scale, causal, window, hq, hkv, bq, bk,
 # backward
 # --------------------------------------------------------------------------
 
-def _bwd_dq_kernel(*refs, scale, causal, window, bq, bk, nk, steps, sub,
-                   groups, padded, extras):
+def _bwd_dq_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps, sub,
+                   groups, padded, extras, step, halves):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), rope, gate, rest = \
         _split_refs(refs, 6, extras)
     dq_ref, dq_acc = rest[:2]
     q_scr = rest[2] if rope is not None else None
-    qi, j = pl.program_id(1), pl.program_id(2)
-    kj = _kv_lo(qi, bq, bk, window) + j
+    j = pl.program_id(2)
+    qi, kj, kind, needed = _kv_step(pl.program_id(1), j, bq, bk, nq, nk,
+                                    causal, window, step, halves)
 
     @pl.when(j == 0)
     def _init():
@@ -585,7 +735,7 @@ def _bwd_dq_kernel(*refs, scale, causal, window, bq, bk, nk, steps, sub,
 
     def body(classes):
         height, strips = _strips(classes, bq, bk, sub)
-        mask = _masks(bq, bk, sub)
+        mask = _masks(bq, bk, sub, step=step)
         for r0, pieces in strips:
             rows = slice(r0, r0 + height)
             q = _mxu(q_ref[0, rows, :] if rope is None else q_scr[rows, :])
@@ -602,8 +752,8 @@ def _bwd_dq_kernel(*refs, scale, causal, window, bq, bk, nk, steps, sub,
                 dq = dq + _dot(ds, k, (1, 0))
             dq_acc[rows, :] = dq
 
-    _on_tile(groups, qi * bq - kj * bk, kj == nk - 1,
-             kj <= _kv_hi(qi, bq, bk, nk, causal), causal, padded, body)
+    _on_tile(groups, qi * bq - kj * bk, kj == nk - 1, needed, causal,
+             padded, body, kind)
 
     @pl.when(j == steps - 1)
     def _finalize():
@@ -612,15 +762,17 @@ def _bwd_dq_kernel(*refs, scale, causal, window, bq, bk, nk, steps, sub,
 
 
 def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps,
-                    group, sub, groups, padded, extras):
+                    group, sub, groups, padded, extras, step, halves):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), rope, gate, rest = \
         _split_refs(refs, 6, extras)
     dk_ref, dv_ref, dk_acc, dv_acc = rest
     # the inner grid dimension walks the query heads that share this
     # key-value head, and within each the query blocks that can need
     # this key block
-    kj, t = pl.program_id(1), pl.program_id(2)
-    qi = _q_lo(kj, bq, bk, causal) + _mod(t, steps)
+    t = pl.program_id(2)
+    _, qi, kj, kind, needed = _q_step(pl.program_id(1), _mod(t, steps), bq,
+                                      bk, nq, nk, causal, window, step,
+                                      halves)
 
     @pl.when(t == 0)
     def _init():
@@ -631,7 +783,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps,
         # strips of keys, each over the pieces of queries it needs, the
         # keys down the rows of every product: nothing is transposed
         width, strips = _strips(classes, bq, bk, sub, by_keys=True)
-        mask = _masks(bq, bk, sub, by_keys=True)
+        mask = _masks(bq, bk, sub, by_keys=True, step=step)
         # every step has another query block: what the strips need of it
         # is rotated as it comes, once
         lo = min(r0 for _, pieces in strips for r0, _, _ in pieces)
@@ -656,8 +808,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps,
             dk_acc[cols, :] = dk
             dv_acc[cols, :] = dv
 
-    _on_tile(groups, qi * bq - kj * bk, kj == nk - 1,
-             qi <= _q_hi(kj, bq, bk, nq, window), causal, padded, body)
+    _on_tile(groups, qi * bq - kj * bk, kj == nk - 1, needed, causal,
+             padded, body, kind)
 
     @pl.when(t == group * steps - 1)
     def _finalize():
@@ -667,7 +819,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps,
 
 def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
                       hkv, bq, bk, seq_q, seq_k, interpret, packed=False,
-                      rope=None, gate=None):
+                      rope=None, gate=None, step=1, halves=1):
     """``(dq, dk, dv, delta)``. With a gate ``o`` is the gated output and
     ``delta`` (the row sums of ``do * o``) serves both the kernels and the
     gate's own gradient."""
@@ -677,7 +829,7 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
     bh = q.shape[0] * hq if packed else q.shape[0]
     bkv = k.shape[0] * hkv if packed else k.shape[0]
     sq, sk = q.shape[1], k.shape[1]
-    nq, nk = sq // bq, sk // bk
+    nq, nk = sq // bq // halves, sk // bk // halves      # a half's
     group = hq // hkv
     if packed:
         delta = jnp.sum(
@@ -688,9 +840,10 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1, keepdims=True)
 
-    kv_steps = _kv_steps(bq, bk, nk, causal, window)
-    kv_map = _kv_block_map(hq, hkv, bq, bk, nk, causal, window, packed)
-    band = (bq, bk, seq_q, seq_k, causal, window)
+    kv_steps = _kv_steps(bq, bk, nk, causal, window) + halves - 1
+    kv_map = _kv_block_map(hq, hkv, bq, bk, nq, nk, causal, window, packed,
+                           step, halves)
+    band = (bq, bk, seq_q, seq_k, causal, window, step, halves)
 
     def q_of_row(b, i, j):
         return _q_index(b, i, hq, packed)
@@ -700,9 +853,10 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          window=window, bq=bq, bk=bk, nk=nk, steps=kv_steps,
-                          extras=extras, **_band("flash_bwd_dq", *band)),
-        grid=(bh, nq, kv_steps),
+                          window=window, bq=bq, bk=bk, nq=nq, nk=nk,
+                          steps=kv_steps, extras=extras,
+                          **_band("flash_bwd_dq", *band)),
+        grid=(bh, halves * nq, kv_steps),
         in_specs=[
             pl.BlockSpec((1, bq, d), q_of_row),
             pl.BlockSpec((1, bk, d), kv_map),
@@ -710,7 +864,9 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
             pl.BlockSpec((1, bq, d), q_of_row),
             pl.BlockSpec((1, bq, 1), stat_of_row),
             pl.BlockSpec((1, bq, 1), stat_of_row),
-        ] + _extra_specs(extras, d, lambda b, i, j: (i, 0), stat_of_row, bq),
+        ] + _extra_specs(extras, d, _table_map(nq, halves,
+                                               lambda b, i, j: i),
+                         stat_of_row, bq),
         out_specs=pl.BlockSpec((1, bq, d), q_of_row),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)]
@@ -719,7 +875,8 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
         name="flash_bwd_dq",
     )(q, k, v, do, lse, delta, *more)
 
-    q_steps = _q_steps(bq, bk, nq, causal, window)
+    # over two halves a clean key block meets both halves' query blocks
+    q_steps = halves * _q_steps(bq, bk, nq, causal, window)
 
     def q_row(b, j, t):
         # row of the flattened (batch x query heads) and query block of
@@ -727,9 +884,8 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
         # block clamped to the last one needed
         head = b if hq == hkv else \
             _div(b, hkv) * hq + _mod(b, hkv) * group + _div(t, q_steps)
-        qi = jnp.minimum(_q_lo(j, bq, bk, causal) + _mod(t, q_steps),
-                         _q_hi(j, bq, bk, nq, window))
-        return head, qi
+        return head, _q_step(j, _mod(t, q_steps), bq, bk, nq, nk, causal,
+                             window, step, halves)[0]
 
     def q_map(b, j, t):
         return _q_index(*q_row(b, j, t), hq, packed)
@@ -743,7 +899,7 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
     def stat_rows(x):
         # a query block's statistics along the lanes, as flash_bwd_dkv
         # reads them: the same bytes
-        return x.reshape(bh, nq, 1, bq)
+        return x.reshape(bh, halves * nq, 1, bq)
 
     def kv_of_row(b, j, t):
         return _kv_index(b, j, hkv, packed)
@@ -753,7 +909,7 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
                           window=window, bq=bq, bk=bk, nq=nq, nk=nk,
                           steps=q_steps, group=group, extras=extras,
                           **_band("flash_bwd_dkv", *band)),
-        grid=(bkv, nk, group * q_steps),
+        grid=(bkv, halves * nk, group * q_steps),
         in_specs=[
             pl.BlockSpec((1, bq, d), q_map),
             pl.BlockSpec((1, bk, d), kv_of_row),
@@ -761,8 +917,8 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
             pl.BlockSpec((1, bq, d), q_map),
             pl.BlockSpec((1, 1, 1, bq), stat_row_map),
             pl.BlockSpec((1, 1, 1, bq), stat_row_map),
-        ] + _extra_specs(extras, d, lambda b, j, t: (q_row(b, j, t)[1], 0),
-                         stat_map, bq),
+        ] + _extra_specs(extras, d, _table_map(
+            nq, halves, lambda b, j, t: q_row(b, j, t)[1]), stat_map, bq),
         out_specs=[
             pl.BlockSpec((1, bk, d), kv_of_row),
             pl.BlockSpec((1, bk, d), kv_of_row),
@@ -793,9 +949,28 @@ def _pad_to(x, axis, mult):
     return jnp.pad(x, widths)
 
 
+def _pad_seq(x, mult, halves):
+    """``x`` (batch, halves x seq, width) with every half padded to a
+    multiple of ``mult``."""
+    if halves == 1 or x.shape[1] // halves % mult == 0:
+        return _pad_to(x, 1, mult)
+    b, rows, w = x.shape
+    return _pad_to(x.reshape(b * halves, rows // halves, w), 1,
+                   mult).reshape(b, -1, w)
+
+
+def _cut_seq(x, seq, width, halves):
+    """The first ``seq`` positions of every half and ``width`` columns."""
+    if halves == 1 or x.shape[1] == halves * seq:
+        return x[:, :halves * seq, :width]
+    b, rows, w = x.shape
+    return x.reshape(b * halves, rows // halves, w)[:, :seq, :width].reshape(
+        b, halves * seq, width)
+
+
 # static configuration of one call: (causal, window, hq, hkv, bq, bk,
-# interpret, packed), hashable so that it rides as one non-differentiable
-# argument. ``rope`` is ``None`` or ``(cos, sin, rot)`` (tables (seq, d)
+# interpret, packed, step, halves), hashable so that it rides as one
+# non-differentiable argument. ``rope`` is ``None`` or ``(cos, sin, rot)`` (tables (seq, d)
 # float32, ``rot`` (d, d)): the QUERY is rotated inside the kernels; ``gate``
 # is ``None`` or the logits (batch x heads, seq, 1) of a sigmoid gate on the
 # output, a factor a head and position.
@@ -805,8 +980,8 @@ def _flash(q, k, v, rope, gate, cfg):
 
 
 def _flash_fwd(q, k, v, rope, gate, cfg):
-    causal, window, hq, hkv, bq, bk, interpret, packed = cfg
-    sq, sk = q.shape[1], k.shape[1]
+    causal, window, hq, hkv, bq, bk, interpret, packed, step, halves = cfg
+    sq, sk = q.shape[1] // halves, k.shape[1] // halves      # a half's
     d = q.shape[2] // hq if packed else q.shape[2]
     scale = 1.0 / (d ** 0.5)
     # Blocks span the full head_dim, so any d equal to the array dim lowers
@@ -814,9 +989,9 @@ def _flash_fwd(q, k, v, rope, gate, cfg):
     # Only round tiny/odd head dims up to a sublane multiple (packed heads
     # are whole lane tiles already).
     dm = 1 if packed else 8 if d >= 8 else d
-    qp = _pad_to(_pad_to(q, 2, dm), 1, bq)
-    kp = _pad_to(_pad_to(k, 2, dm), 1, bk)
-    vp = _pad_to(_pad_to(v, 2, dm), 1, bk)
+    qp = _pad_seq(_pad_to(q, 2, dm), bq, halves)
+    kp = _pad_seq(_pad_to(k, 2, dm), bk, halves)
+    vp = _pad_seq(_pad_to(v, 2, dm), bk, halves)
     ropep = None if rope is None else (
         _pad_to(rope[0], 0, bq), _pad_to(rope[1], 0, bq),
         rope[2].astype(q.dtype))
@@ -826,30 +1001,35 @@ def _flash_fwd(q, k, v, rope, gate, cfg):
                                window=window, hq=hq, hkv=hkv, bq=bq, bk=bk,
                                seq_q=sq, seq_k=sk,
                                interpret=interpret, packed=packed,
-                               rope=ropep, gate=factor)
+                               rope=ropep, gate=factor, step=step,
+                               halves=halves)
     # under a recomputation segment (executor._remat_segments) the output
     # and the row statistics are kept: recomputing them is this kernel again
     o = checkpoint_name(o, REMAT_KEEP)
     lse = checkpoint_name(lse, REMAT_KEEP)
-    return o[:, :sq, :q.shape[2]], (qp, kp, vp, o, lse, ropep, factor, rope,
-                                     gate, scale, sq, sk, q.shape[2])
+    return _cut_seq(o, sq, q.shape[2], halves), (
+        qp, kp, vp, o, lse, ropep, factor, rope, gate, scale, sq, sk,
+        q.shape[2])
 
 
 def _flash_bwd(cfg, res, g):
-    causal, window, hq, hkv, bq, bk, interpret, packed = cfg
+    causal, window, hq, hkv, bq, bk, interpret, packed, step, halves = cfg
     qp, kp, vp, o, lse, ropep, factor, rope, gate, scale, sq, sk, d = res
-    gp = _pad_to(_pad_to(g, 2, qp.shape[-1]), 1, bq)  # match residual padding
+    # match residual padding
+    gp = _pad_seq(_pad_to(g, 2, qp.shape[-1]), bq, halves)
     dq, dk, dv, delta = _flash_bwd_padded(
         qp, kp, vp, o, lse, gp, scale=scale, causal=causal, window=window,
         hq=hq, hkv=hkv, bq=bq, bk=bk, seq_q=sq, seq_k=sk,
-        interpret=interpret, packed=packed, rope=ropep, gate=factor)
+        interpret=interpret, packed=packed, rope=ropep, gate=factor,
+        step=step, halves=halves)
     dkv = kp.shape[2] if packed else d
     d_rope = None if rope is None else tuple(jnp.zeros_like(a) for a in rope)
     # o is the gated output: sum(do * o) = s * sum(do * ungated), and the
     # sigmoid's slope is s (1 - s)
     d_gate = None if gate is None else \
         (delta * (1.0 - factor))[:, :sq].astype(gate.dtype)
-    return dq[:, :sq, :d], dk[:, :sk, :dkv], dv[:, :sk, :dkv], d_rope, d_gate
+    return _cut_seq(dq, sq, d, halves), _cut_seq(dk, sk, dkv, halves), \
+        _cut_seq(dv, sk, dkv, halves), d_rope, d_gate
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -892,7 +1072,7 @@ def flash_attention_with_lse(q, k, v, causal=False, block_q=512,
     o, res = _flash_fwd(q.reshape(b * h, sq, d),
                         k.reshape(b * h, k.shape[2], d),
                         v.reshape(b * h, v.shape[2], d), None, None,
-                        (causal, None, h, h, bq, bk, interpret, False))
+                        (causal, None, h, h, bq, bk, interpret, False, 1, 1))
     lse = res[4][:, :sq, 0]
     return o.reshape(b, h, sq, d), lse.reshape(b, h, sq)
 
@@ -955,7 +1135,7 @@ def rotary_tables(seq, head_dim, inv_freq, attention_factor=1.0):
 
 def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
                     interpret=None, window=None, heads_last=False,
-                    rotary=None, gate=None):
+                    rotary=None, gate=None, step=1, halves=1):
     """Blocked flash attention. q: [batch, heads, seq, head_dim]; k, v:
     [batch, kv_heads, seq, head_dim] with ``heads`` a multiple of
     ``kv_heads`` (query head ``i`` reads key-value head ``i // (heads /
@@ -975,6 +1155,15 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
     need ``heads_last`` and whole lane tiles: what they save is the pass
     over HBM and the relayout of the widest tensor of the layer.
 
+    ``step=B`` (with ``causal=True``, no window) is the block-causal mask:
+    query ``t`` sees the keys of its own block of ``B`` positions and of
+    every block before it. ``halves=2`` is block-diffusion training: q, k
+    and v hold a sequence's noisy copy and then its clean copy (``seq`` is
+    twice a copy's length; the rotary tables are one copy's, both have its
+    positions); a clean query sees the clean keys block-causally, a noisy
+    query the clean keys of the blocks before its own and the noisy keys of
+    its own block. No gate there. Sequences and blocks are whole steps.
+
     Exact (up to fp accumulation order) match of the dense masked softmax
     attention, with O(block) VMEM footprint. Differentiable via Pallas
     backward kernels. Block shapes are chosen here from the mask
@@ -991,7 +1180,8 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
         # narrow heads cannot be cut out of the packed last axis
         o = flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                             v.transpose(0, 2, 1, 3), causal, block_q,
-                            block_k, interpret, window)
+                            block_k, interpret, window, step=step,
+                            halves=halves)
         return o.transpose(0, 2, 1, 3)
     seq_axis, head_axis = (1, 2) if heads_last else (2, 1)
     b, d = q.shape[0], q.shape[3]
@@ -1006,10 +1196,18 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
                              "and at least one key")
         if window >= sk:
             window = None   # the causal mask alone
+    if (step != 1 or halves != 1) and (
+            step < 1 or halves not in (1, 2) or gate is not None
+            or sq % halves or sk % halves):
+        raise ValueError("flash_attention: a staircase has a step of at "
+                         "least one position, one or two halves and no gate")
     chosen = _choose_blocks(causal)
-    bq = min(chosen[0] if block_q is None else block_q, max(8, sq))
-    bk = min(chosen[1] if block_k is None else block_k, max(8, sk))
-    cfg = (causal, window, hq, hkv, bq, bk, interpret, heads_last)
+    bq = min(chosen[0] if block_q is None else block_q,
+             max(8, sq // halves))
+    bk = min(chosen[1] if block_k is None else block_k,
+             max(8, sk // halves))
+    cfg = (causal, window, hq, hkv, bq, bk, interpret, heads_last, step,
+           halves)
     # pad seq blocks up so bq | sq_padded handled inside _flash_fwd
     if heads_last:
         if gate is not None:     # as the row statistics lie: a row a head

@@ -89,6 +89,27 @@ def _decoder_attention_case(heads, window, yarn):
     return build
 
 
+def _blockdiff_attention_case(S):
+    """The attention operator of block-diffusion training at the published
+    widths (``sdar_30b_a3b.blockdiff4k``): a noisy and a clean copy of
+    4,096 positions (8,192 rows), 32 heads of 128 over 4, blocks of 4, the
+    kernels rotating the queries on rows as the projections leave them.
+    Forward and both backward kernels, every quadrant's tiles."""
+    from mxnet_tpu.ops import OPS
+
+    op = OPS.create("BlockDiffusionAttention", seq_len=4096, block_length=4,
+                    num_heads=32, num_kv_heads=4, head_dim=128,
+                    rotary_dim=128, rope_theta=1e6)
+    q = S((8192, 32 * 128), jnp.bfloat16)
+    kv = S((8192, 4 * 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(op.fwd([q, k, v], [], True, None)[0][0]
+                       .astype(jnp.float32))
+
+    return jax.grad(loss, argnums=(0, 1, 2)), (q, kv, kv)
+
+
 def _padded_flash_case(causal, window):
     """The edge-tile variants that 8,192 positions never reach: 8,000
     positions of the cell's heads (128 wide, 16 over 8, rows as projected)
@@ -159,6 +180,8 @@ CASES = {
                            {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     "flash_decoder_window": (_decoder_attention_case(64, 512, False),
                              {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    "flash_blockdiff": (_blockdiff_attention_case,
+                        {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     "flash_padded_causal": (_padded_flash_case(True, None),
                             {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     "flash_padded_window": (_padded_flash_case(True, 512),
