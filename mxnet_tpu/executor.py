@@ -171,10 +171,27 @@ def _remat_segments(nodes):
     return runs
 
 
-def _build_graph_fn(symbol, is_train: bool):
-    """Compile the symbol DAG into a pure function of (args, aux, rng)."""
+def _build_graph_fn(symbol, is_train: bool, stored=None, stored_dtype=None):
+    """Compile the symbol DAG into a pure function of (args, aux, rng).
+
+    ``stored`` maps a variable's name to the axes (major to minor) in
+    which its value is handed over permuted (``FeedForward``'s stored
+    order: the order the consuming operator reads it in). Each consumer
+    takes it back to the declared order as its inputs are read, inside its
+    recomputation block and right before the operator's own transposition,
+    so the two cancel at trace level and the program reads the leaf where
+    it lies."""
     nodes = symbol._topo()
     fused_bn, passthrough, skip_bn, fused_add = _fusion_plan(symbol)
+    declared = {(id(n), 0): tuple(int(a) for a in np.argsort(stored[n.name]))
+                for n in nodes if n.is_variable and n.name in (stored or {})}
+
+    def read(env, ref):
+        value = env[ref]
+        if ref not in declared:
+            return value
+        value = value.transpose(declared[ref])
+        return value if stored_dtype is None else value.astype(stored_dtype)
 
     def node_aux_names(node):
         if id(node) in fused_add:
@@ -226,8 +243,8 @@ def _build_graph_fn(symbol, is_train: bool):
             # node_input_refs ordering contract: bn.inputs..., then z
             refs = node_input_refs(node)
             bn = fused_add[id(node)][0]
-            bn_ins = [env[r] for r in refs[:-1]]
-            z = env[refs[-1]]
+            bn_ins = [read(env, r) for r in refs[:-1]]
+            z = read(env, refs[-1])
             aux_names = node_aux_names(node)
             aux = [aux_values[a] for a in aux_names]
             outs, updated = bn.op.fwd_fused_add_relu(
@@ -236,7 +253,7 @@ def _build_graph_fn(symbol, is_train: bool):
             for a_name, a_val in zip(aux_names, updated):
                 new_aux[a_name] = a_val
             return
-        ins = [env[r] for r in node_input_refs(node)]
+        ins = [read(env, r) for r in node_input_refs(node)]
         aux_names = node_aux_names(node)
         aux = [aux_values[a] for a in aux_names]
         key = jax.random.fold_in(rng, i) if node.op.need_rng else None

@@ -576,6 +576,16 @@ class FeedForward(BASE_ESTIMATOR):
         self._opt_cache = (sig, obj)
         return obj
 
+    def _state_order(self, apply_update=True):
+        """``_stored_order`` of this model's symbol: what ``fit``,
+        ``precompile`` and every train step over the one state agree on.
+        Nothing is permuted where the optimizer runs elsewhere
+        (update-on-kvstore: the weights arrive anew every step and
+        gradients leave), nor for a model without one symbol."""
+        if not apply_update or self.symbol is None:
+            return {}
+        return _stored_order(self.symbol)
+
     def _get_train_step(self, bucket_key, data_names, label_names, optimizer,
                         mesh, metric=None, apply_update=True, guard_cfg=None,
                         pad_policy=None, compression=None, overlap_plan=None,
@@ -670,8 +680,15 @@ class FeedForward(BASE_ESTIMATOR):
         no extra collective crosses the wire.
         """
         symbol = symbol if symbol is not None else self.symbol
-        graph_fn = _build_graph_fn(symbol, is_train=True)
+        # the leaves the state holds with their axes permuted, and the
+        # shapes they then have: the graph takes each back to its declared
+        # order where its operator reads it, and a leaf handed over in its
+        # declared shape is refused (run), never transposed again or
+        # compiled for
+        order = self._state_order(apply_update)
         compute_dtype = self.compute_dtype
+        graph_fn = _build_graph_fn(symbol, is_train=True, stored=order,
+                                   stored_dtype=compute_dtype)
         health_groups = None
         health_heads = ()
         if health_cfg is not None:
@@ -702,6 +719,19 @@ class FeedForward(BASE_ESTIMATOR):
                     continue
                 total = lv if total is None else total + lv
             return total
+        stored_shapes = {
+            k: tuple(self.arg_params[k].shape[a] for a in axes)
+            for k, axes in order.items()}
+
+        def check_stored(params):
+            for k, shape in stored_shapes.items():
+                if tuple(params[k].shape) != shape:
+                    raise MXNetError(
+                        f"train step: parameter {k!r} arrived as "
+                        f"{tuple(params[k].shape)}; the step's state holds "
+                        f"it in its stored order {order[k]}, as {shape} "
+                        "(FeedForward._state_order)")
+
         comm_spec = compression if mesh is not None else None
         in_shard = comm_spec is not None  # compute body runs inside shard_map
         axis_size = int(mesh.shape["dp"]) if mesh is not None else 1
@@ -727,7 +757,8 @@ class FeedForward(BASE_ESTIMATOR):
             def loss_fn(p):
                 if compute_dtype is not None:
                     p_c = {k: (v.astype(compute_dtype)
-                               if jnp.issubdtype(v.dtype, jnp.floating) else v)
+                               if jnp.issubdtype(v.dtype, jnp.floating)
+                               and k not in order else v)
                            for k, v in p.items()}
                     # floating data only: integer ids above 256 are not
                     # whole numbers in bfloat16 (predict and eval do the same)
@@ -879,7 +910,8 @@ class FeedForward(BASE_ESTIMATOR):
         if in_shard:
             return self._finish_sharded_step(
                 compute, mesh, comm_spec, axis_size, guard_cfg, has_cstate,
-                padded, label, overlap_plan=overlap_plan, has_health=has_h)
+                padded, label, overlap_plan=overlap_plan, has_health=has_h,
+                check_stored=check_stored)
 
         def step(params, opt_state, aux, batch, rng, lr, mstate, *rest):
             i = 0
@@ -913,6 +945,7 @@ class FeedForward(BASE_ESTIMATOR):
                                              donate_argnums=donate)
 
             def run(params, opt_state, aux, batch, rng, lr, mstate, *rest):
+                check_stored(params)
                 batch = {k: _to_dev(v, dev) for k, v in batch.items()}
                 params = {k: _to_dev(v, dev) for k, v in params.items()}
                 aux = {k: _to_dev(v, dev) for k, v in aux.items()}
@@ -944,6 +977,7 @@ class FeedForward(BASE_ESTIMATOR):
                                          donate_argnums=donate)
 
         def run(params, opt_state, aux, batch, rng, lr, mstate, *rest):
+            check_stored(params)
             batch = {k: _place(v, batch_sh if np.ndim(v) else repl)
                      for k, v in batch.items()}
             if _needs_place(params, mesh):
@@ -966,7 +1000,8 @@ class FeedForward(BASE_ESTIMATOR):
 
     def _finish_sharded_step(self, compute, mesh, comm_spec, axis_size,
                              guard_cfg, has_cstate, padded, label,
-                             overlap_plan=None, has_health=False):
+                             overlap_plan=None, has_health=False,
+                             check_stored=lambda params: None):
         """Assemble the compressed-comm train step: ``jit(shard_map(...))``
         over the dp axis (see _build_train_step's compression note).
 
@@ -1033,6 +1068,7 @@ class FeedForward(BASE_ESTIMATOR):
                         comm_mod.flat_size(params), axis_size, comm_spec))
                 plan_state["registered"] = True
             reg.record_step(label)
+            check_stored(params)
             batch = {k: _place(v, batch_sh if np.ndim(v) else repl)
                      for k, v in batch.items()}
             place_repl = lambda t: (jax.tree_util.tree_map(  # noqa: E731
@@ -1484,9 +1520,14 @@ class FeedForward(BASE_ESTIMATOR):
         # device-resident training state (f32 master params). dist_async
         # keeps NO worker-side optimizer state: the server owns it
         # (update-on-kvstore), so a momentum tree here would be dead HBM.
+        # the leaves an operator reads in another order than the declared
+        # one live on the device in that order from here to the write-back
+        # (_stored_order); everything that leaves the loop (arg_params,
+        # checkpoints, evaluation) sees the declared shapes
+        order = self._state_order(apply_update=not async_kv)
         with telemetry_mod.phase("setup.place_state"):
-            params = {k: jnp.asarray(self.arg_params[k].asnumpy())
-                      for k in param_names}
+            params = _reorder({k: jnp.asarray(self.arg_params[k].asnumpy())
+                               for k in param_names}, order)
             aux = {k: jnp.asarray(self.aux_params[k].asnumpy())
                    for k in aux_names}
             opt_state = {} if async_kv else optimizer.init_state_tree(params)
@@ -1495,9 +1536,18 @@ class FeedForward(BASE_ESTIMATOR):
                 # through this optimizer's state structure
                 flat, treedef = jax.tree_util.tree_flatten(opt_state)
                 if len(flat) == len(resume_opt_leaves):
-                    opt_state = jax.tree_util.tree_unflatten(
-                        treedef,
-                        [jnp.asarray(leaf) for leaf in resume_opt_leaves])
+                    saved = jax.tree_util.tree_unflatten(
+                        treedef, resume_opt_leaves)
+                    opt_state = _reorder(jax.tree_util.tree_map(
+                        jnp.asarray, saved), order)
+            # how often the mechanism engages: the parameter and
+            # optimizer-state leaves that live in another order than the
+            # declared one, and their bytes
+            moved = [x for k in order for x in jax.tree_util.tree_leaves(
+                (params[k], opt_state.get(k))) if x.ndim == len(order[k])]
+            self._fit_start.attrs.update(
+                state_leaves_relaid=len(moved),
+                state_bytes_relaid=sum(x.nbytes for x in moved))
         # One compiled step per bucket key (None = the single-symbol case);
         # all entries share the same live param/opt-state pytrees. The
         # programs live in self._train_fns so precompile() warms the exact
@@ -1781,6 +1831,14 @@ class FeedForward(BASE_ESTIMATOR):
             # other processes' devices (jax.distributed) cannot join and
             # gives this process's rows by itself. All copies are complete
             # on return: the next step donates `params`.
+            #
+            # A parameter kept in a stored order is fetched and landed as
+            # it lies (a plain array: it joins the batch at the batch's
+            # speed and takes no room on the chip) and goes back to its
+            # declared order on the cpu device, where the transposition is
+            # XLA's and runs on every core; as a strided numpy view it
+            # would be landed by one thread (a v5e's host: 1.3 s for
+            # 1.6 GB against 0.39 s for the plain copy).
             leaves = [params[k] for k in param_names] \
                 + [aux[k] for k in aux_names]
             joins = [x.is_fully_addressable for x in leaves]
@@ -1796,10 +1854,22 @@ class FeedForward(BASE_ESTIMATOR):
                 # initializer's do. predict/score hand them to jit beside a
                 # batch committed to ctx, which refuses a committed cpu array
                 with jax.default_device(cpu().jax_device):
-                    landed = [NDArray(v) for v in jax.device_put(values)]
-                n = len(param_names)
-                self.arg_params.update(zip(param_names, landed[:n]))
-                self.aux_params.update(zip(aux_names, landed[n:]))
+                    n = len(param_names)
+                    landed = jax.device_put(values)
+                    declared = _reorder(dict(zip(param_names, landed[:n])),
+                                        order, back=True)
+                self.arg_params.update(
+                    (k, NDArray(v)) for k, v in declared.items())
+                self.aux_params.update(
+                    (k, NDArray(v)) for k, v in zip(aux_names, landed[n:]))
+
+        def _declared_state():
+            """``(params, opt_state)`` in their declared order, for what
+            leaves the loop (checkpoints): the live trees themselves where
+            nothing is stored otherwise, device transposes of the stored
+            leaves where something is."""
+            return (_reorder(params, order, back=True),
+                    _reorder(opt_state, order, back=True))
 
         # an operator may have a line to say an epoch about its auxiliary
         # states (OpProp.epoch_record; an expert layer's load): once an
@@ -1879,9 +1949,10 @@ class FeedForward(BASE_ESTIMATOR):
                     ckpt_writer.flush()
                 comm_state, comm_meta = _comm_ckpt()
                 step_id = num_update if ckpt_every is not None else epoch
+                declared = _declared_state()
                 ckpt_plane_mod.save_now(
-                    sharded_checkpoint_dir, step_id, params, aux=aux,
-                    symbol=self.symbol, opt_state=opt_state,
+                    sharded_checkpoint_dir, step_id, declared[0], aux=aux,
+                    symbol=self.symbol, opt_state=declared[1],
                     comm_state=comm_state,
                     extra_meta={"epoch": epoch, "num_update": num_update,
                                 "preempted": True, **_resume_meta(nbatch),
@@ -1985,16 +2056,17 @@ class FeedForward(BASE_ESTIMATOR):
                     loaded, laux, _, meta, opt_leaves, comm_saved = \
                         ckpt_mod.load_resharded(sharded_checkpoint_dir,
                                                 mesh)
-                params = {k: loaded[k] for k in param_names}
+                params = _reorder({k: loaded[k] for k in param_names},
+                                  order)
                 aux = {k: laux[k] for k in aux_names}
                 opt_state = optimizer.init_state_tree(params)
                 if opt_leaves is not None:
                     flat, treedef = jax.tree_util.tree_flatten(opt_state)
                     if len(flat) == len(opt_leaves):
-                        opt_state = jax.tree_util.tree_unflatten(
+                        opt_state = _reorder(jax.tree_util.tree_unflatten(
                             treedef,
                             [jnp.asarray(np.asarray(leaf))
-                             for leaf in opt_leaves])
+                             for leaf in opt_leaves]), order)
                 num_update = int(meta.get("num_update", num_update))
                 # step-granular resume (ISSUE 17): a mid-epoch snapshot
                 # fast-forwards the redone epoch past the batches it
@@ -2141,8 +2213,9 @@ class FeedForward(BASE_ESTIMATOR):
             the ``ckpt.replica`` chaos site fires (the mid-replication
             kill of the acceptance test)."""
             comm_state, comm_meta = _comm_ckpt()
+            declared = _declared_state()
             snap = ckpt_plane_mod.capture_snapshot(
-                num_update, params, aux=aux, opt_state=opt_state,
+                num_update, declared[0], aux=aux, opt_state=declared[1],
                 comm_state=comm_state,
                 meta={"epoch": epoch, "num_update": num_update,
                       **_resume_meta(nbatch), **_guard_meta(), **comm_meta},
@@ -2173,9 +2246,10 @@ class FeedForward(BASE_ESTIMATOR):
                 # persist the starting state as the floor checkpoint
                 comm_state, comm_meta = _comm_ckpt()
                 floor_id = num_update if ckpt_every is not None else epoch
+                declared = _declared_state()
                 ckpt_plane_mod.save_now(
-                    sharded_checkpoint_dir, floor_id, params, aux=aux,
-                    symbol=self.symbol, opt_state=opt_state,
+                    sharded_checkpoint_dir, floor_id, declared[0],
+                    aux=aux, symbol=self.symbol, opt_state=declared[1],
                     comm_state=comm_state,
                     extra_meta={"epoch": epoch, "num_update": num_update,
                                 **_resume_meta(resume_batches_done),
@@ -2721,9 +2795,11 @@ class FeedForward(BASE_ESTIMATOR):
                         # resumed run starts the NEXT epoch from its top.
                         step_id = num_update if ckpt_every is not None \
                             else epoch + 1
+                        declared = _declared_state()
                         ckpt_plane_mod.save_now(
-                            sharded_checkpoint_dir, step_id, params, aux=aux,
-                            symbol=self.symbol, opt_state=opt_state,
+                            sharded_checkpoint_dir, step_id, declared[0],
+                            aux=aux, symbol=self.symbol,
+                            opt_state=declared[1],
                             comm_state=comm_state,
                             extra_meta={"epoch": epoch + 1,
                                         "num_update": num_update,
@@ -2770,7 +2846,11 @@ class FeedForward(BASE_ESTIMATOR):
                             eval_data[0], eval_data[1], batch_size,
                             is_train=False) \
                             if isinstance(eval_data, tuple) else eval_data
-                        self._eval(eval_iter, eval_metric, params, aux,
+                        # the evaluation program is the declared graph:
+                        # it gets the leaves in their declared order
+                        # (device transposes, dropped after the pass)
+                        self._eval(eval_iter, eval_metric,
+                                   _reorder(params, order, back=True), aux,
                                    data_names, label_names)
                         name, value = eval_metric.get()
                         logger.info("Epoch[%d] Validation-%s=%f", epoch, name,
@@ -2960,7 +3040,10 @@ class FeedForward(BASE_ESTIMATOR):
             sh = NamedSharding(mesh, P("dp") if sharded else P())
             return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
 
-        params_s = {k: _sds(tuple(self.arg_params[k].shape),
+        order = self._state_order()
+        params_s = {k: _sds(tuple(self.arg_params[k].shape[a]
+                                  for a in order.get(
+                                      k, range(self.arg_params[k].ndim))),
                             self.arg_params[k].dtype) for k in param_names}
         aux_s = {k: _sds(tuple(self.aux_params[k].shape),
                          self.aux_params[k].dtype) for k in aux_names}
@@ -3476,3 +3559,47 @@ def _needs_place(tree, mesh):
     first = leaves[0]
     return not (hasattr(first, "sharding") and
                 getattr(first.sharding, "mesh", None) is mesh)
+
+
+def _stored_order(symbol):
+    """``{variable: axes, major to minor}``: the learnable arguments that an
+    operator of ``symbol`` reads in another order than the declared one
+    (``OpProp.argument_major_to_minor``). The train step keeps such a leaf
+    on the device with its axes so permuted (its *stored* form; the array's
+    own layout stays the default, so nothing here depends on how a compiled
+    program is cached), and the graph takes it back to the declared order
+    where its operator reads it (``executor._build_graph_fn``): a
+    transposition that cancels against the operator's own. The choice can
+    only cost time, never change a result; a variable that two nodes want
+    in different orders stays as declared."""
+    order, clash = {}, set()
+    for node in symbol._topo():
+        if node.is_variable:
+            continue
+        wanted = node.op.argument_major_to_minor()
+        for arg, (src, _) in zip(node.op.list_arguments(), node.inputs):
+            if arg in wanted and src.is_variable:
+                axes = tuple(wanted[arg])
+                if order.setdefault(src.name, axes) != axes:
+                    clash.add(src.name)
+    return {k: v for k, v in order.items()
+            if k not in clash and v != tuple(range(len(v)))}
+
+
+def _reorder(tree, order, back=False):
+    """A tree keyed by parameter name (the parameters, or the optimizer's
+    state for each) with the leaves of ``order``'s parameters taken from the
+    declared axes to the stored ones, or ``back``. A state leaf follows its
+    parameter where it has the parameter's rank (a moment does, a step
+    count does not). Numpy or jax arrays; the very tree where ``order`` is
+    empty."""
+    if not order:
+        return tree
+
+    def move(x, axes):
+        if back:
+            axes = tuple(int(i) for i in np.argsort(axes))
+        return x.transpose(axes) if np.ndim(x) == len(axes) else x
+
+    return {k: (jax.tree_util.tree_map(lambda x: move(x, order[k]), v)
+                if k in order else v) for k, v in tree.items()}

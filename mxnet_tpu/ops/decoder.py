@@ -451,6 +451,17 @@ class MixtureOfExpertsOp(OpProp):
     def list_auxiliary_states(self):
         return ["expert_load"]
 
+    def argument_major_to_minor(self):
+        # the grouped product and its weight gradient read ``gate_weight``
+        # and ``up_weight`` with their last two axes swapped (``routed``).
+        # Kept as declared, every stacked weight and both its Adam moments
+        # were copied into that order at the top of each step and back at
+        # its end (a v5e, laguna_xs2.seq8k: 72 float32 copies of 134 MB a
+        # step, ``down_weight``'s among them, which follow the other two
+        # and go with them; stored so too, ``down_weight`` costs the
+        # block-diffusion cell 150 MB more of temporaries than it saves)
+        return {"gate_weight": (0, 2, 1), "up_weight": (0, 2, 1)}
+
     def infer_shape(self, in_shapes):
         d = self._known(in_shapes, 0)
         hidden, e, w, s = d[1], self.experts_held, self.expert_width, \

@@ -52,6 +52,15 @@ class OpProp:
                    first) and as they are now, and emits one zero-length
                    telemetry record of that name with ``epoch``, ``node``
                    and the attrs. ``fit`` knows no operator by name.
+      argument_major_to_minor() -> {argument name: axes, major to minor}
+                   the order in which the operator's arithmetic reads a
+                   learnable argument, where that is not the declared
+                   (row-major) one. The train step then keeps that leaf and
+                   its optimizer state in this order on the device, from
+                   placement to write-back, and no step copies it there and
+                   back (``FeedForward``'s stored order); the declared
+                   shape is what ``arg_params``, checkpoints and
+                   ``infer_shape`` go on showing.
     """
 
     params: dict = {}
@@ -99,6 +108,9 @@ class OpProp:
         """
         d = self._known(in_shapes, 0)
         return [d] * len(in_shapes), [d], []
+
+    def argument_major_to_minor(self):
+        return {}
 
     def _known(self, in_shapes, idx):
         s = in_shapes[idx]

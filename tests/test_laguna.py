@@ -999,6 +999,132 @@ def test_fit_emits_one_expert_load_record_a_node_and_epoch():
                 if r["name"] == "fit.epoch.expert_load"]
 
 
+# two epochs of Adam on the tiny model from one seed, as the tree before
+# PR 34 computed them (sha256 over the written-back arg_params, names in
+# order, each with its shape and bytes): pinned BEFORE the train step's
+# state moved into the order its program reads it in, which is no rounding
+ADAM_TWO_EPOCHS = \
+    "964c964ddd83b870e77e61caa01d64b3f0704602bf6bcfc438ae09d4b8fc0c92"
+
+
+def _adam_two_epochs(precompile=False):
+    """``(model, digest, fit.start's attrs)`` of the pinned run."""
+    import hashlib
+
+    from mxnet_tpu import telemetry
+
+    batch = 2
+    symbol = tiny_model(experts_held=8, first_expert=4)
+    ids, labels = _batches(np.random.RandomState(11), batch)
+    mx.random.seed(5)
+    model = mx.FeedForward(symbol, ctx=mx.cpu(), num_epoch=2,
+                           optimizer="adam", learning_rate=1e-3,
+                           initializer=mx.init.Xavier())
+    metric = mx.metric.CrossEntropy()
+    if precompile:          # NDArrayIter hands the ids over as float32
+        model.precompile(data_shapes={"data": (batch, T)},
+                         label_shapes={"softmax_label": (batch, T)},
+                         eval_metric=metric)
+    mark = len(telemetry.span_records())
+    model.fit(mx.io.NDArrayIter(ids, labels, batch_size=batch),
+              eval_metric=metric, batch_size=batch)
+    start = [r for r in telemetry.span_records()[mark:]
+             if r["name"] == "fit.start"]
+    h = hashlib.sha256()
+    for k in sorted(model.arg_params):
+        a = model.arg_params[k].asnumpy()
+        h.update(k.encode())
+        h.update(str(a.shape).encode())
+        h.update(np.ascontiguousarray(a).tobytes())
+    return model, h.hexdigest(), start[0]["attrs"]
+
+
+@pytest.mark.parametrize("precompile", [False, True],
+                         ids=["compiled_at_the_first_step", "precompiled"])
+def test_two_epochs_of_adam_are_bit_equal_to_the_declared_order(precompile):
+    """The order a leaf is stored in is no rounding: with the stacked
+    expert weights and their moments kept on the device as the grouped
+    product reads them, ``fit`` writes back what the tree before PR 34
+    wrote, in the declared shapes, from one train program; ``fit.start``
+    says how many leaves live in another order than declared, and their
+    bytes."""
+    model, digest, attrs = _adam_two_epochs(precompile)
+    assert digest == ADAM_TWO_EPOCHS
+    shapes, _, _ = model.symbol.infer_shape(data=(2, T),
+                                            softmax_label=(2, T))
+    for name, shape in zip(model.symbol.list_arguments(), shapes):
+        if name in model.arg_params:
+            a = model.arg_params[name].asnumpy()
+            assert a.shape == tuple(shape) and a.flags.c_contiguous, name
+    stored = sorted(k for k in model.arg_params
+                    if k.endswith(("moe_gate_weight", "moe_up_weight")))
+    assert model._state_order() == dict.fromkeys(stored, (0, 2, 1))
+    assert len(stored) == 6             # three sparse layers, two each
+    # each weight and its two moments, float32
+    assert (attrs["state_leaves_relaid"], attrs["state_bytes_relaid"]) == (
+        18, 3 * sum(model.arg_params[k].asnumpy().nbytes for k in stored))
+    (run,) = model._train_fns.values()
+    tracked = run._tracked
+    assert tracked.aot_programs + tracked._cache_size() == 1
+
+
+def test_a_state_leaf_in_its_declared_shape_is_refused_by_the_step():
+    """``fit``'s step holds the stacked leaves in their stored order: one
+    handed over as declared raises (also where the two shapes are the
+    same length, which a program could not tell apart), and no second
+    train program is compiled for it."""
+    model, _, _ = _adam_two_epochs(precompile=True)
+    (run,) = model._train_fns.values()
+    order = model._state_order()
+    names = [k for k in model.symbol.list_arguments()
+             if k in model.arg_params]
+    optimizer = model._resolve_optimizer(names, 2)
+    ids, labels = _batches(np.random.RandomState(11), 2)
+
+    def step(params):
+        return run(params, optimizer.init_state_tree(params),
+                   {k: v.asnumpy() for k, v in model.aux_params.items()},
+                   {"data": ids[:2].astype(np.float32),
+                    "softmax_label": labels[:2].astype(np.float32)},
+                   mx.random.next_key(), 1e-3,
+                   mx.metric.CrossEntropy().device_init())
+
+    declared = {k: jnp.asarray(model.arg_params[k].asnumpy())
+                for k in names}
+    stored = {k: v.transpose(order[k]) if k in order else v
+              for k, v in declared.items()}
+    out = step(stored)
+    assert all(out[0][k].shape == stored[k].shape for k in names)
+    with pytest.raises(mx.MXNetError, match="stored order"):
+        step(declared)
+    assert run._tracked.aot_programs == 1
+    assert run._tracked._cache_size() == 0
+
+
+def test_stored_order_follows_the_operators_of_the_symbol():
+    """``_stored_order`` reads what the operators declare: a model with no
+    such operator stores nothing otherwise, a variable two nodes share is
+    stored once, and one they want in different orders stays as
+    declared."""
+    from mxnet_tpu import model as model_mod
+
+    assert model_mod._stored_order(mx.models.mlp()) == {}
+    shared = mx.sym.Variable("shared")
+    moe = dict(num_experts=4, experts_held=4, top_k=2, expert_width=8,
+               gate_weight=shared)
+    symbol = mx.sym.MixtureOfExperts(
+        data=mx.sym.MixtureOfExperts(data=mx.sym.Variable("data"),
+                                     name="a", **moe), name="b", **moe)
+    stacked = {"shared", "a_up_weight", "b_up_weight"}
+    assert model_mod._stored_order(symbol) == dict.fromkeys(stacked,
+                                                            (0, 2, 1))
+    last = [n for n in symbol._topo() if n.name == "b"][0]
+    last.op.argument_major_to_minor = lambda: {
+        "gate_weight": (1, 0, 2), "up_weight": (0, 2, 1)}
+    assert model_mod._stored_order(symbol) == dict.fromkeys(
+        stacked - {"shared"}, (0, 2, 1))
+
+
 def test_fit_asks_any_operator_with_an_epoch_record(monkeypatch):
     """``fit`` knows no operator by name: whatever operator defines
     ``epoch_record`` gets its auxiliary states, before and after, once an

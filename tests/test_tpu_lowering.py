@@ -222,3 +222,103 @@ def test_kernel_compiles_for_v5e(v5e, case, monkeypatch):
     fn, args = build(S)
     found = _mosaic_kernels(fn, *args)
     assert names <= found, (case, names - found)
+
+
+# -- the train step's state in the order its program reads it ---------------------
+
+def _two_expert_layers():
+    """Two ``MixtureOfExperts`` layers of 8 experts of 512 on rows of
+    1,024 (stacked leaves of (8, 512, 1024) and (8, 1024, 512)), each
+    behind a recomputation boundary as in the decoders, Adam, bfloat16
+    compute: the shapes are small, the program's form is the cells'."""
+    from mxnet_tpu import symbol as sym
+
+    x = sym.Variable("data")
+    for l in range(2):
+        y = sym.MixtureOfExperts(
+            data=sym.RMSNorm(data=x, eps=1e-6, name=f"layer{l}_norm"),
+            name=f"layer{l}_moe", num_experts=8, experts_held=8, top_k=2,
+            expert_width=512, train_router=False)
+        x = sym.RematBoundary(
+            data=sym._Plus(lhs=x, rhs=y, name=f"layer{l}_add"),
+            name=f"layer{l}_out")
+    head = sym.FullyConnected(data=x, num_hidden=1024, no_bias=True,
+                              name="head")
+    model = mx.FeedForward(sym.SoftmaxOutput(data=head, name="softmax"),
+                           ctx=mx.cpu(), optimizer="adam",
+                           learning_rate=1e-5, compute_dtype="bfloat16")
+    return model, {"data": (2048, 1024), "softmax_label": (2048,)}
+
+
+def _small_convnet():
+    """A bottleneck network of two stages on 64 x 64 images, NHWC,
+    bfloat16, SGD with momentum: 3 x 3 weights and their momentum, as the
+    convnet cells hold them."""
+    model = mx.FeedForward(
+        mx.models.resnet((1, 1), num_classes=16, filter_list=(64, 128),
+                         layout="NHWC"),
+        ctx=mx.cpu(), optimizer="sgd", learning_rate=1e-3, momentum=0.9,
+        compute_dtype="bfloat16")
+    return model, {"data": (32, 64, 64, 3), "softmax_label": (32,)}
+
+
+STATE_CASES = {"expert_layers": _two_expert_layers,
+               "convnet": _small_convnet}
+
+
+@pytest.mark.parametrize("case", sorted(STATE_CASES))
+def test_train_step_reads_its_state_where_it_lies(v5e, case, monkeypatch):
+    """``FeedForward``'s own step builder, compiled for v5e: the stacked
+    expert weights and their Adam moments cross the jit boundary in the
+    order the grouped product reads them (``_stored_order``; the arrays'
+    layouts stay the default), so the optimized HLO holds no float32
+    ``copy`` of a stacked ``params[...]`` / ``opt_state[...]`` leaf (they
+    were 12 a stacked matrix and step), every parameter and state leaf
+    comes back in the layout it went in with, and one train program
+    exists. A convnet's leaves are stored as declared."""
+    import re
+
+    monkeypatch.setenv("MXNET_TPU_PALLAS_INTERPRET", "0")
+    model, shapes = STATE_CASES[case]()
+    names, aux_names = model._init_params(shapes)
+    optimizer = model._resolve_optimizer(names, shapes["data"][0])
+    metric = mx.metric.create("ce")
+    run = model._get_train_step(None, ["data"], ["softmax_label"],
+                                optimizer, None, metric=metric)
+    order = model._state_order()
+
+    def S(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=v5e)
+
+    params = {k: S([model.arg_params[k].shape[a] for a in
+                    order.get(k, range(model.arg_params[k].ndim))])
+              for k in names}
+    aux = {k: S(model.aux_params[k].shape) for k in aux_names}
+    opt_state = jax.tree_util.tree_map(
+        lambda s: S(s.shape, s.dtype),
+        jax.eval_shape(optimizer.init_state_tree, params))
+    args = (params, opt_state, aux,
+            {k: S(s) for k, s in shapes.items()}, S((2,), jnp.uint32),
+            S(()), jax.tree_util.tree_map(lambda x: S(x.shape, x.dtype),
+                                          metric.device_init()))
+    tracked = run._tracked
+    compiled = tracked.precompile(*args)
+    ins, outs = compiled.input_formats[0], compiled.output_formats
+    assert ins[0] == outs[0] and ins[1] == outs[1]
+    copies = [line for line in compiled.as_text().splitlines()
+              if re.search(r"= f32\[\d+,\d+,\d+\]\{[^}]*\} copy\(", line)
+              and re.search(r"params\W|opt_state\W", line)]
+    assert not copies, copies[:3]
+    if case == "expert_layers":
+        # gate and up of both layers; down follows them by itself
+        assert sorted(order) == sorted(
+            k for k in names if k.endswith(("gate_weight", "up_weight")))
+        assert len(order) == 4 and all(
+            params[k].shape == (8, 1024, 512) for k in order)
+    else:
+        assert not order
+    # a second warm-up finds the program there; a second fit, the one handle
+    tracked.precompile(*args)
+    assert tracked.aot_programs == 1 and len(model._train_fns) == 1
+    assert run is model._get_train_step(None, ["data"], ["softmax_label"],
+                                        optimizer, None, metric=metric)
