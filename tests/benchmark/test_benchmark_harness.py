@@ -6,6 +6,7 @@ edits none: that a later PR can do the same is what is being tested.
 No TPU topology is described anywhere in this file.
 """
 
+import ast
 import importlib.util
 import json
 import os
@@ -66,18 +67,77 @@ def bench():
     return catalog.load_benchmark(ROOT)
 
 
+# -- the accepted record ----------------------------------------------------------
+
+# What the benchmark held when each of its cells was accepted, by NAME (the
+# convnets PR 23, ``laguna_xs2.seq8k`` PR 27, ``sdar_30b_a3b.blockdiff4k``
+# PR 33). A later PR appends cells, metrics and names to the lists and edits
+# none; only a ``benchmark`` PR extends this record, and a configuration's
+# own test file imports it and keeps what is its own.
+ACCEPTED_CELLS = {
+    "resnet50.device": ("resnet50", "device_ring", 1),
+    "inception_bn.device": ("inception_bn", "device_ring", 1),
+    "resnet50.dp4": ("resnet50", "device_ring_dp", 4),
+    "laguna_xs2.seq8k": ("laguna_xs2", "token_ring_8k", 1),
+    "sdar_30b_a3b.blockdiff4k": ("sdar_30b_a3b", "token_ring_blockdiff_4k",
+                                 1)}
+COMMON_METRICS = [
+    "epoch_tail_ms", "epoch_rate_median", "epoch_rate_min_over_median",
+    "step_gap_ms_p50", "precompile_s", "compiles_in_window",
+    "device_step_ms", "mfu_device", "collective_ms_per_step",
+    "collective_exposed_ms_per_step", "plan_mb", "device_idle_pct",
+    "write_back_ms", "epoch_tail_host_ms", "epoch_tail_unnamed_ms",
+    "host_step_ms_p10", "feed_wait_ms_per_step", "init_params_s",
+    "fit_start_s", "forward_ms_per_step", "backward_ms_per_step",
+    "optimizer_unfused_ms_per_step", "unscoped_ms_per_step"]
+ATTENTION_METRICS = [
+    "attention_window_ms_per_step", "attention_full_ms_per_step",
+    "attention_window_roofline_pct", "attention_full_roofline_pct"]
+MOE_METRICS = [
+    "moe_ms_per_step", "moe_grouped_roofline_pct", "moe_load_max_over_mean",
+    "moe_picks_held_per_token"]
+DECODER_METRICS = ATTENTION_METRICS + MOE_METRICS      # laguna_xs2's eight
+BLOCKDIFF_METRICS = [                                  # sdar_30b_a3b's three
+    "attention_blockdiff_ms_per_step", "attention_blockdiff_roofline_pct",
+    "blockdiff_masked_share"]
+ACCEPTED_METRICS = COMMON_METRICS + DECODER_METRICS + BLOCKDIFF_METRICS
+# the cells each accepted metric is read in, in the order they joined; a
+# metric that is not here has no ``workloads`` key: every cell reports it
+ACCEPTED_WORKLOADS = {
+    "collective_ms_per_step": ["resnet50.dp4"],
+    "collective_exposed_ms_per_step": ["resnet50.dp4"],
+    **{name: ["laguna_xs2.seq8k"] for name in ATTENTION_METRICS},
+    **{name: ["laguna_xs2.seq8k", "sdar_30b_a3b.blockdiff4k"]
+       for name in MOE_METRICS},
+    **{name: ["sdar_30b_a3b.blockdiff4k"] for name in BLOCKDIFF_METRICS}}
+ACCEPTED_BOUNDS = {"samples_per_s_per_chip": 0.01, "peak_hbm_mb": 0.01,
+                   "setup_s": 0.1}
+
+
 # -- BENCHMARK.json against its contract and against the files ---------------
 
-@pytest.fixture(scope="module", params=["repository", "later_pr"])
-def tree(request, bench):
+@pytest.fixture(scope="module",
+                params=["repository", "later_pr", "second_blockdiff"])
+def tree(request, bench, tmp_path_factory):
     """A checkout whose ``BENCHMARK.json`` and files the contract tests
-    read: the repository's own, and the tree a later PR makes of it by
-    adding files and entries (``overlay``, below)."""
+    read: the repository's own, and two trees a later PR makes of it by
+    adding files and entries: ``overlay``'s (three configurations and five
+    cells, two of them four-chip rehearsals) and the one the next
+    ``model_config`` PR makes (``_second_blockdiff``: ONE configuration,
+    one one-chip cell). ``quota``: the tree keeps to the contract's share
+    of four-chip cells."""
     if request.param == "repository":
-        return types.SimpleNamespace(root=ROOT, bench=bench, later=False)
-    root = str(request.getfixturevalue("overlay"))
-    return types.SimpleNamespace(root=root, later=True,
-                                 bench=catalog.load_benchmark(root))
+        yield types.SimpleNamespace(root=ROOT, bench=bench, quota=True)
+    elif request.param == "later_pr":
+        root = str(request.getfixturevalue("overlay"))
+        yield types.SimpleNamespace(root=root, quota=False,
+                                    bench=catalog.load_benchmark(root))
+    else:
+        root = tmp_path_factory.mktemp("bench_blockdiff")
+        before = _second_blockdiff(root, bench)
+        yield types.SimpleNamespace(root=str(root), quota=True,
+                                    bench=catalog.load_benchmark(str(root)))
+        _nothing_that_was_there_changed(before)
 
 
 def test_benchmark_json_keys_names_and_units(tree):
@@ -113,8 +173,8 @@ def test_benchmark_json_keys_names_and_units(tree):
     assert len(pairs) == len(set(pairs))
     four = [w for w in bench["workloads"] if w["chips"] == 4]
     assert all(w["chips"] in (1, 4) for w in bench["workloads"])
-    if not tree.later:      # the later tree rehearses the four-chip path
-        assert len(four) <= max(1, len(bench["workloads"]) // 4)   # twice
+    if tree.quota:          # ``overlay`` rehearses the four-chip path twice
+        assert len(four) <= max(1, len(bench["workloads"]) // 4)
     for folder in bench["paths"]:
         for base, _, files in os.walk(os.path.join(tree.root, folder)):
             if "__pycache__" in base:
@@ -162,6 +222,34 @@ def test_every_entry_has_its_file_and_they_agree(tree):
     with pytest.raises(catalog.BenchmarkError):
         catalog.peak_for("cpu", here=here)
     assert catalog.peak_for("TPU v5 lite", here=here)["bf16_flops"] == 197e12
+
+
+def test_the_accepted_cells_and_metrics_are_as_they_were(tree):
+    """Whatever came after: every accepted cell, metric and bound is found
+    by its name and is what it was accepted as; the accepted metrics head
+    ``per_layer`` in their order and the accepted names head each
+    ``workloads`` list in theirs, because a later PR appends."""
+    bench = tree.bench
+    cells = {w["name"]: w for w in bench["workloads"]}
+    assert len(cells) == len(bench["workloads"])
+    for name, (of, mix, chips) in ACCEPTED_CELLS.items():
+        assert (cells[name]["config"], cells[name]["traffic"],
+                cells[name]["chips"]) == (of, mix, chips), name
+    assert {c["name"] for c in bench["configs"]} >= {
+        of for of, _, _ in ACCEPTED_CELLS.values()}
+    assert len(ACCEPTED_METRICS) == len(set(ACCEPTED_METRICS)) == 23 + 8 + 3
+    assert [m["name"] for m in bench["per_layer"]][
+        :len(ACCEPTED_METRICS)] == ACCEPTED_METRICS
+    assert set(ACCEPTED_WORKLOADS) <= set(ACCEPTED_METRICS)
+    for m in bench["per_layer"][:len(ACCEPTED_METRICS)]:
+        accepted = ACCEPTED_WORKLOADS.get(m["name"])
+        if accepted is None:        # every cell reports it, a later one too
+            assert "workloads" not in m, m["name"]
+        else:
+            assert m["workloads"][:len(accepted)] == accepted, m["name"]
+    assert {m["name"]: m["bound"] for m in bench["end_to_end"]
+            if m["name"] in ACCEPTED_BOUNDS} == ACCEPTED_BOUNDS
+    assert bench["run_seconds"] == 24
 
 
 # -- the rate: all the window's samples over all its seconds -------------------
@@ -242,7 +330,7 @@ def test_epoch_clock_counts_whole_epochs_and_stops_at_a_boundary():
 
 # -- the FLOP count --------------------------------------------------------------
 
-def test_flops_hand_worked_layers_and_totals(bench):
+def test_flops_hand_worked_layers_and_totals(tree):
     # ResNet-50's stem by hand: 7x7 kernel, 3 -> 64 channels, 112x112
     # outputs: 7*7*3*64 = 9,408 multiply-adds an output pixel, x 12,544
     # pixels = 118,013,952 multiply-adds = 236,027,904 FLOP forward
@@ -255,8 +343,8 @@ def test_flops_hand_worked_layers_and_totals(bench):
     with pytest.raises(ValueError):
         flops.layer_forward_flops({"op": "pool"})
     totals = {}
-    for c in bench["configs"]:
-        layers = catalog.read_json(os.path.join(ROOT, c["file"]))[
+    for c in tree.bench["configs"]:
+        layers = catalog.read_json(os.path.join(tree.root, c["file"]))[
             "flops_per_sample"]["layers"]
         totals[c["name"]] = flops.train_flops_per_sample(layers) / 1e9
     # 4.09 G multiply-adds forward (v1.5) -> 8.18 GFLOP, 24.5 with backward
@@ -823,11 +911,6 @@ DECODER_CONFIG = dict(
     initializer={"name": "Xavier"}, logits="head_output",
     reference="tiny_decoder.py", reference_rows=2, reference_tolerance=1e-4,
     reduced=[])
-DECODER_METRICS = [
-    "attention_window_ms_per_step", "attention_full_ms_per_step",
-    "attention_window_roofline_pct", "attention_full_roofline_pct",
-    "moe_ms_per_step", "moe_grouped_roofline_pct", "moe_load_max_over_mean",
-    "moe_picks_held_per_token"]
 # its reference: the decoder's plain reference that is there, reading this
 # configuration's file, one head count and no shared expert
 DECODER_REFERENCE_EDITS = [
@@ -858,14 +941,24 @@ def read(run):
 '''
 
 
+def _copy_of_the_benchmark(root):
+    """``benchmark/`` as it is under ``root``, and every byte of it."""
+    here = root / "benchmark"
+    shutil.copytree(BENCH, here, ignore=shutil.ignore_patterns("__pycache__"))
+    return here, {p: p.read_bytes() for p in here.rglob("*") if p.is_file()}
+
+
+def _nothing_that_was_there_changed(before):
+    for p, content in before.items():
+        assert p.read_bytes() == content, p
+
+
 @pytest.fixture(scope="module")
 def overlay(tmp_path_factory, bench):
     """A checkout-shaped directory: ``benchmark/`` copied as it is, plus
     ADDED files and a ``BENCHMARK.json`` with added entries."""
     root = tmp_path_factory.mktemp("bench_overlay")
-    here = root / "benchmark"
-    shutil.copytree(BENCH, here, ignore=shutil.ignore_patterns("__pycache__"))
-    before = {p: p.read_bytes() for p in here.rglob("*") if p.is_file()}
+    here, before = _copy_of_the_benchmark(root)
     (here / "configs" / "tiny_resnet.json").write_text(json.dumps(TINY_CONFIG))
     reference = (here / "configs" / "resnet50.py").read_text()
     assert "UNITS = (3, 4, 6, 3)" in reference
@@ -935,8 +1028,42 @@ def overlay(tmp_path_factory, bench):
                            ("moe_experts_hit_pct", "tiny_decoder.device"))]
     (root / "BENCHMARK.json").write_text(json.dumps(added))
     yield root
-    for p, content in before.items():     # nothing that was there changed
-        assert p.read_bytes() == content, p
+    _nothing_that_was_there_changed(before)
+
+
+def _second_blockdiff(root, bench):
+    """What the next ``model_config`` PR does, as ISSUE 35 tried it by hand
+    on a copy of the repository: a SIXTH configuration by files and entries
+    alone, here a second block-diffusion decoder (``sdar_30b_a3b``'s file
+    under another name with its reference), ONE one-chip cell on the mix
+    that is there, the cell's name appended to the four ``moe_*`` lists
+    and to none of the ``attention_*`` ones, one metric of its own. At its
+    full size, so nothing runs it: the tests held on ``tree`` read it."""
+    twin, cell = "blockdiff_twin", "blockdiff_twin.blockdiff4k"
+    here, before = _copy_of_the_benchmark(root)
+    (entry,) = [c for c in bench["configs"] if c["name"] == "sdar_30b_a3b"]
+    config = catalog.read_json(os.path.join(ROOT, entry["file"]))
+    reference = (here / "configs" / config["reference"]).read_text()
+    assert reference.count('"sdar_30b_a3b.json"') == 1
+    (here / "configs" / (twin + ".py")).write_text(
+        reference.replace('"sdar_30b_a3b.json"', f'"{twin}.json"'))
+    (here / "configs" / (twin + ".json")).write_text(json.dumps(
+        dict(config, name=twin, reference=twin + ".py")))
+    (here / "layer_metrics" / "moe_experts_hit_pct.py").write_text(HIT_METRIC)
+    added = dict(bench)
+    added["configs"] = bench["configs"] + [
+        dict(entry, name=twin, file=f"benchmark/configs/{twin}.json")]
+    added["workloads"] = bench["workloads"] + [
+        {"name": cell, "config": twin,
+         "traffic": "token_ring_blockdiff_4k", "chips": 1, "why": "test"}]
+    added["per_layer"] = [
+        dict(m, workloads=m["workloads"] + [cell])
+        if m["name"] in MOE_METRICS else m for m in bench["per_layer"]] + [
+        dict(catalog.load_file_module(
+            str(here / "layer_metrics" / "moe_experts_hit_pct.py"),
+            "moe_experts_hit_pct").METRIC, workloads=[cell])]
+    (root / "BENCHMARK.json").write_text(json.dumps(added))
+    return before
 
 
 def _run(root, *args, devices=4):
@@ -1209,20 +1336,136 @@ def test_token_preset_walk_feeder_and_float32_reference(overlay, tmp_path):
     assert "relative error" in proc.stdout
 
 
-def test_no_line_of_the_harness_names_the_token_preset():
+def test_no_line_of_the_harness_names_the_token_preset(tree):
+    here = os.path.join(tree.root, "benchmark")
     for name in ("run.py", "checks.py", "catalog.py", "walk.py", "scopes.py",
                  "flops.py", "trace_reduce.py",
                  os.path.join("feeds", "token_ring.py")):
-        with open(os.path.join(BENCH, name), encoding="utf-8") as f:
+        with open(os.path.join(here, name), encoding="utf-8") as f:
             text = f.read()
         assert "tiny_tokens" not in text and "tiny_resnet" not in text, name
     # nor a configuration of the benchmark: only ``configs/`` and the tests
     # name one, so a reader serves whichever cell lists it
-    names = [c["name"] for c in catalog.load_benchmark(ROOT)["configs"]]
+    names = [c["name"] for c in tree.bench["configs"]]
     for folder in ("", "layer_metrics", "end_to_end", "feeds", "walkers"):
-        for name in sorted(os.listdir(os.path.join(BENCH, folder))):
+        for name in sorted(os.listdir(os.path.join(here, folder))):
             if name.endswith(".py"):
-                with open(os.path.join(BENCH, folder, name),
+                with open(os.path.join(here, folder, name),
                           encoding="utf-8") as f:
                     text = f.read()
                 assert not [n for n in names + ["laguna"] if n in text], name
+
+
+# -- the tests themselves: held on the tree a later PR makes ------------------------
+
+# test functions that read the repository's ``BENCHMARK.json`` alone, and why
+# each may: everything else that reads its ``configs``, ``workloads`` or
+# ``per_layer`` takes ``tree``
+READS_THE_REPOSITORY_ALONE = {
+    ("test_benchmark_harness.py",
+     "test_layer_lists_match_the_models_the_builders_make"):
+        "walks the model of every configuration the repository has; "
+        "``overlay``'s ``tiny_resnet`` states a one-layer recipe that is not "
+        "its model's, and a later PR's configuration is walked in that PR, "
+        "where it is the repository's",
+    ("test_benchmark_harness.py",
+     "test_runner_on_a_second_decoder_made_of_added_files"):
+        "holds BOTH trees against each other: a cell that was there reports "
+        "in the later tree what it reports in the repository's",
+    ("test_span_metrics.py", "test_reader_reads_the_programs_records"):
+        "finds each of its seven entries by name and compares it with its "
+        "reader's ``METRIC``: PR 24's file, as ISSUE 35 leaves it",
+}
+LISTS = {"configs", "workloads", "per_layer"}
+LIST_READERS = {"metrics_for", "find_cell"}
+
+
+def _unheld_readers(folder):
+    """``(file, function)`` of every test function in ``folder``'s
+    ``test_*.py`` that has the repository's ``BENCHMARK.json`` in hand (a
+    ``bench`` parameter, a ``load_benchmark`` call or the file's name) and
+    reads its ``configs``, ``workloads`` or ``per_layer``: what a pin on a
+    list's end or length is written with, and what only a test held on
+    ``tree`` may do, because the later trees append after every end.
+    Fixtures and helpers are not meant: they assert nothing."""
+    found = []
+    for name in sorted(os.listdir(folder)):
+        if not (name.startswith("test_") and name.endswith(".py")):
+            continue
+        with open(os.path.join(folder, name), encoding="utf-8") as f:
+            module = ast.parse(f.read(), name)
+        for node in ast.walk(module):
+            if not (isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef))
+                    and node.name.startswith("test_")):
+                continue
+            params = {a.arg for a in node.args.posonlyargs + node.args.args
+                      + node.args.kwonlyargs}
+            inside = list(ast.walk(node))
+            words = {n.value for n in inside if isinstance(n, ast.Constant)
+                     and isinstance(n.value, str)}
+            called = {n.func.attr if isinstance(n.func, ast.Attribute)
+                      else getattr(n.func, "id", None)
+                      for n in inside if isinstance(n, ast.Call)}
+            in_hand = "bench" in params or "load_benchmark" in called \
+                or "BENCHMARK.json" in words
+            if in_hand and (words & LISTS or called & LIST_READERS):
+                found.append((name, node.name))
+    return sorted(found)
+
+
+def test_every_test_that_reads_the_benchmarks_lists_is_held_on_tree():
+    """PR 33's entry test pinned the LAST configuration, cell and metrics
+    through the plain ``bench`` fixture, and nothing saw it until a sixth
+    configuration was tried (ISSUE 35). A test file a later PR adds in that
+    style fails here, in that PR, by file and function."""
+    readers = _unheld_readers(os.path.dirname(os.path.abspath(__file__)))
+    unheld = [r for r in readers if r not in READS_THE_REPOSITORY_ALONE]
+    assert not unheld, (
+        f"{unheld}: each reads configs, workloads or per_layer of the "
+        "repository's BENCHMARK.json without `tree`. Take `tree` from "
+        "test_benchmark_harness.py (`overlay, tree = harness.overlay, "
+        "harness.tree`), read `tree.bench` and `tree.root`, find entries by "
+        "name and assert prefixes of lists, never their ends or lengths")
+    assert set(READS_THE_REPOSITORY_ALONE) <= set(readers)  # no stale excuse
+
+
+def test_the_guard_names_a_test_written_in_the_old_style(tmp_path):
+    (tmp_path / "test_scratch_config.py").write_text('''
+import pytest
+
+
+@pytest.fixture(scope="module")
+def tiny_checkout(bench):                      # a fixture: not meant
+    return [w["name"] for w in bench["workloads"]]
+
+
+def _cells(bench):                             # a helper: not meant
+    return bench["workloads"]
+
+
+def test_entries_of_the_benchmark(bench, config):
+    assert bench["workloads"][-1]["config"] == config["name"]
+
+
+class TestReaders:
+    def test_listed(self, bench):
+        assert catalog.metrics_for(bench, "end_to_end", "a.cell")
+
+
+def test_loaded_by_hand():
+    assert len(catalog.load_benchmark()["per_layer"]) == 34
+
+
+def test_held(tree, config):
+    assert tree.bench["workloads"][0]["chips"] == 1
+
+
+def test_reads_no_list(bench):
+    assert bench["run_seconds"] == 24
+''')
+    (tmp_path / "helpers.py").write_text(
+        'def test_not_a_test_file(bench):\n    bench["configs"]\n')
+    assert _unheld_readers(str(tmp_path)) == [
+        ("test_scratch_config.py", "test_entries_of_the_benchmark"),
+        ("test_scratch_config.py", "test_listed"),
+        ("test_scratch_config.py", "test_loaded_by_hand")]

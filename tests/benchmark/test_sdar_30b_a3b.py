@@ -41,10 +41,6 @@ PUBLISHED = {
     "vocab_size": 151936}
 HEAD_NORMS_SHARP = "^layer[0-1]_[qk]_norm_gamma$"
 CUT = {"num_hidden_layers": 6, "num_experts": 16, "vocab_size": 18992}
-MOE_METRICS = ["moe_ms_per_step", "moe_grouped_roofline_pct",
-               "moe_load_max_over_mean", "moe_picks_held_per_token"]
-NEW_METRICS = ["attention_blockdiff_ms_per_step",
-               "attention_blockdiff_roofline_pct", "blockdiff_masked_share"]
 
 
 @pytest.fixture(scope="module")
@@ -63,6 +59,16 @@ def _load(path, name):
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+# the harness's own test file, for the tree a later PR makes of this one by
+# additions (``overlay``), the trees the contract is held on (``tree``) and
+# the record of what the benchmark was accepted with
+harness = _load(os.path.join(HERE, "test_benchmark_harness.py"),
+                "bench_harness_tests_of_sdar")
+overlay, tree = harness.overlay, harness.tree
+MOE_METRICS = harness.MOE_METRICS           # the four lists this cell joined
+NEW_METRICS = harness.BLOCKDIFF_METRICS     # the three this cell brought
 
 
 def test_the_file_holds_the_published_values(config):
@@ -221,43 +227,46 @@ def test_the_cost_function_counts_as_flops_py_counts(config):
                          "RotaryAttention)/flash_fwd/pallas_call")
 
 
-def test_entries_of_the_benchmark(bench, config):
-    """The configuration, the one cell, the cell's name at the end of the
-    four expert metrics' lists and of none of the attention ones, and the
-    three new metrics, each listing the cell alone."""
-    entry = bench["configs"][-1]
-    assert entry["name"] == config["name"] == "sdar_30b_a3b"
+def test_entries_of_the_benchmark(tree, config):
+    """This configuration's own entries, each found by its NAME, in the
+    repository's tree and in the trees a later PR makes by appending: the
+    configuration, the one cell, the cell's name after ``laguna_xs2``'s in
+    the four expert metrics' lists and in none of the accepted attention
+    ones, and the three new metrics, each listing the cell first."""
+    bench, here = tree.bench, os.path.join(tree.root, "benchmark")
+    (entry,) = [c for c in bench["configs"] if c["name"] == "sdar_30b_a3b"]
+    assert config["name"] == "sdar_30b_a3b"
     assert entry["source"] == config["source"]
     assert entry["file"] == "benchmark/configs/sdar_30b_a3b.json"
     assert entry["reduced"] == config["reduced"]
-    cell = bench["workloads"][-1]
+    (cell,) = [w for w in bench["workloads"] if w["name"] == CELL]
     assert cell == {"name": CELL, "config": "sdar_30b_a3b",
                     "traffic": "token_ring_blockdiff_4k", "chips": 1,
                     "why": cell["why"]}
-    assert len(bench["workloads"]) == 5
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    for name in MOE_METRICS:
-        assert by_name[name]["workloads"] == ["laguna_xs2.seq8k", CELL]
-    for name in by_name:
-        if name.startswith("attention_") and name not in NEW_METRICS:
-            assert by_name[name]["workloads"] == ["laguna_xs2.seq8k"]
-    assert [m["name"] for m in bench["per_layer"][-3:]] == NEW_METRICS
+    for name in MOE_METRICS:        # a later cell comes after the two
+        assert by_name[name]["workloads"][:2] == ["laguna_xs2.seq8k", CELL]
+    for name in harness.ATTENTION_METRICS:
+        assert CELL not in by_name[name]["workloads"]
     for name in NEW_METRICS:
-        reader = catalog.load_metric("layer_metrics", name)
+        reader = catalog.load_metric("layer_metrics", name, here=here)
         assert "workloads" not in reader.METRIC
-        assert by_name[name] == dict(reader.METRIC, workloads=[CELL])
+        assert by_name[name] == dict(reader.METRIC,
+                                     workloads=by_name[name]["workloads"])
+        assert by_name[name]["workloads"][0] == CELL
         assert by_name[name]["layer"] == \
             "graph to XLA (symbol.py, executor.py, ops/)"
         assert by_name[name]["moves"] == "samples_per_s_per_chip"
-    reported = {m["name"] for m in catalog.metrics_for(bench, "per_layer",
-                                                       CELL)}
-    assert set(MOE_METRICS + NEW_METRICS + ["mfu_device"]) <= reported
-    assert not {n for n in reported if n.startswith("attention_")} \
-        - set(NEW_METRICS)
+    # the cell reports its 28 metrics, and none that a later cell brought:
+    # of the attention readers its own three alone
+    reported = [m["name"] for m in catalog.metrics_for(bench, "per_layer",
+                                                       CELL)]
+    assert reported == [n for n in harness.ACCEPTED_METRICS
+                        if not n.startswith("collective_")
+                        and n not in harness.ATTENTION_METRICS]
     assert len(reported) == 21 + 4 + 3
     traffic = catalog.read_json(os.path.join(
-        BENCH, "traffic", "token_ring_blockdiff_4k.json"))
+        here, "traffic", cell["traffic"] + ".json"))
     assert {k: v for k, v in traffic.items() if k != "why"} == {
         "kind": "token_ring_blockdiff", "ring": 8, "steps_per_epoch": 64,
         "warmup_steps": 16, "follow_p": 0.5}
