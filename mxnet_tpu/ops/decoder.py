@@ -315,10 +315,16 @@ class LatentAttentionOp(OpProp):
 
     The products run in ops/pallas/flash_attention.py with keys of
     ``qk_nope + qk_rope`` and values of ``v_head_dim``: nothing is padded
-    to the wider. A head of 192 is no whole lane tile, so the kernels take
-    the heads first; the rotation of the queries, the shared key's copy to
-    every head and that change of layout are one pass of XLA's over the
-    rows, where they cost no more than the layout change alone."""
+    to the wider. The kernels read the three inputs as they are
+    (``flash_attention(key_rope=...)``): a pair of query heads is whole
+    lane tiles (two of 192 are 384 columns), ``q_pe`` turns on the query
+    block in VMEM, the one rotary key (rotated here: it is 64 columns) is
+    read by every head, the output and the gradients of ``query`` and
+    ``key_value`` come in those rows' layout, and ``key_rope``'s is summed
+    over the heads inside the kernel. Widths whose pair is no whole lane
+    tiles (``latent_fits``) take the heads first: the rotation of the
+    queries, the shared key's copy to every head and that change of layout
+    are then one pass of XLA's over the rows."""
 
     params = {
         "seq_len": (Range(int, lo=1), REQUIRED, "positions a sequence"),
@@ -355,17 +361,22 @@ class LatentAttentionOp(OpProp):
 
     def fwd(self, ins, aux, is_train, rng):
         from .pallas import flash_attention
-        from .pallas.flash_attention import rotary_tables
+        from .pallas.flash_attention import latent_fits, rotary_tables
 
         t, h, n, r = self.seq_len, self.num_heads, self.qk_nope_head_dim, \
             self.qk_rope_head_dim
         batch = ins[0].shape[0] // t
         inv_freq = rotary_inv_freq(r, self.rope_theta)
+        kv = ins[1].reshape(batch, t, h, n + self.v_head_dim)
+        turn = rotary_tables(t, r, inv_freq, interleave=True)
+        k_pe = rotate_heads(ins[2].reshape(batch, t, 1, r), turn)
+        if latent_fits(h, n, r, self.v_head_dim):
+            o = flash_attention(ins[0].reshape(batch, t, h, n + r), kv, None,
+                                causal=True, heads_last=True, rotary=turn,
+                                key_rope=k_pe.reshape(batch, t, r))
+            return [o.reshape(batch * t, h * self.v_head_dim)], []
         q = rotate_heads(ins[0].reshape(batch, t, h, n + r), rotary_tables(
             t, n + r, inv_freq, interleave=True, offset=n))
-        k_pe = rotate_heads(ins[2].reshape(batch, t, 1, r), rotary_tables(
-            t, r, inv_freq, interleave=True))
-        kv = ins[1].reshape(batch, t, h, n + self.v_head_dim)
         k = jnp.concatenate(
             [kv[..., :n], jnp.broadcast_to(k_pe, (batch, t, h, r))], axis=-1)
         o = flash_attention(q, k, kv[..., n:], causal=True, heads_last=True)

@@ -203,6 +203,14 @@ def _kv_index(row, kj, hkv, packed):
 # forward pass, bound by its vector work, gains most from computing least;
 # the backward kernels, bound by their products, from products of 256 rows
 _SUB = {"flash_fwd": 128, "flash_bwd_dq": 256, "flash_bwd_dkv": 256}
+# a pair of latent attention's heads walks larger sub-tiles: each piece of
+# a body is traced and lowered in every layer, and a pair's piece costs
+# about twice a head's to lower (its columns are cut apart as they load).
+# At 8,192 causal positions on a v5e (PERF.md section 6) the three kernels
+# of a layer then trace to fewer equations than the heads-first kernels
+# did (823 against 889), and the train step lowers 1.75 s sooner than with
+# 256 in the backward kernels, for 0.43 ms a layer more device time
+_PAIR_SUB = {"flash_fwd": 256, "flash_bwd_dq": 512, "flash_bwd_dkv": 512}
 
 # a tile's quadrant under ``halves=2``: a clean query on clean keys (the
 # one kind there is with one half), a noisy query on the clean keys before
@@ -322,14 +330,17 @@ def band_tiles(seq_q, seq_k, bq, bk, sub, causal, window, step=1, halves=1):
     return count
 
 
-def _band(kernel, bq, bk, seq_q, seq_k, causal, window, step=1, halves=1):
+def _band(kernel, bq, bk, seq_q, seq_k, causal, window, step=1, halves=1,
+          rope_dim=0):
     """What a kernel's body needs of the band: its ``sub``, whether the
     last key block is ``padded``, and ``groups``, the distinct patterns of
     its grid with the ``(off, last)`` (and ``kind``) of the tiles that have
-    each. Leaves one zero-length ``flash.band`` record a traced call."""
+    each. Leaves one zero-length ``flash.band`` record a traced call, with
+    ``rope_dim`` the width of the shared rotary key (latent attention) and
+    ``rope_shared`` 1 where there is one, 0 / 0 for every other call."""
     from ... import telemetry
 
-    sub = _SUB[kernel]
+    sub = (_PAIR_SUB if rope_dim else _SUB)[kernel]
     if step > 1 or halves == 2:
         sides = (bq, bk, seq_q, seq_k, *_sub_shape(bq, bk, sub))
         if not causal or window is not None or any(n % step for n in sides) \
@@ -341,7 +352,8 @@ def _band(kernel, bq, bk, seq_q, seq_k, causal, window, step=1, halves=1):
                 "steps (equal blocks, longer than a step, over two halves)")
     with telemetry.phase("flash.band", kernel=kernel, seq=seq_q, bq=bq,
                          bk=bk, sub=sub, window=window or 0, step=step,
-                         halves=halves,
+                         halves=halves, rope_dim=rope_dim,
+                         rope_shared=int(rope_dim > 0),
                          **band_tiles(seq_q, seq_k, bq, bk, sub, causal,
                                       window, step, halves)):
         pass
@@ -486,29 +498,107 @@ def _q_step(kjg, s, bq, bk, nq, nk, causal, window, step, halves):
 # the rotation and the gate cost no pass over HBM and no relayout there:
 #   rotated q = q * cos + (q @ rot) * sin      (``rot`` a signed permutation)
 #   gated   o = o * gate                       (one factor a row)
-# ``extras`` is the static pair (rotary, gated); the refs they add follow
-# the kernel's own inputs in the order cos, sin, rot, gate.
+# ``extras`` is the static triple (rotary, gated, shared); the refs they
+# add follow the kernel's own inputs in the order cos, sin, rot, gate,
+# shared key.
+#
+# ``shared`` is latent attention (DeepSeek-V3): a query head is ``[q_nope |
+# q_pe]``, a key-value head ``[k_nope | v]``, both as the projections leave
+# them, and ONE rotary key ``k_pe`` a position is read by every head (the
+# shared key, (batch, 1, seq, rope)). A score is ``q_nope . k_nope +
+# rot(q_pe) . k_pe``; the tables rotate ``q_pe`` alone. A head of ``nope +
+# rope`` columns need not be whole lane tiles, two are: a grid step takes a
+# PAIR of heads, its query, key-value and output blocks those of both, and
+# the body runs on values with the pair as a leading axis of 2 (one batched
+# product for both heads): the same traced body as one head's, whose loads,
+# stores and products only gain that axis. The pair's columns are cut apart
+# by ``_pair`` as they are loaded, whole lane tiles but for the second
+# query head. A query or key is then the pair of its parts ``(own,
+# rotary)``, and a score the sum of two products (``_score``), the rotary
+# one both heads' rows against the one key: nothing is put together in
+# VMEM, and the MXU passes are those of one product of ``nope + rope``.
 
 def _split_refs(refs, n_in, extras):
-    """``(inputs, (cos, sin, rot), gate, rest)`` of a kernel's refs."""
-    rotary, gated = extras
-    ins, k = refs[:n_in], n_in
+    """``(inputs, (cos, sin, rot), gate, shared, rest)`` of a kernel's
+    refs. With the shared key the inputs' second ref is the pair's
+    key-value block and ``None`` stands in the value ref's place."""
+    rotary, gated, shared = extras
+    ins, k = refs[:n_in - shared], n_in - shared
+    if shared:
+        ins = (*ins[:2], None, *ins[2:])
     rope = refs[k:k + 3] if rotary else None
     k += 3 if rotary else 0
     gate = refs[k] if gated else None
     k += 1 if gated else 0
-    return ins, rope, gate, refs[k:]
+    key = refs[k] if shared else None
+    k += 1 if shared else 0
+    return ins, rope, gate, key, refs[k:]
+
+
+def _pair(ref, rows, lo, width):
+    """Columns ``lo:lo + width`` of either head of a pair block's ``rows``:
+    ``(2, rows, width)``."""
+    half = ref.shape[-1] // 2
+    return jax.lax.concatenate([ref[:1, rows, lo:lo + width],
+                                ref[:1, rows, half + lo:half + lo + width]], 0)
+
+
+def _pair_keys(kv_ref, shared, rows, nope):
+    """The pair's keys of ``rows``: each head's own ``k_nope`` ``(2, rows,
+    nope)`` and the one rotary key ``(rows, rope)``, which both read."""
+    return _pair(kv_ref, rows, 0, nope), shared[0, 0, rows, :]
+
+
+def _pair_query(q_ref, rows, rope, nope):
+    """The pair's queries of ``rows`` as ``(q_nope, q_pe)``, ``q_pe``
+    rotated to its positions."""
+    pe = _pair(q_ref, rows, nope, q_ref.shape[-1] // 2 - nope)
+    return _pair(q_ref, rows, 0, nope), _rotated(pe, rope, rows)
+
+
+def _parts(f, x):
+    """``f`` of ``x``, or of each part of a query or key in parts."""
+    return tuple(map(f, x)) if isinstance(x, tuple) else f(x)
+
+
+def _score(a, b):
+    """``a . b^T`` over the last axis, float32: the sum of the parts'
+    products where ``a`` and ``b`` come in parts (latent attention)."""
+    if isinstance(a, tuple):
+        return jax.lax.add(_dot(a[0], b[0], (1, 1)), _dot(a[1], b[1], (1, 1)))
+    return _dot(a, b, (1, 1))
+
+
+def _weigh(w, x):
+    """``w . x`` (``w``'s last axis against ``x``'s rows), float32, for
+    ``x`` or each of its parts."""
+    return _parts(lambda part: _dot(w, part, (1, 0)), x)
+
+
+def _plus(a, b):
+    return tuple(map(jax.lax.add, a, b)) if isinstance(a, tuple) else a + b
+
+
+def _acc_rows(acc, rows):
+    """The ``rows`` of an accumulator, or of each of its parts."""
+    return _parts(lambda ref: ref[..., rows, :], acc)
+
+
+def _acc_store(acc, rows, x):
+    if isinstance(acc, tuple):
+        for ref, part in zip(acc, x):
+            ref[..., rows, :] = part
+    else:
+        acc[..., rows, :] = x
 
 
 def _rotated(x, rope, rows=slice(None)):
-    """``x``, the ``rows`` of a query block (rows, d), rotated to its
-    positions, in ``x``'s dtype."""
+    """``x``, the ``rows`` of a query block (rows, d) or of a pair's (2,
+    rows, d), rotated to its positions, in ``x``'s dtype."""
     if rope is None:
         return x
     cos_ref, sin_ref, rot_ref = rope
-    turned = jax.lax.dot_general(
-        x, rot_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    turned = _dot(x, rot_ref[...], (1, 0))
     return (x.astype(jnp.float32) * cos_ref[rows, :]
             + turned * sin_ref[rows, :]).astype(x.dtype)
 
@@ -519,9 +609,8 @@ def _unrotated(g, rope, dtype):
     if rope is None:
         return g
     cos_ref, sin_ref, rot_ref = rope
-    return g * cos_ref[...] + jax.lax.dot_general(
-        (g * sin_ref[...]).astype(dtype), rot_ref[...],
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    return g * cos_ref[...] + _dot((g * sin_ref[...]).astype(dtype),
+                                   rot_ref[...], (1, 1))
 
 
 def _gated(do, gate, rows=slice(None)):
@@ -533,9 +622,10 @@ def _gated(do, gate, rows=slice(None)):
 
 
 def _extra_specs(extras, d, q_block_map, stat_map, bq):
-    """BlockSpecs of the refs ``extras`` adds: cos and sin by the query
-    block, the whole ``rot``, the gate as the row statistics are."""
-    rotary, gated = extras
+    """BlockSpecs of the rotary tables and the gate: cos and sin (``d``
+    wide) by the query block, the whole ``rot``, the gate as the row
+    statistics are. The shared key's follows them (the wrappers')."""
+    rotary, gated = extras[:2]
     specs = []
     if rotary:
         specs += [pl.BlockSpec((bq, d), q_block_map),
@@ -551,7 +641,17 @@ def _extra_specs(extras, d, q_block_map, stat_map, bq):
 # --------------------------------------------------------------------------
 
 def _dot(a, b, contract):
-    """``a`` and ``b`` contracted over the axes ``contract``, float32."""
+    """``a`` and ``b`` contracted over the axes ``contract``, float32; a
+    pair of heads (a leading axis of 2 on both) a product each, or on
+    ``a`` alone one product of both heads' rows against ``b``."""
+    if a.ndim == 3 and b.ndim == 2:
+        return jax.lax.dot_general(
+            a, b, (((contract[0] + 1,), (contract[1],)), ((), ())),
+            preferred_element_type=jnp.float32)
+    if a.ndim == 3:
+        return jax.lax.dot_general(
+            a, b, (((contract[0] + 1,), (contract[1] + 1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
     return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
                                preferred_element_type=jnp.float32)
 
@@ -560,25 +660,49 @@ def _probs(a, b, lse, scale, mask):
     """The probabilities of a piece from the saved row statistics
     (backward), zero where ``mask`` is not set: queries ``a`` down the
     rows and ``lse`` a column, or keys ``a`` and ``lse`` a row."""
-    p = jnp.exp(_dot(a, b, (1, 1)) * scale - lse)
+    p = jnp.exp(_score(a, b) * scale - lse)
     return p if mask is None else jnp.where(mask, p, 0.0)
 
 
 def _fwd_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps, sub,
-                groups, padded, extras, step, halves):
-    (q_ref, k_ref, v_ref), rope, gate, rest = _split_refs(refs, 3, extras)
+                groups, padded, extras, step, halves, nope=0):
+    (q_ref, k_ref, v_ref), rope, gate, shared, rest = _split_refs(
+        refs, 3, extras)
     o_ref, lse_ref, acc, m_scr, l_scr = rest[:5]
     q_scr = rest[5] if rope is not None else None
     j = pl.program_id(2)
     qi, kj, kind, needed = _kv_step(pl.program_id(1), j, bq, bk, nq, nk,
                                     causal, window, step, halves)
+    if shared is None:
+        def queries(rows):
+            return q_ref[0, rows, :] if rope is None else q_scr[..., rows, :]
+
+        def keys(rows):
+            return k_ref[0, rows, :]
+
+        def values(rows):
+            return v_ref[0, rows, :]
+    else:     # the pair's, read from its blocks as they are
+        q_scr = rest[5:7]
+
+        def queries(rows):
+            return _acc_rows(q_scr, rows)
+
+        def keys(rows):
+            return _pair_keys(k_ref, shared, rows, nope)
+
+        def values(rows):
+            return _pair(k_ref, rows, nope, o_ref.shape[-1] // 2)
 
     @pl.when(j == 0)
     def _init():
         acc[:] = jnp.zeros_like(acc)
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
-        if rope is not None:     # once a query block, kept for its steps
+        if shared is not None:     # both heads, once a query block
+            _acc_store(q_scr, slice(None),
+                       _pair_query(q_ref, slice(None), rope, nope))
+        elif rope is not None:     # once a query block, kept for its steps
             q_scr[...] = _rotated(q_ref[0], rope)
 
     def body(classes):
@@ -588,41 +712,48 @@ def _fwd_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps, sub,
         mask = _masks(bq, bk, sub, step=step)
         for r0, pieces in strips:
             rows = slice(r0, r0 + height)
-            q = _mxu(q_ref[0, rows, :] if rope is None else q_scr[rows, :])
+            q = _parts(_mxu, queries(rows))
             scores = []
             for k0, n, terms in pieces:
-                s = _dot(q, _mxu(k_ref[0, k0:k0 + n, :]), (1, 1)) * scale
+                s = _score(q, _parts(_mxu, keys(slice(k0, k0 + n)))) * scale
                 scores.append(jnp.where(mask(terms), s, NEG_INF)
                               if terms else s)
-            m_prev = m_scr[rows, :1]                 # [height, 1]
+            m_prev = m_scr[..., rows, :1]            # [height, 1]
             m_new = functools.reduce(jnp.maximum, [m_prev] + [
                 jnp.max(s, axis=-1, keepdims=True) for s in scores])
             alpha = jnp.exp(m_prev - m_new)
-            l_new = l_scr[rows, :1] * alpha
-            out = acc[rows, :] * alpha
+            l_new = l_scr[..., rows, :1] * alpha
+            out = acc[..., rows, :] * alpha
             for (k0, n, terms), s in zip(pieces, scores):
                 p = jnp.exp(s - m_new)               # [height, n] f32
                 if terms:     # a row with no key yet has m_new = NEG_INF
                     p = jnp.where(mask(terms), p, 0.0)
                 l_new = l_new + jnp.sum(p, axis=-1, keepdims=True)
-                v = _mxu(v_ref[0, k0:k0 + n, :])
+                v = _mxu(values(slice(k0, k0 + n)))
                 out = out + _dot(p.astype(v.dtype), v, (1, 0))
-            acc[rows, :] = out
-            m_scr[rows, :] = jnp.broadcast_to(m_new, (height, _LANES))
-            l_scr[rows, :] = jnp.broadcast_to(l_new, (height, _LANES))
+            acc[..., rows, :] = out
+            lanes = (*m_new.shape[:-1], _LANES)
+            m_scr[..., rows, :] = jnp.broadcast_to(m_new, lanes)
+            l_scr[..., rows, :] = jnp.broadcast_to(l_new, lanes)
 
     _on_tile(groups, qi * bq - kj * bk, kj == nk - 1, needed, causal,
              padded, body, kind)
 
     @pl.when(j == steps - 1)
     def _finalize():
-        l = l_scr[:, :1]
+        l = l_scr[..., :1]
         l_safe = jnp.maximum(l, 1e-30)
         out = acc[:] / l_safe
         if gate is not None:
             out = out * gate[0]
-        o_ref[0] = out.astype(o_ref.dtype)
-        lse_ref[0] = (m_scr[:, :1] + jnp.log(l_safe)).astype(jnp.float32)
+        if shared is None:
+            o_ref[0] = out.astype(o_ref.dtype)
+            lse_ref[0] = (m_scr[:, :1] + jnp.log(l_safe)).astype(jnp.float32)
+            return
+        # the pair's outputs side by side, its statistics a row each
+        o_ref[0] = jnp.concatenate([out[0], out[1]], axis=-1).astype(
+            o_ref.dtype)
+        lse_ref[...] = m_scr[..., :1] + jnp.log(l_safe)
 
 
 def _kv_block_map(hq, hkv, bq, bk, nq, nk, causal, window, packed, step=1,
@@ -658,24 +789,71 @@ def _table_map(nq, halves, block):
     return index
 
 
+def _shared_map(kv_map):
+    """Index map of the shared key's block: the key block that ``kv_map``
+    gives, of the row's sequence."""
+    def index(*at):
+        row, kj, _ = kv_map(*at)
+        return row, 0, kj, 0
+
+    return index
+
+
+def _pair_widths(q, k, shared, hq):
+    """A call with the shared key as the grid sees it, a row a PAIR of
+    heads: ``(rows a sequence, query width, key-value width, output
+    width, nope)`` of a pair, ``nope`` a head's key columns without
+    positions."""
+    d = q.shape[2] // hq
+    nope = d - shared.shape[3]
+    v_dim = k.shape[2] // hq - nope
+    return hq // 2, 2 * d, 2 * (nope + v_dim), 2 * v_dim, nope
+
+
+def _pair_scratch(rows, widths, dtype):
+    """VMEM of a pair's parts: ``(2, rows, width)`` for each width."""
+    return [pltpu.VMEM((2, rows, w), dtype) for w in widths]
+
+
+# A pair's blocks and accumulators are twice a head's, and its row
+# statistics are a lane tile a row: latent attention's kernels take more
+# VMEM than the default scoped limit of 16 MiB (v5e has 128 MiB).
+_LATENT_VMEM = 48 * 2 ** 20
+
+
+def _latent_params(shared):
+    """The ``pallas_call`` arguments of a latent call (none otherwise)."""
+    if shared is None:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=_LATENT_VMEM)}
+
+
 def _flash_fwd_padded(q, k, v, *, scale, causal, window, hq, hkv, bq, bk,
                       seq_q, seq_k, interpret, packed=False, rope=None,
-                      gate=None, step=1, halves=1):
+                      gate=None, step=1, halves=1, shared=None):
     """``seq_q`` / ``seq_k`` and the blocks counted below are a half's;
-    the arrays and the grid hold ``halves`` of them."""
-    d = q.shape[2] // hq if packed else q.shape[2]
-    v_dim = v.shape[2] // hkv if packed else v.shape[2]
+    the arrays and the grid hold ``halves`` of them. With the ``shared``
+    key (latent attention) ``k`` is the key-value rows, ``v`` is None and
+    a row of the grid is a pair of heads (``_pair_widths``)."""
+    rope_dim = 0 if shared is None else shared.shape[3]
+    if shared is None:
+        d = q.shape[2] // hq if packed else q.shape[2]
+        v_dim = v.shape[2] // hkv if packed else v.shape[2]
+    else:
+        hq, d, kv_dim, v_dim, nope = _pair_widths(q, k, shared, hq)
+        hkv = hq
     bh = q.shape[0] * hq if packed else q.shape[0]
     sq = q.shape[1]
     nq, nk = sq // bq // halves, k.shape[1] // bk // halves
     # over two halves one step more: a noisy query block's own block
     steps = _kv_steps(bq, bk, nk, causal, window) + halves - 1
-    extras = (rope is not None, gate is not None)
+    extras = (rope is not None, gate is not None, shared is not None)
     kern = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, window=window, bq=bq, bk=bk,
         nq=nq, nk=nk, steps=steps, extras=extras,
         **_band("flash_fwd", bq, bk, seq_q, seq_k, causal, window, step,
-                halves))
+                halves, rope_dim))
     kv_map = _kv_block_map(hq, hkv, bq, bk, nq, nk, causal, window, packed,
                            step, halves)
 
@@ -685,33 +863,45 @@ def _flash_fwd_padded(q, k, v, *, scale, causal, window, hq, hkv, bq, bk,
     def stat_map(b, i, j):
         return (b, i, 0)
 
+    if shared is None:
+        pair, per, ins = (), 1, (q, k, v)
+        own = [pl.BlockSpec((1, bq, d), q_map),
+               pl.BlockSpec((1, bk, d), kv_map),
+               pl.BlockSpec((1, bk, v_dim), kv_map)]
+        last = []
+    else:     # a pair's statistics and scratch a row a head
+        kern = functools.partial(kern, nope=nope)
+        pair, per, ins = (2,), 2, (q, k)
+        own = [pl.BlockSpec((1, bq, d), q_map),
+               pl.BlockSpec((1, bk, kv_dim), kv_map)]
+        last = [pl.BlockSpec((1, 1, bk, rope_dim), _shared_map(kv_map))]
+
     o, lse = pl.pallas_call(
         kern,
         grid=(bh, halves * nq, steps),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), q_map),
-            pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bk, v_dim), kv_map),
-        ] + _extra_specs(extras, d, _table_map(nq, halves,
-                                               lambda b, i, j: i),
-                         stat_map, bq),
+        in_specs=own + _extra_specs(
+            extras, rope_dim or d, _table_map(nq, halves, lambda b, i, j: i),
+            stat_map, bq) + last,
         out_specs=[
             pl.BlockSpec((1, bq, v_dim), q_map),
-            pl.BlockSpec((1, bq, 1), stat_map),
+            pl.BlockSpec((per, bq, 1), stat_map),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((*q.shape[:2], q.shape[2] // d * v_dim),
                                  q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh * per, sq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, v_dim), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-            pltpu.VMEM((bq, _LANES), jnp.float32),
-        ] + ([pltpu.VMEM((bq, d), q.dtype)] if rope is not None else []),
+            pltpu.VMEM((*pair, bq, v_dim // per), jnp.float32),
+            pltpu.VMEM((*pair, bq, _LANES), jnp.float32),
+            pltpu.VMEM((*pair, bq, _LANES), jnp.float32),
+        ] + (_pair_scratch(bq, (nope, rope_dim), q.dtype) if shared is not None
+             else [pltpu.VMEM((bq, d), q.dtype)] if rope is not None else []),
         interpret=interpret,
         name="flash_fwd",
-    )(q, k, v, *(rope or ()), *(() if gate is None else (gate,)))
+        **_latent_params(shared),
+    )(*ins, *(rope or ()), *(() if gate is None else (gate,)),
+      *(() if shared is None else (shared,)))
     return o, lse
 
 
@@ -720,17 +910,63 @@ def _flash_fwd_padded(q, k, v, *, scale, causal, window, hq, hkv, bq, bk,
 # --------------------------------------------------------------------------
 
 def _bwd_dq_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps, sub,
-                   groups, padded, extras, step, halves):
-    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), rope, gate, rest = \
-        _split_refs(refs, 6, extras)
+                   groups, padded, extras, step, halves, nope=0):
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), rope, gate, shared, \
+        rest = _split_refs(refs, 6, extras)
     dq_ref, dq_acc = rest[:2]
     q_scr = rest[2] if rope is not None else None
     j = pl.program_id(2)
     qi, kj, kind, needed = _kv_step(pl.program_id(1), j, bq, bk, nq, nk,
                                     causal, window, step, halves)
+    if shared is None:
+        def queries(rows):
+            return q_ref[0, rows, :] if rope is None else q_scr[..., rows, :]
+
+        def keys(rows):
+            return k_ref[0, rows, :]
+
+        def values(rows):
+            return v_ref[0, rows, :]
+
+        def grads(rows):
+            return _gated(do_ref[0, rows, :], gate, rows)
+
+        def stats(ref, rows):
+            return ref[0, rows, :]
+    else:     # the pair's, read from its blocks as they are; the gradient
+        # by q_nope and by q_pe accumulated apart. The output rows stand in
+        # delta's place, and delta is the second output, made at the first
+        # step
+        o_ref, delta_ref = delta_ref, rest[1]
+        dq_acc, q_scr = rest[2:4], rest[4:6]
+
+        def queries(rows):
+            return _acc_rows(q_scr, rows)
+
+        def keys(rows):
+            return _pair_keys(k_ref, shared, rows, nope)
+
+        def values(rows):
+            return _pair(k_ref, rows, nope, do_ref.shape[-1] // 2)
+
+        def grads(rows):
+            return _pair(do_ref, rows, 0, do_ref.shape[-1] // 2)
+
+        def stats(ref, rows):
+            return ref[:, rows, :]
 
     @pl.when(j == 0)
     def _init():
+        if shared is not None:
+            for acc in dq_acc:
+                acc[...] = jnp.zeros_like(acc)
+            _acc_store(q_scr, slice(None),
+                       _pair_query(q_ref, slice(None), rope, nope))
+            o = _pair(o_ref, slice(None), 0, o_ref.shape[-1] // 2)
+            delta_ref[...] = jnp.sum(
+                grads(slice(None)).astype(jnp.float32)
+                * o.astype(jnp.float32), axis=-1, keepdims=True)
+            return
         dq_acc[:] = jnp.zeros_like(dq_acc)
         if rope is not None:
             q_scr[...] = _rotated(q_ref[0], rope)
@@ -740,46 +976,99 @@ def _bwd_dq_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps, sub,
         mask = _masks(bq, bk, sub, step=step)
         for r0, pieces in strips:
             rows = slice(r0, r0 + height)
-            q = _mxu(q_ref[0, rows, :] if rope is None else q_scr[rows, :])
-            do = _mxu(_gated(do_ref[0, rows, :], gate, rows))
-            lse = lse_ref[0, rows, :]                # [height, 1]
-            delta = delta_ref[0, rows, :]
-            dq = dq_acc[rows, :]
+            q = _parts(_mxu, queries(rows))
+            do = _mxu(grads(rows))
+            lse = stats(lse_ref, rows)                # [height, 1]
+            delta = stats(delta_ref, rows)
+            dq = _acc_rows(dq_acc, rows)
             for k0, n, terms in pieces:
-                k = _mxu(k_ref[0, k0:k0 + n, :])
-                v = _mxu(v_ref[0, k0:k0 + n, :])
+                k = _parts(_mxu, keys(slice(k0, k0 + n)))
+                v = _mxu(values(slice(k0, k0 + n)))
                 p = _probs(q, k, lse, scale, mask(terms))
                 dp = _dot(do, v, (1, 1))
-                ds = (p * (dp - delta) * scale).astype(k.dtype)
-                dq = dq + _dot(ds, k, (1, 0))
-            dq_acc[rows, :] = dq
+                ds = (p * (dp - delta) * scale).astype(v.dtype)
+                dq = _plus(dq, _weigh(ds, k))
+            _acc_store(dq_acc, rows, dq)
 
     _on_tile(groups, qi * bq - kj * bk, kj == nk - 1, needed, causal,
              padded, body, kind)
 
     @pl.when(j == steps - 1)
     def _finalize():
-        dq_ref[0] = _unrotated(dq_acc[:], rope, dq_ref.dtype).astype(
-            dq_ref.dtype)
+        if shared is None:
+            dq_ref[0] = _unrotated(dq_acc[:], rope, dq_ref.dtype).astype(
+                dq_ref.dtype)
+            return
+        # the gradient by q_pe unrotated, the pair's heads side by side
+        own = dq_acc[0][...]
+        pe = _unrotated(dq_acc[1][...], rope, dq_ref.dtype)
+        dq_ref[0] = jnp.concatenate([own[0], pe[0], own[1], pe[1]],
+                                    axis=-1).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps,
-                    group, sub, groups, padded, extras, step, halves):
-    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), rope, gate, rest = \
-        _split_refs(refs, 6, extras)
-    dk_ref, dv_ref, dk_acc, dv_acc = rest
-    # the inner grid dimension walks the query heads that share this
-    # key-value head, and within each the query blocks that can need
+                    group, sub, groups, padded, extras, step, halves, nope=0):
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), rope, gate, shared, \
+        rest = _split_refs(refs, 6, extras)
+    # with the shared key: the gradient of the pair's key-value block and
+    # the shared key's
+    dk_ref, dv_ref, dk_acc, dv_acc = rest[:4]
+    # the inner grid dimension walks the query heads (pairs) that share
+    # this key-value head, and within each the query blocks that can need
     # this key block
     t = pl.program_id(2)
     _, qi, kj, kind, needed = _q_step(pl.program_id(1), _mod(t, steps), bq,
                                       bk, nq, nk, causal, window, step,
                                       halves)
+    if shared is None:
+        def keys(rows):
+            return k_ref[0, rows, :]
 
-    @pl.when(t == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        def values(rows):
+            return v_ref[0, rows, :]
+
+        def queries(rows):
+            return _rotated(q_ref[0, rows, :], rope, rows)
+
+        def grads(rows):
+            return _gated(do_ref[0, rows, :], gate, rows)
+
+        def stats(ref, rows):
+            return ref[0, 0, :, rows]
+    else:     # the step's pair's, read from its blocks as they are; the
+        # gradient by k_nope a pair's, by the shared key summed over all
+        dk_acc = (dk_acc, rest[4])
+
+        def keys(rows):     # a strip's: the keys are the products' rows
+            own, pe = _pair_keys(k_ref, shared, rows, nope)
+            return own, jax.lax.broadcast(pe, (2,))
+
+        def values(rows):
+            return _pair(k_ref, rows, nope, do_ref.shape[-1] // 2)
+
+        def queries(rows):
+            return _pair_query(q_ref, rows, rope, nope)
+
+        def grads(rows):
+            return _pair(do_ref, rows, 0, do_ref.shape[-1] // 2)
+
+        def stats(ref, rows):
+            return ref[:, 0, :, rows]
+
+    if shared is None:
+        @pl.when(t == 0)
+        def _init():
+            dk_acc[:] = jnp.zeros_like(dk_acc)
+            dv_acc[:] = jnp.zeros_like(dv_acc)
+    else:     # a pair's own accumulators anew, the shared key's once
+        @pl.when(_mod(t, steps) == 0)
+        def _next_pair():
+            for acc in (dk_acc[0], dv_acc):
+                acc[...] = jnp.zeros_like(acc)
+
+        @pl.when(t == 0)
+        def _init():
+            dk_acc[1][...] = jnp.zeros_like(dk_acc[1])
 
     def body(classes):
         # strips of keys, each over the pieces of queries it needs, the
@@ -790,51 +1079,75 @@ def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps,
         # is rotated as it comes, once
         lo = min(r0 for _, pieces in strips for r0, _, _ in pieces)
         hi = max(r0 + n for _, pieces in strips for r0, n, _ in pieces)
-        q_all = _mxu(_rotated(q_ref[0, lo:hi, :], rope, slice(lo, hi)))
-        do_all = _mxu(_gated(do_ref[0, lo:hi, :], gate, slice(lo, hi)))
+        q_all = _parts(_mxu, queries(slice(lo, hi)))
+        do_all = _mxu(grads(slice(lo, hi)))
         for c0, pieces in strips:
             cols = slice(c0, c0 + width)
-            k = _mxu(k_ref[0, cols, :])
-            v = _mxu(v_ref[0, cols, :])
-            dk, dv = dk_acc[cols, :], dv_acc[cols, :]
+            k = _parts(_mxu, keys(cols))
+            v = _mxu(values(cols))
+            dk, dv = _acc_rows(dk_acc, cols), dv_acc[..., cols, :]
             for r0, n, terms in pieces:
-                q = q_all[r0 - lo:r0 - lo + n]
-                do = do_all[r0 - lo:r0 - lo + n]
-                p = _probs(k, q, lse_ref[0, 0, :, r0:r0 + n], scale,
+                q = _parts(lambda x: x[..., r0 - lo:r0 - lo + n, :], q_all)
+                do = do_all[..., r0 - lo:r0 - lo + n, :]
+                p = _probs(k, q, stats(lse_ref, slice(r0, r0 + n)), scale,
                            mask(terms))              # [width, n] f32
                 dv = dv + _dot(p.astype(do.dtype), do, (1, 0))
                 dp = _dot(v, do, (1, 1))
-                ds = (p * (dp - delta_ref[0, 0, :, r0:r0 + n])
-                      * scale).astype(q.dtype)
-                dk = dk + _dot(ds, q, (1, 0))
-            dk_acc[cols, :] = dk
-            dv_acc[cols, :] = dv
+                ds = (p * (dp - stats(delta_ref, slice(r0, r0 + n)))
+                      * scale).astype(do.dtype)
+                dk = _plus(dk, _weigh(ds, q))
+            _acc_store(dk_acc, cols, dk)
+            dv_acc[..., cols, :] = dv
 
     _on_tile(groups, qi * bq - kj * bk, kj == nk - 1, needed, causal,
              padded, body, kind)
 
+    if shared is None:
+        @pl.when(t == group * steps - 1)
+        def _finalize():
+            dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        return
+
+    @pl.when(_mod(t, steps) == steps - 1)
+    def _pair_done():     # [k_nope | v] of either head, as the rows lie
+        own, dv = dk_acc[0][...], dv_acc[...]
+        dk_ref[0] = jnp.concatenate([own[0], dv[0], own[1], dv[1]],
+                                    axis=-1).astype(dk_ref.dtype)
+
     @pl.when(t == group * steps - 1)
-    def _finalize():
-        dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+    def _finalize_shared():
+        dv_ref[0, 0] = (dk_acc[1][0] + dk_acc[1][1]).astype(dv_ref.dtype)
 
 
 def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
                       hkv, bq, bk, seq_q, seq_k, interpret, packed=False,
-                      rope=None, gate=None, step=1, halves=1):
+                      rope=None, gate=None, step=1, halves=1, shared=None):
     """``(dq, dk, dv, delta)``. With a gate ``o`` is the gated output and
     ``delta`` (the row sums of ``do * o``) serves both the kernels and the
-    gate's own gradient."""
-    extras = (rope is not None, gate is not None)
-    more = (*(rope or ()), *(() if gate is None else (gate,)))
-    d = q.shape[2] // hq if packed else q.shape[2]
-    v_dim = v.shape[2] // hkv if packed else v.shape[2]
+    gate's own gradient. With the ``shared`` key (latent attention) ``k``
+    is the key-value rows and ``v`` None: ``dk`` is their gradient and
+    ``dv`` the shared key's, summed over every head in ``flash_bwd_dkv``,
+    whose one key-value head is the shared key; a row of the grids is a
+    pair of heads (``_pair_widths``), and ``delta`` is made by
+    ``flash_bwd_dq``."""
+    extras = (rope is not None, gate is not None, shared is not None)
+    more = (*(rope or ()), *(() if gate is None else (gate,)),
+            *(() if shared is None else (shared,)))
+    rope_dim = 0 if shared is None else shared.shape[3]
+    if shared is None:
+        d = q.shape[2] // hq if packed else q.shape[2]
+        v_dim = v.shape[2] // hkv if packed else v.shape[2]
+        per = 1
+    else:
+        hq, d, kv_dim, v_dim, nope = _pair_widths(q, k, shared, hq)
+        hkv, per = hq, 2
     bh = q.shape[0] * hq if packed else q.shape[0]
-    bkv = k.shape[0] * hkv if packed else k.shape[0]
     sq, sk = q.shape[1], k.shape[1]
     nq, nk = sq // bq // halves, sk // bk // halves      # a half's
-    group = hq // hkv
-    if packed:
+    if shared is not None:     # flash_bwd_dq's second output
+        delta = None
+    elif packed:
         delta = jnp.sum(
             (do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
                 q.shape[0], sq, hq, v_dim), axis=-1).transpose(
@@ -846,7 +1159,11 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
     kv_steps = _kv_steps(bq, bk, nk, causal, window) + halves - 1
     kv_map = _kv_block_map(hq, hkv, bq, bk, nq, nk, causal, window, packed,
                            step, halves)
-    band = (bq, bk, seq_q, seq_k, causal, window, step, halves)
+    band = (bq, bk, seq_q, seq_k, causal, window, step, halves, rope_dim)
+    dq_kernel = functools.partial(
+        _bwd_dq_kernel, scale=scale, causal=causal, window=window, bq=bq,
+        bk=bk, nq=nq, nk=nk, steps=kv_steps, extras=extras,
+        **_band("flash_bwd_dq", *band))
 
     def q_of_row(b, i, j):
         return _q_index(b, i, hq, packed)
@@ -854,30 +1171,53 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
     def stat_of_row(b, i, j):
         return (b, i, 0)
 
+    outs = (pl.BlockSpec((1, bq, d), q_of_row),
+            jax.ShapeDtypeStruct(q.shape, q.dtype))
+    if shared is None:
+        own, last, ins = [pl.BlockSpec((1, bk, d), kv_map),
+                          pl.BlockSpec((1, bk, v_dim), kv_map)], [], (k, v)
+        sums = (delta, pl.BlockSpec((per, bq, 1), stat_of_row))
+    else:
+        dq_kernel = functools.partial(dq_kernel, nope=nope)
+        own, ins = [pl.BlockSpec((1, bk, kv_dim), kv_map)], (k,)
+        last = [pl.BlockSpec((1, 1, bk, rope_dim), _shared_map(kv_map))]
+        # the output rows in delta's place: the row sums of do * o are the
+        # kernel's second output, made there from the blocks it reads
+        sums = (o, pl.BlockSpec((1, bq, v_dim), q_of_row))
+        outs = ([outs[0], pl.BlockSpec((per, bq, 1), stat_of_row)],
+                [outs[1], jax.ShapeDtypeStruct((bh * per, sq, 1),
+                                               jnp.float32)])
+
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                          window=window, bq=bq, bk=bk, nq=nq, nk=nk,
-                          steps=kv_steps, extras=extras,
-                          **_band("flash_bwd_dq", *band)),
+        dq_kernel,
         grid=(bh, halves * nq, kv_steps),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), q_of_row),
-            pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bk, v_dim), kv_map),
+        in_specs=[pl.BlockSpec((1, bq, d), q_of_row)] + own + [
             pl.BlockSpec((1, bq, v_dim), q_of_row),
-            pl.BlockSpec((1, bq, 1), stat_of_row),
-            pl.BlockSpec((1, bq, 1), stat_of_row),
-        ] + _extra_specs(extras, d, _table_map(nq, halves,
-                                               lambda b, i, j: i),
-                         stat_of_row, bq),
-        out_specs=pl.BlockSpec((1, bq, d), q_of_row),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)]
-        + ([pltpu.VMEM((bq, d), q.dtype)] if rope is not None else []),
+            pl.BlockSpec((per, bq, 1), stat_of_row),
+            sums[1],
+        ] + _extra_specs(extras, rope_dim or d, _table_map(
+            nq, halves, lambda b, i, j: i), stat_of_row, bq) + last,
+        out_specs=outs[0],
+        out_shape=outs[1],
+        scratch_shapes=(
+            _pair_scratch(bq, (nope, rope_dim), jnp.float32)
+            + _pair_scratch(bq, (nope, rope_dim), q.dtype)
+            if shared is not None else
+            [pltpu.VMEM((bq, d), jnp.float32)]
+            + ([pltpu.VMEM((bq, d), q.dtype)] if rope is not None else [])),
         interpret=interpret,
         name="flash_bwd_dq",
-    )(q, k, v, do, lse, delta, *more)
+        **_latent_params(shared),
+    )(q, *ins, do, lse, sums[0], *more)
+    if shared is not None:
+        dq, delta = dq
 
+    # the one key-value head of latent attention is the shared key, which
+    # every pair reads
+    if shared is not None:
+        hkv = 1
+    bkv = k.shape[0] * hkv if packed else k.shape[0]
+    group = hq // hkv
     # over two halves a clean key block meets both halves' query blocks
     q_steps = halves * _q_steps(bq, bk, nq, causal, window)
 
@@ -902,39 +1242,54 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
     def stat_rows(x):
         # a query block's statistics along the lanes, as flash_bwd_dkv
         # reads them: the same bytes
-        return x.reshape(bh, halves * nq, 1, bq)
+        return x.reshape(bh * per, halves * nq, 1, bq)
 
     def kv_of_row(b, j, t):
         return _kv_index(b, j, hkv, packed)
+
+    if shared is None:
+        outs = [pl.BlockSpec((1, bk, d), kv_of_row),
+                pl.BlockSpec((1, bk, v_dim), kv_of_row)]
+        own, last = outs, []
+        shapes = (v.shape, v.dtype)
+    else:     # the step's pair's key-value block, and the shared key's
+        def kv_of_pair(b, j, t):
+            return _kv_index(q_row(b, j, t)[0], j, hq, True)
+
+        outs = [pl.BlockSpec((1, bk, kv_dim), kv_of_pair),
+                pl.BlockSpec((1, 1, bk, rope_dim),
+                             lambda b, j, t: (b, 0, j, 0))]
+        own, last = outs[:1], outs[1:]
+        shapes = (shared.shape, shared.dtype)
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           window=window, bq=bq, bk=bk, nq=nq, nk=nk,
                           steps=q_steps, group=group, extras=extras,
-                          **_band("flash_bwd_dkv", *band)),
+                          **_band("flash_bwd_dkv", *band),
+                          **({} if shared is None else {"nope": nope})),
         grid=(bkv, halves * nk, group * q_steps),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), q_map),
-            pl.BlockSpec((1, bk, d), kv_of_row),
-            pl.BlockSpec((1, bk, v_dim), kv_of_row),
+        in_specs=[pl.BlockSpec((1, bq, d), q_map)] + own + [
             pl.BlockSpec((1, bq, v_dim), q_map),
-            pl.BlockSpec((1, 1, 1, bq), stat_row_map),
-            pl.BlockSpec((1, 1, 1, bq), stat_row_map),
-        ] + _extra_specs(extras, d, _table_map(
-            nq, halves, lambda b, j, t: q_row(b, j, t)[1]), stat_map, bq),
-        out_specs=[
-            pl.BlockSpec((1, bk, d), kv_of_row),
-            pl.BlockSpec((1, bk, v_dim), kv_of_row),
-        ],
+            pl.BlockSpec((per, 1, 1, bq), stat_row_map),
+            pl.BlockSpec((per, 1, 1, bq), stat_row_map),
+        ] + _extra_specs(extras, rope_dim or d, _table_map(
+            nq, halves, lambda b, j, t: q_row(b, j, t)[1]), stat_map,
+            bq) + last,
+        out_specs=outs,
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct(*shapes),
         ],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, v_dim), jnp.float32)],
+        scratch_shapes=(
+            _pair_scratch(bk, (nope, v_dim // 2, rope_dim), jnp.float32)
+            if shared is not None else
+            [pltpu.VMEM((bk, d), jnp.float32),
+             pltpu.VMEM((bk, v_dim), jnp.float32)]),
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(q, k, v, do, stat_rows(lse), stat_rows(delta), *more)
+        **_latent_params(shared),
+    )(q, *ins, do, stat_rows(lse), stat_rows(delta), *more)
     return dq, dk, dv, delta
 
 
@@ -976,13 +1331,16 @@ def _cut_seq(x, seq, width, halves):
 # non-differentiable argument. ``rope`` is ``None`` or ``(cos, sin, rot)`` (tables (seq, d)
 # float32, ``rot`` (d, d)): the QUERY is rotated inside the kernels; ``gate``
 # is ``None`` or the logits (batch x heads, seq, 1) of a sigmoid gate on the
-# output, a factor a head and position.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _flash(q, k, v, rope, gate, cfg):
-    return _flash_fwd(q, k, v, rope, gate, cfg)[0]
+# output, a factor a head and position. ``shared`` is ``None`` or latent
+# attention's rotary key (batch, 1, seq, rope), already rotated: ``k`` is
+# then the key-value rows, ``v`` is ``None``, the tables are ``rope`` wide
+# and ``hkv`` is 1.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _flash(q, k, v, rope, gate, shared, cfg):
+    return _flash_fwd(q, k, v, rope, gate, shared, cfg)[0]
 
 
-def _flash_fwd(q, k, v, rope, gate, cfg):
+def _flash_fwd(q, k, v, rope, gate, shared, cfg):
     causal, window, hq, hkv, bq, bk, interpret, packed, step, halves = cfg
     sq, sk = q.shape[1] // halves, k.shape[1] // halves      # a half's
     d = q.shape[2] // hq if packed else q.shape[2]
@@ -998,7 +1356,8 @@ def _flash_fwd(q, k, v, rope, gate, cfg):
 
     qp = _pad_seq(_pad_to(q, 2, dm(q)), bq, halves)
     kp = _pad_seq(_pad_to(k, 2, dm(k)), bk, halves)
-    vp = _pad_seq(_pad_to(v, 2, dm(v)), bk, halves)
+    vp = None if v is None else _pad_seq(_pad_to(v, 2, dm(v)), bk, halves)
+    sharedp = None if shared is None else _pad_to(shared, 2, bk)
     ropep = None if rope is None else (
         _pad_to(rope[0], 0, bq), _pad_to(rope[1], 0, bq),
         rope[2].astype(q.dtype))
@@ -1009,35 +1368,39 @@ def _flash_fwd(q, k, v, rope, gate, cfg):
                                seq_q=sq, seq_k=sk,
                                interpret=interpret, packed=packed,
                                rope=ropep, gate=factor, step=step,
-                               halves=halves)
+                               halves=halves, shared=sharedp)
     # under a recomputation segment (executor._remat_segments) the output
     # and the row statistics are kept: recomputing them is this kernel again
     o = checkpoint_name(o, REMAT_KEEP)
     lse = checkpoint_name(lse, REMAT_KEEP)
-    widths = (q.shape[2], k.shape[2], v.shape[2],
-              v.shape[2] // hkv * hq if packed else v.shape[2])
+    widths = (q.shape[2], k.shape[2], None if v is None else v.shape[2],
+              o.shape[2] if packed else v.shape[2])
     return _cut_seq(o, sq, widths[3], halves), (
-        qp, kp, vp, o, lse, ropep, factor, rope, gate, scale, sq, sk, widths)
+        qp, kp, vp, o, lse, ropep, factor, sharedp, rope, gate, scale, sq,
+        sk, widths)
 
 
 def _flash_bwd(cfg, res, g):
     causal, window, hq, hkv, bq, bk, interpret, packed, step, halves = cfg
-    qp, kp, vp, o, lse, ropep, factor, rope, gate, scale, sq, sk, widths = res
+    qp, kp, vp, o, lse, ropep, factor, sharedp, rope, gate, scale, sq, sk, \
+        widths = res
     # match residual padding
     gp = _pad_seq(_pad_to(g, 2, o.shape[-1]), bq, halves)
     dq, dk, dv, delta = _flash_bwd_padded(
         qp, kp, vp, o, lse, gp, scale=scale, causal=causal, window=window,
         hq=hq, hkv=hkv, bq=bq, bk=bk, seq_q=sq, seq_k=sk,
         interpret=interpret, packed=packed, rope=ropep, gate=factor,
-        step=step, halves=halves)
+        step=step, halves=halves, shared=sharedp)
     d_rope = None if rope is None else tuple(jnp.zeros_like(a) for a in rope)
     # o is the gated output: sum(do * o) = s * sum(do * ungated), and the
     # sigmoid's slope is s (1 - s)
     d_gate = None if gate is None else \
         (delta * (1.0 - factor))[:, :sq].astype(gate.dtype)
-    return _cut_seq(dq, sq, widths[0], halves), \
-        _cut_seq(dk, sk, widths[1], halves), \
-        _cut_seq(dv, sk, widths[2], halves), d_rope, d_gate
+    dq = _cut_seq(dq, sq, widths[0], halves)
+    dk = _cut_seq(dk, sk, widths[1], halves)
+    if sharedp is not None:    # dv is the shared key's
+        return dq, dk, None, d_rope, d_gate, dv[:, :, :sk]
+    return dq, dk, _cut_seq(dv, sk, widths[2], halves), d_rope, d_gate, None
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -1079,7 +1442,7 @@ def flash_attention_with_lse(q, k, v, causal=False, block_q=512,
     bq, bk = _blocks(q, k, block_q, block_k)
     o, res = _flash_fwd(q.reshape(b * h, sq, d),
                         k.reshape(b * h, k.shape[2], d),
-                        v.reshape(b * h, v.shape[2], d), None, None,
+                        v.reshape(b * h, v.shape[2], d), None, None, None,
                         (causal, None, h, h, bq, bk, interpret, False, 1, 1))
     lse = res[4][:, :sq, 0]
     return o.reshape(b, h, sq, d), lse.reshape(b, h, sq)
@@ -1162,9 +1525,40 @@ def rotary_tables(seq, head_dim, inv_freq, attention_factor=1.0,
             jnp.asarray(rot))
 
 
+def latent_fits(heads, nope, rope, v_dim):
+    """Whether ``flash_attention(key_rope=...)`` takes latent attention of
+    these widths as projected: the pair of query heads and the key-value
+    head are whole lane tiles, as are the keys' and the values' columns."""
+    return heads % 2 == 0 and (2 * (nope + rope)) % _LANES == 0 \
+        and nope % _LANES == 0 and v_dim % _LANES == 0
+
+
+def _latent_attention(q, kv, key_rope, rotary, causal, block_q, block_k,
+                      interpret):
+    """``flash_attention(key_rope=...)``: ``_flash`` with the shared key."""
+    b, sq, hq, d = q.shape
+    sk, rope = kv.shape[1], key_rope.shape[-1]
+    v_dim = kv.shape[3] - (d - rope)
+    if rotary is None or rotary[0].shape[-1] != rope \
+            or key_rope.shape != (b, sk, rope) or kv.shape[2] != hq \
+            or not latent_fits(hq, d - rope, rope, v_dim):
+        raise ValueError(
+            f"flash_attention: latent attention of {hq} heads of {d} "
+            f"(rotary {rope}) over keys and values {kv.shape[3]} wide needs "
+            "rotary tables of the rotary key's width and heads that pair "
+            f"into whole lane tiles of {_LANES}")
+    chosen = _choose_blocks(causal)
+    bq = min(chosen[0] if block_q is None else block_q, max(8, sq))
+    bk = min(chosen[1] if block_k is None else block_k, max(8, sk))
+    o = _flash(q.reshape(b, sq, hq * d), kv.reshape(b, sk, hq * kv.shape[3]),
+               None, rotary, None, key_rope.reshape(b, 1, sk, rope),
+               (causal, None, hq, 1, bq, bk, interpret, True, 1, 1))
+    return o.reshape(b, sq, hq, v_dim)
+
+
 def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
                     interpret=None, window=None, heads_last=False,
-                    rotary=None, gate=None, step=1, halves=1):
+                    rotary=None, gate=None, step=1, halves=1, key_rope=None):
     """Blocked flash attention. q: [batch, heads, seq, head_dim]; k, v:
     [batch, kv_heads, seq, head_dim] with ``heads`` a multiple of
     ``kv_heads`` (query head ``i`` reads key-value head ``i // (heads /
@@ -1197,6 +1591,38 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
     query the clean keys of the blocks before its own and the noisy keys of
     its own block. No gate there. Sequences and blocks are whole steps.
 
+    ``key_rope`` [batch, seq, rope] is latent attention (DeepSeek-V3)
+    read as the projections leave its rows (``heads_last``, ``v`` None):
+    a head of ``q`` [batch, seq, heads, nope + rope] is ``[q_nope | q_pe]``,
+    a head of ``k`` [batch, seq, heads, nope + v_dim] is ``[k_nope | v]``,
+    and ``key_rope`` is ONE rotary key a position, already rotated, that
+    every head reads. The score is ``q_nope . k_nope + rot(q_pe) . k_pe``
+    scaled by ``(nope + rope) ** -0.5``, bfloat16 products accumulated in
+    float32; ``rotary`` (``rope`` wide) rotates ``q_pe`` on the query block
+    in VMEM. A row of the kernels' grids is a PAIR of heads (``nope +
+    rope`` need not be whole lane tiles, two heads are: 384 columns at
+    192): their query block, their key-value block (``[k_nope | v]`` twice,
+    whose columns split into the keys' and the values') and the block of
+    the one rotary key, which every pair reads; the body runs on both heads
+    at once, a leading axis of 2. Nothing is transposed, broadcast or
+    rotated in HBM but that small key. The output is ``[batch, seq, heads,
+    v_dim]`` in the rows' layout; the gradient by ``q`` comes in ``q``'s
+    layout (``flash_bwd_dq`` writes a pair's block once, ``dq_pe``
+    unrotated in the kernel, and makes the row sums of ``do * o`` from the
+    blocks it reads, a second output for ``flash_bwd_dkv``), by ``k`` in
+    ``k``'s, and by ``key_rope`` summed over every head inside
+    ``flash_bwd_dkv``, whose grid walks all the pairs of a key block.
+    ``latent_fits`` says which widths it takes. Set-up rules its kernels
+    keep, because each kernel body is traced and lowered once a layer and
+    the train step's lowering is paid in ``setup_s``: heads run on the
+    grid (no loop over heads in a body; a pair is one body); index maps
+    divide through ``_div`` / ``_mod``; no table is a host constant
+    (``rotary_tables`` builds them in the program); a pair walks larger
+    sub-tiles (``_PAIR_SUB``), so that its bodies have few pieces; nothing is
+    inserted between the caller's scope and ``flash_*/pallas_call`` in the
+    kernels' names; and the lowered size of a layer is held by a test
+    (``tests/test_tpu_lowering.py``).
+
     Exact (up to fp accumulation order) match of the dense masked softmax
     attention, with O(block) VMEM footprint. Differentiable via Pallas
     backward kernels. Block shapes are chosen here from the mask
@@ -1205,6 +1631,14 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
     """
     if interpret is None:
         interpret = _use_interpret()
+    if key_rope is not None:
+        if not heads_last or v is not None or gate is not None \
+                or window is not None or step != 1 or halves != 1:
+            raise ValueError("flash_attention: latent attention takes the "
+                             "rows as projected (heads_last, v None) and "
+                             "no gate, window or staircase")
+        return _latent_attention(q, k, key_rope, rotary, causal, block_q,
+                                 block_k, interpret)
     if (rotary is not None or gate is not None) and not (
             heads_last and q.shape[3] % _LANES == 0):
         raise ValueError("flash_attention: rotary and gate need heads_last "
@@ -1250,10 +1684,10 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
         if gate is not None:     # as the row statistics lie: a row a head
             gate = gate.transpose(0, 2, 1).reshape(b * hq, sq, 1)
         o = _flash(q.reshape(b, sq, hq * d), k.reshape(b, sk, hkv * d),
-                   v.reshape(b, sk, hkv * dv), rotary, gate, cfg)
+                   v.reshape(b, sk, hkv * dv), rotary, gate, None, cfg)
         return o.reshape(b, sq, hq, dv)
     o = _flash(q.reshape(b * hq, sq, d), k.reshape(b * hkv, sk, d),
-               v.reshape(b * hkv, sk, dv), None, None, cfg)
+               v.reshape(b * hkv, sk, dv), None, None, None, cfg)
     return o.reshape(b, hq, sq, dv)
 
 
@@ -1266,19 +1700,31 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
 # the number matches the dense attention it replaces (MFU convention:
 # model FLOPs, not grid-cell recompute).
 
-def _flash_dims(in_avals):
+def _flash_dims(in_avals, stats=None):
     """``(bh, sq, sk, d, dv)``; the values' width by its ratio to the keys'
-    (both have the key-value heads, in either layout)."""
+    (both have the key-value heads, in either layout). A latent call's
+    operands end in the shared key (batch, 1, seq, rope) and its ``k`` is
+    the key-value rows: the heads are counted from ``stats``, the row
+    statistics (batch x heads, ...) (the forward's second result, the
+    backward's fourth operand)."""
     q, k, v = in_avals[0], in_avals[1], in_avals[2]
     bh, sq, d = q.shape
     sk = k.shape[1]
+    key = in_avals[-1]
+    if len(key.shape) == 4 and key.shape[1] == 1 and key.shape[2] == sk:
+        heads = int(stats.shape[0]) // int(bh)
+        d = int(d) // heads
+        nope = d - int(key.shape[3])
+        return int(stats.shape[0]), int(sq), int(sk), d, \
+            int(k.shape[2]) // heads - nope
     return int(bh), int(sq), int(sk), int(d), d * v.shape[2] / k.shape[2]
 
 
 def _flash_fwd_cost(in_avals, out_avals):
     from .registry import KernelCost
 
-    bh, sq, sk, d, dv = _flash_dims(in_avals)
+    bh, sq, sk, d, dv = _flash_dims(
+        in_avals, out_avals[1] if len(out_avals) > 1 else None)
     # QK^T (d) and PV (dv), per batch*head slab
     return KernelCost(flops=2.0 * bh * sq * sk * (d + dv),
                       bytes=io_bytes(in_avals, out_avals))
@@ -1287,7 +1733,8 @@ def _flash_fwd_cost(in_avals, out_avals):
 def _flash_bwd_dq_cost(in_avals, out_avals):
     from .registry import KernelCost
 
-    bh, sq, sk, d, dv = _flash_dims(in_avals)
+    bh, sq, sk, d, dv = _flash_dims(
+        in_avals, in_avals[3] if len(in_avals) > 3 else None)
     # recomputed scores (d) + dp (dv) + dq accumulation (d)
     return KernelCost(flops=2.0 * bh * sq * sk * (2 * d + dv),
                       bytes=io_bytes(in_avals, out_avals))
@@ -1296,7 +1743,8 @@ def _flash_bwd_dq_cost(in_avals, out_avals):
 def _flash_bwd_dkv_cost(in_avals, out_avals):
     from .registry import KernelCost
 
-    bh, sq, sk, d, dv = _flash_dims(in_avals)
+    bh, sq, sk, d, dv = _flash_dims(
+        in_avals, in_avals[3] if len(in_avals) > 3 else None)
     # recomputed scores (d) + dp (dv) + dv (dv) + dk (d) accumulations
     return KernelCost(flops=4.0 * bh * sq * sk * (d + dv),
                       bytes=io_bytes(in_avals, out_avals))

@@ -222,6 +222,99 @@ def test_latent_attention_matches_the_reference_layer():
     assert np.all(np.abs(np.asarray(moved[T // 2:])).max(axis=(0, 2)) > 1e-3)
 
 
+def test_latent_kernels_read_the_rows_as_projected():
+    """Interpret mode, the published head widths (``[q_nope 128 | q_pe
+    64]``, ``[k_nope 128 | v 128]``, one rotary key of 64) at a small
+    length: 40 positions in blocks of 16 (the last key block padded), two
+    sequences, four heads, so that a head starting on a lane tile (even)
+    and one 64 columns into one (odd) are both read and written. The
+    output, the gradient by ``query`` in its layout, by ``key_value`` in
+    its layout and by the one rotary key, summed over the heads, against
+    dense float32 attention; the ``flash.band`` record of every latent call
+    says the shared rotary key is there, a ``RotaryAttention`` call's
+    says it is not."""
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.ops.decoder import rotary_inv_freq, rotate_heads
+
+    b, t, h, n, r, dv = 2, 40, 4, 128, 64, 128
+    rng = np.random.RandomState(12)
+    q = jnp.asarray(rng.randn(b, t, h, n + r), jnp.float32)
+    kv = jnp.asarray(rng.randn(b, t, h, n + dv), jnp.float32)
+    k_pe = jnp.asarray(rng.randn(b, t, r), jnp.float32)
+    w = jnp.asarray(rng.randn(b, t, h, dv), jnp.float32)
+    turn = rotary_tables(t, r, rotary_inv_freq(r, 1e6), interleave=True)
+
+    def ours(q, kv, k_pe):
+        return flash_attention(q, kv, None, causal=True, heads_last=True,
+                               rotary=turn, key_rope=k_pe, block_q=16,
+                               block_k=16)
+
+    def dense(q, kv, k_pe):
+        qh = jnp.concatenate([q[..., :n], rotate_heads(q[..., n:], turn)],
+                             -1).transpose(0, 2, 1, 3)
+        k = jnp.concatenate([kv[..., :n], jnp.broadcast_to(
+            k_pe[:, :, None], (b, t, h, r))], -1).transpose(0, 2, 1, 3)
+        return _dense(qh, k, kv[..., n:].transpose(0, 2, 1, 3)).transpose(
+            0, 2, 1, 3)
+
+    mark = len(telemetry.span_records())
+    assert ours(q, kv, k_pe).shape == (b, t, h, dv)
+    assert rel(ours(q, kv, k_pe), dense(q, kv, k_pe)) < 1e-5
+    got = jax.grad(lambda *a: jnp.sum(ours(*a) * w), (0, 1, 2))(q, kv, k_pe)
+    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(
+        q, kv, k_pe)
+    for g, e in zip(got, want):
+        assert g.shape == e.shape and rel(g, e) < 1e-5
+    # the odd heads' halves of the pair blocks, each head's own gradient
+    for head in range(h):
+        assert rel(got[0][:, :, head], want[0][:, :, head]) < 1e-5
+        assert rel(got[1][:, :, head], want[1][:, :, head]) < 1e-5
+    bands = [rec["attrs"] for rec in telemetry.span_records()[mark:]
+             if rec["name"] == "flash.band"]
+    assert {a["kernel"] for a in bands} == {"flash_fwd", "flash_bwd_dq",
+                                            "flash_bwd_dkv"}
+    assert all((a["rope_dim"], a["rope_shared"]) == (64, 1) for a in bands)
+    # the registry prices the calls at keys of 192 and values of 128, the
+    # padded 48 positions
+    from mxnet_tpu.ops.pallas.registry import attribute_eqn
+
+    flops = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            hit = attribute_eqn(eqn)
+            if hit:
+                flops[hit[0]] = hit[1].flops
+            for p in eqn.params.values():
+                for sub in p if isinstance(p, (tuple, list)) else [p]:
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns") and not hit:
+                        walk(sub)
+
+    walk(jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(ours(*a) * w),
+                                 (0, 1, 2)))(q, kv, k_pe).jaxpr)
+    unit = 2.0 * b * h * 48 * 48
+    assert flops == {"flash_fwd": unit * (192 + 128),
+                     "flash_bwd_dq": unit * (2 * 192 + 128),
+                     "flash_bwd_dkv": unit * 2 * (192 + 128)}
+    # a call without the shared key: 0 / 0
+    mark = len(telemetry.span_records())
+    op = OPS.create("RotaryAttention", seq_len=t, num_heads=2,
+                    num_kv_heads=1, head_dim=128, rotary_dim=128)
+    x = jnp.asarray(rng.randn(t, 256), jnp.float32)
+    op.fwd([x, x[:, :128], x[:, 128:]], [], True, None)
+    bands = [rec["attrs"] for rec in telemetry.span_records()[mark:]
+             if rec["name"] == "flash.band"]
+    assert bands and all((a["rope_dim"], a["rope_shared"]) == (0, 0)
+                         for a in bands)
+    with pytest.raises(ValueError, match="latent attention"):
+        flash_attention(q[:, :, :3], kv[:, :, :3], None, causal=True,
+                        heads_last=True, rotary=turn, key_rope=k_pe)
+    with pytest.raises(ValueError, match="latent attention"):
+        flash_attention(q, kv, None, causal=True, heads_last=True,
+                        rotary=turn, key_rope=k_pe, window=8)
+
+
 # -- the selection bias ---------------------------------------------------------
 
 def _moe(held=16, first=0, selection="biased"):

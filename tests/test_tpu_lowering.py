@@ -118,8 +118,9 @@ def _latent_attention_case(S):
     """Latent attention at the published widths
     (``kanana2_30b_a3b.seq8k``): 8,192 positions, 32 heads whose queries
     and keys are 192 wide (128 of their own and the one rotary key of 64
-    that every head reads) and whose values are 128, heads first in the
-    kernels. Forward and both backward kernels."""
+    that every head reads) and whose values are 128, the rows read by the
+    kernels as the projections leave them. Forward and both backward
+    kernels."""
     from mxnet_tpu.ops import OPS
 
     op = OPS.create("LatentAttention", seq_len=8192, num_heads=32,
@@ -368,7 +369,12 @@ def test_latent_attention_runs_under_its_operators_scope(v5e, monkeypatch):
     own step builder, compiled for v5e: the three flash kernels of every
     layer carry ``layer<l>_attn/LatentAttention``, the scope the
     benchmark's readers look under (``benchmark/latent_costs.py``), and
-    the forward kernel is not run again in the backward pass."""
+    the forward kernel is not run again in the backward pass. The kernels
+    read the rows as projected: under that scope the optimized HLO holds
+    no ``transpose`` and no ``broadcast`` of an array the size of the
+    queries, the keys and values, or the output (the one rotary key, 64
+    columns, and its tables are all that XLA moves there)."""
+    import math
     import re
     import sys
 
@@ -388,9 +394,59 @@ def test_latent_attention_runs_under_its_operators_scope(v5e, monkeypatch):
     shapes = {"data": (1, 1024), "softmax_label": (1, 1024)}
     run, *_, args = _train_step(v5e, model, shapes, dict.fromkeys(
         shapes, jnp.int32))
-    found = scopes.hlo_scopes(run._tracked.precompile(*args).as_text())
+    text = run._tracked.precompile(*args).as_text()
+    found = scopes.hlo_scopes(text)
     flash = sorted(v for k, v in found.items() if k.startswith("flash_"))
     pattern = r"layer([01])_attn/LatentAttention\)?/(flash_\w+)/pallas_call"
     assert sorted(re.search(pattern, v).groups() for v in flash) == [
         (l, k) for l in "01"
         for k in ("flash_bwd_dkv", "flash_bwd_dq", "flash_fwd")]
+    # the output (1,024 x 2 heads x 128) is the smallest of the three
+    smallest = 1024 * 2 * 128
+    moved = []
+    for line in text.splitlines():
+        op = re.search(r"= (\w+)\[([\d,]*)\]\{?[^ ]* (transpose|broadcast)\(",
+                       line)
+        if op and "LatentAttention" in line and math.prod(
+                int(x) for x in op.group(2).split(",") if x) >= smallest:
+            moved.append(line.strip()[:160])
+    assert not moved, moved[:3]
+
+
+def test_a_latent_layer_lowers_to_a_bounded_program(v5e, monkeypatch):
+    """The set-up cost of the latent kernels, held off the chip: the
+    ``flash_latent`` case (one cell-shaped layer, forward and gradients)
+    lowers to at most 1.5 x the 387,600 characters of StableHLO text that
+    the heads-first kernels before it lowered to, and its three kernel
+    bodies trace to no more equations than those kernels' 889. A kernel
+    body is unrolled for every tile pattern, strip and piece and traced and
+    lowered again in every layer, and the train step's lowering time
+    follows the equations: a body that grows a product, a loop over heads
+    or a division a piece fails here instead of in ``setup_s`` on the
+    chip. Measured (jax 0.9.0): 93,214 characters (their locations' paths
+    make a few thousand) and 823 equations (358 / 221 / 244); the
+    heads-first kernels 389,355 and 889; a pair walked by sub-tiles of 256
+    in the backward kernels 1,073, and of 128 in the forward too 1,367."""
+    monkeypatch.setenv("MXNET_TPU_PALLAS_INTERPRET", "0")
+
+    def S(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    fn, args = _latent_attention_case(S)
+    text = jax.jit(fn).lower(*args).as_text()
+    assert text.count("@tpu_custom_call") == 3
+    assert len(text) <= 1.5 * 387_600, len(text)
+
+    def equations(jaxpr, inside=False):
+        n = 0
+        for eqn in jaxpr.eqns:
+            n += inside
+            kernel = eqn.primitive.name == "pallas_call"
+            for p in eqn.params.values():
+                for sub in p if isinstance(p, (tuple, list)) else (p,):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        n += equations(sub, inside or kernel)
+        return n
+
+    assert equations(jax.make_jaxpr(fn)(*args).jaxpr) <= 889
