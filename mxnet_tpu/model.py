@@ -2967,199 +2967,212 @@ class FeedForward(BASE_ESTIMATOR):
         Returns ``{"programs", "wall_seconds", "labels"}``. With the
         persistent compilation cache (utils/compile.py) the first process
         pays XLA once, every later precompile deserializes from disk.
+
+        The call is one ``setup.precompile`` span (attr ``programs``);
+        inside it ``setup.init_params``, a ``setup.build_step`` for each
+        program's step builder and a ``setup.compile`` for each program's
+        trace, lowering and compile (``TrackedJit.precompile``).
         """
-        if isinstance(kvstore, str) and "dist" in kvstore:
-            raise MXNetError(
-                "precompile: multi-process kvstore strategies must warm up "
-                "inside the launched job (the mesh spans processes); call "
-                "precompile there, or rely on the persistent cache")
-        programs = []
-        if data is not None:
-            if hasattr(data, "bucket_shapes"):
-                programs = [(bk, dict(d), dict(l))
-                            for bk, d, l in data.bucket_shapes()]
-            else:
-                programs = [(None, dict(data.provide_data),
-                             dict(data.provide_label))]
-        elif data_shapes:
-            programs = [(None, dict(data_shapes), dict(label_shapes or {}))]
-        if not programs:
-            raise MXNetError(
-                "precompile: pass data_shapes (+label_shapes) or "
-                "data=<DataIter>")
-
-        def _split(spec):
-            # shape, or (shape, dtype)
-            if (isinstance(spec, tuple) and len(spec) == 2
-                    and isinstance(spec[0], (tuple, list))):
-                return tuple(spec[0]), np.dtype(spec[1])
-            return tuple(spec), np.dtype(np.float32)
-
-        guard_cfg = guards_mod.GuardConfig.resolve(guards)
-        pad_policy = compile_mod.PadPolicy.resolve(pad_policy)
-        health_cfg = telemetry_mod.HealthConfig.resolve(health)
-        from . import comm as comm_mod
-
-        comm_spec = comm_mod.CompressionSpec.resolve(compression)
-        overlap_cfg = comm_mod.OverlapConfig.resolve(overlap)
-        kern_cfg = comm_mod.CommKernelConfig.resolve(comm_kernels)
-        metric = metric_mod.create(eval_metric)
-        # same fusion decision as fit(): a batch callback needs per-batch
-        # host metric values, so the metric stays out of the step program
-        use_device_metric = (metric.device_supported
-                             and batch_end_callback is None
-                             and (pad_policy is None
-                                  or metric.device_mask_supported))
-
-        if data is not None:
-            init_shapes = {**dict(data.provide_data),
-                           **dict(data.provide_label)}
-        else:
-            init_shapes = {k: _split(v)[0]
-                           for k, v in {**programs[0][1],
-                                        **programs[0][2]}.items()}
-        param_names, aux_names = self._init_params(init_shapes)
-        first_shape = _split(next(iter(programs[0][1].values())))[0]
-        batch_size = int(first_shape[0])
-        mesh = self._make_mesh(dist=False)
-        if mesh is None:
-            comm_spec = None  # matches fit(): no mesh, no wire, no comm
-        overlap_plan = None
-        if comm_spec is not None and overlap_cfg is not None:
-            # the EXACT plan fit() will build — same symbol order, shapes,
-            # cap — so the warmed program is the one fit dispatches
-            overlap_plan = comm_mod.plan_overlap(
-                {k: tuple(self.arg_params[k].shape) for k in param_names},
-                comm_spec, int(mesh.shape["dp"]),
-                max_bytes=overlap_cfg.bucket_bytes, symbol=self.symbol)
-        optimizer = self._resolve_optimizer(param_names, batch_size)
-
-        def _sds(shape, dtype, sharded=False):
-            if mesh is None:
-                return jax.ShapeDtypeStruct(shape, dtype)
-            sh = NamedSharding(mesh, P("dp") if sharded else P())
-            return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
-
-        order = self._state_order()
-        params_s = {k: _sds(tuple(self.arg_params[k].shape[a]
-                                  for a in order.get(
-                                      k, range(self.arg_params[k].ndim))),
-                            self.arg_params[k].dtype) for k in param_names}
-        aux_s = {k: _sds(tuple(self.aux_params[k].shape),
-                         self.aux_params[k].dtype) for k in aux_names}
-        opt_state_s = jax.eval_shape(optimizer.init_state_tree, params_s)
-        if mesh is not None:
-            opt_state_s = jax.tree_util.tree_map(
-                lambda s: _sds(tuple(s.shape), s.dtype), opt_state_s)
-        rng_s = _sds((2,), np.dtype(np.uint32))
-        lr_s = _sds((), np.dtype(np.float32))
-        mstate = metric.device_init()
-        mstate_s = jax.tree_util.tree_map(
-            lambda x: _sds(tuple(x.shape), np.dtype(x.dtype)), mstate)
-
-        jobs = []
-        ef_resid_struct = None  # the EF residual shape the warmup lowers for
-        for bkey, d, l in programs:
-            data_names_p = list(d)
-            label_names_p = list(l)
-            step = self._get_train_step(
-                bkey, data_names_p, label_names_p, optimizer, mesh,
-                metric=metric if use_device_metric else None,
-                apply_update=True, guard_cfg=guard_cfg,
-                pad_policy=pad_policy, compression=comm_spec,
-                overlap_plan=overlap_plan, comm_kernels=kern_cfg,
-                health_cfg=health_cfg)
-            batch_s = {}
-            for name, spec in {**d, **l}.items():
-                shape, dtype = _split(spec)
-                batch_s[name] = _sds(shape, dtype, sharded=True)
-            args = (params_s, opt_state_s, aux_s, batch_s, rng_s, lr_s,
-                    mstate_s)
-            if guard_cfg is not None:
-                args += (guards_mod.init_guard_state(guard_cfg),)
-            if comm_spec is not None and comm_spec.error_feedback:
-                ndev = int(mesh.shape["dp"])
-                if overlap_plan is not None:
-                    resid_s = {name: _sds((ndev, lp), np.dtype(np.float32),
-                                          sharded=True)
-                               for name, lp
-                               in overlap_plan.padded_sizes().items()}
-                    args += ({"resid": resid_s},)
+        with telemetry_mod.phase("setup.precompile") as span:
+            if isinstance(kvstore, str) and "dist" in kvstore:
+                raise MXNetError(
+                    "precompile: multi-process kvstore strategies must warm "
+                    "up inside the launched job (the mesh spans processes); "
+                    "call precompile there, or rely on the persistent cache")
+            programs = []
+            if data is not None:
+                if hasattr(data, "bucket_shapes"):
+                    programs = [(bk, dict(d), dict(l))
+                                for bk, d, l in data.bucket_shapes()]
                 else:
-                    Lp = comm_mod.padded_flat_size(
-                        sum(int(np.prod(self.arg_params[k].shape))
-                            for k in param_names), comm_spec, ndev)
-                    args += ({"resid": _sds((ndev, Lp),
-                                            np.dtype(np.float32),
-                                            sharded=True)},)
-                ef_resid_struct = args[-1]["resid"]
-            if health_cfg is not None:
-                groups = telemetry_mod.health.layer_groups(param_names)
-                hs = telemetry_mod.health.init_device_stats(groups)
-                args += (jax.tree_util.tree_map(
-                    lambda x: _sds(tuple(x.shape), np.dtype(x.dtype)), hs),)
-            if pad_policy is not None:
-                args += (_sds((), np.dtype(np.int32)),)
-            jobs.append((step._tracked, args))
+                    programs = [(None, dict(data.provide_data),
+                                 dict(data.provide_label))]
+            elif data_shapes:
+                programs = [(None, dict(data_shapes),
+                             dict(label_shapes or {}))]
+            if not programs:
+                raise MXNetError(
+                    "precompile: pass data_shapes (+label_shapes) or "
+                    "data=<DataIter>")
 
-        def _compile(tj, args):
-            # the lowering and the XLA compile, or the persistent cache's read
-            with telemetry_mod.phase("setup.compile", label=tj.label):
-                tj.precompile(*args)
+            def _split(spec):
+                # shape, or (shape, dtype)
+                if (isinstance(spec, tuple) and len(spec) == 2
+                        and isinstance(spec[0], (tuple, list))):
+                    return tuple(spec[0]), np.dtype(spec[1])
+                return tuple(spec), np.dtype(np.float32)
 
-        t0 = time.time()
-        if parallel and len(jobs) > 1:
-            import concurrent.futures as cf
+            guard_cfg = guards_mod.GuardConfig.resolve(guards)
+            pad_policy = compile_mod.PadPolicy.resolve(pad_policy)
+            health_cfg = telemetry_mod.HealthConfig.resolve(health)
+            from . import comm as comm_mod
 
-            workers = min(len(jobs), int(os.environ.get(
-                "MXNET_TPU_PRECOMPILE_THREADS", "4")))
-            with cf.ThreadPoolExecutor(max_workers=workers,
-                                       thread_name_prefix="mx-precompile") \
-                    as pool:
-                futures = [pool.submit(_compile, tj, args)
-                           for tj, args in jobs]
-                for f in futures:
-                    f.result()
-        else:
-            for tj, args in jobs:
-                _compile(tj, args)
-        wall = time.time() - t0
-        logging.info("precompile: %d program(s) ready in %.2fs", len(jobs),
-                     wall)
-        # OOM preflight over the EXACT warmed programs: every job just
-        # registered its memory plan, so the check uses real temp/output
-        # bytes — reject an over-budget configuration here, before fit
-        # dispatches a single step (ISSUE 9)
-        hbm_budget = telemetry_mod.memory.hbm_budget()
-        if hbm_budget:
-            plan_label, plan = telemetry_mod.memory.largest_plan(
-                labels=[tj.label for tj, _ in jobs])
-            entries = telemetry_mod.memory.preflight_entries(
-                params_s, opt_state_s, aux_s,
-                resid=ef_resid_struct,
-                ndev=int(mesh.shape["dp"]) if mesh is not None else 1,
-                plan_label=plan_label, plan=plan)
-            telemetry_mod.memory.preflight(entries, hbm_budget,
-                                           what="precompile")
-        # opt-in shard audit over the EXACT warmed executables (ISSUE 16):
-        # shard_audit=True / MXNET_TPU_SHARD_AUDIT raises on MX802 drift;
-        # shard_audit="report" collects findings without raising (the
-        # --shardcheck CLI path)
-        from .analysis.sharding import shard_audit_enabled
-        report_only = shard_audit == "report"
-        shard_reports = []
-        if (report_only or shard_audit_enabled(shard_audit)) \
-                and mesh is not None:
-            flat_elems = sum(int(np.prod(self.arg_params[k].shape))
-                             for k in param_names)
-            for tj, args in jobs:
-                shard_reports.append(self._shard_audit_program(
-                    tj, args, mesh=mesh, comm_spec=comm_spec,
-                    overlap_plan=overlap_plan, flat_elems=flat_elems,
-                    raise_on_error=not report_only))
-        return {"programs": len(jobs), "wall_seconds": wall,
-                "labels": [tj.label for tj, _ in jobs],
-                "shard_audit": shard_reports}
+            comm_spec = comm_mod.CompressionSpec.resolve(compression)
+            overlap_cfg = comm_mod.OverlapConfig.resolve(overlap)
+            kern_cfg = comm_mod.CommKernelConfig.resolve(comm_kernels)
+            metric = metric_mod.create(eval_metric)
+            # same fusion decision as fit(): a batch callback needs per-batch
+            # host metric values, so the metric stays out of the step program
+            use_device_metric = (metric.device_supported
+                                 and batch_end_callback is None
+                                 and (pad_policy is None
+                                      or metric.device_mask_supported))
+
+            if data is not None:
+                init_shapes = {**dict(data.provide_data),
+                               **dict(data.provide_label)}
+            else:
+                init_shapes = {k: _split(v)[0]
+                               for k, v in {**programs[0][1],
+                                            **programs[0][2]}.items()}
+            param_names, aux_names = self._init_params(init_shapes)
+            first_shape = _split(next(iter(programs[0][1].values())))[0]
+            batch_size = int(first_shape[0])
+            mesh = self._make_mesh(dist=False)
+            if mesh is None:
+                comm_spec = None  # matches fit(): no mesh, no wire, no comm
+            overlap_plan = None
+            if comm_spec is not None and overlap_cfg is not None:
+                # the EXACT plan fit() will build — same symbol order, shapes,
+                # cap — so the warmed program is the one fit dispatches
+                overlap_plan = comm_mod.plan_overlap(
+                    {k: tuple(self.arg_params[k].shape) for k in param_names},
+                    comm_spec, int(mesh.shape["dp"]),
+                    max_bytes=overlap_cfg.bucket_bytes, symbol=self.symbol)
+            optimizer = self._resolve_optimizer(param_names, batch_size)
+
+            def _sds(shape, dtype, sharded=False):
+                if mesh is None:
+                    return jax.ShapeDtypeStruct(shape, dtype)
+                sh = NamedSharding(mesh, P("dp") if sharded else P())
+                return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+            order = self._state_order()
+            params_s = {k: _sds(tuple(self.arg_params[k].shape[a]
+                                      for a in order.get(
+                                          k, range(self.arg_params[k].ndim))),
+                                self.arg_params[k].dtype) for k in param_names}
+            aux_s = {k: _sds(tuple(self.aux_params[k].shape),
+                             self.aux_params[k].dtype) for k in aux_names}
+            opt_state_s = jax.eval_shape(optimizer.init_state_tree, params_s)
+            if mesh is not None:
+                opt_state_s = jax.tree_util.tree_map(
+                    lambda s: _sds(tuple(s.shape), s.dtype), opt_state_s)
+            rng_s = _sds((2,), np.dtype(np.uint32))
+            lr_s = _sds((), np.dtype(np.float32))
+            mstate = metric.device_init()
+            mstate_s = jax.tree_util.tree_map(
+                lambda x: _sds(tuple(x.shape), np.dtype(x.dtype)), mstate)
+
+            jobs = []
+            ef_resid_struct = None  # the EF residual the warmup lowers for
+            for bkey, d, l in programs:
+                data_names_p = list(d)
+                label_names_p = list(l)
+                with telemetry_mod.phase("setup.build_step") as built:
+                    step = self._get_train_step(
+                        bkey, data_names_p, label_names_p, optimizer, mesh,
+                        metric=metric if use_device_metric else None,
+                        apply_update=True, guard_cfg=guard_cfg,
+                        pad_policy=pad_policy, compression=comm_spec,
+                        overlap_plan=overlap_plan, comm_kernels=kern_cfg,
+                        health_cfg=health_cfg)
+                    built.attrs["label"] = step._tracked.label
+                batch_s = {}
+                for name, spec in {**d, **l}.items():
+                    shape, dtype = _split(spec)
+                    batch_s[name] = _sds(shape, dtype, sharded=True)
+                args = (params_s, opt_state_s, aux_s, batch_s, rng_s, lr_s,
+                        mstate_s)
+                if guard_cfg is not None:
+                    args += (guards_mod.init_guard_state(guard_cfg),)
+                if comm_spec is not None and comm_spec.error_feedback:
+                    ndev = int(mesh.shape["dp"])
+                    if overlap_plan is not None:
+                        resid_s = {name: _sds((ndev, lp),
+                                              np.dtype(np.float32),
+                                              sharded=True)
+                                   for name, lp
+                                   in overlap_plan.padded_sizes().items()}
+                        args += ({"resid": resid_s},)
+                    else:
+                        Lp = comm_mod.padded_flat_size(
+                            sum(int(np.prod(self.arg_params[k].shape))
+                                for k in param_names), comm_spec, ndev)
+                        args += ({"resid": _sds((ndev, Lp),
+                                                np.dtype(np.float32),
+                                                sharded=True)},)
+                    ef_resid_struct = args[-1]["resid"]
+                if health_cfg is not None:
+                    groups = telemetry_mod.health.layer_groups(param_names)
+                    hs = telemetry_mod.health.init_device_stats(groups)
+                    args += (jax.tree_util.tree_map(
+                        lambda x: _sds(tuple(x.shape), np.dtype(x.dtype)),
+                        hs),)
+                if pad_policy is not None:
+                    args += (_sds((), np.dtype(np.int32)),)
+                jobs.append((step._tracked, args))
+            span.attrs["programs"] = len(jobs)
+
+            def _compile(tj, args):
+                # the trace, the lowering and the XLA compile or the
+                # persistent cache's read: a setup.compile.* span each
+                with telemetry_mod.phase("setup.compile", label=tj.label):
+                    tj.precompile(*args)
+
+            t0 = time.time()
+            if parallel and len(jobs) > 1:
+                import concurrent.futures as cf
+
+                workers = min(len(jobs), int(os.environ.get(
+                    "MXNET_TPU_PRECOMPILE_THREADS", "4")))
+                with cf.ThreadPoolExecutor(
+                        max_workers=workers,
+                        thread_name_prefix="mx-precompile") as pool:
+                    futures = [pool.submit(_compile, tj, args)
+                               for tj, args in jobs]
+                    for f in futures:
+                        f.result()
+            else:
+                for tj, args in jobs:
+                    _compile(tj, args)
+            wall = time.time() - t0
+            logging.info("precompile: %d program(s) ready in %.2fs", len(jobs),
+                         wall)
+            # OOM preflight over the EXACT warmed programs: every job just
+            # registered its memory plan, so the check uses real temp/output
+            # bytes — reject an over-budget configuration here, before fit
+            # dispatches a single step
+            hbm_budget = telemetry_mod.memory.hbm_budget()
+            if hbm_budget:
+                plan_label, plan = telemetry_mod.memory.largest_plan(
+                    labels=[tj.label for tj, _ in jobs])
+                entries = telemetry_mod.memory.preflight_entries(
+                    params_s, opt_state_s, aux_s,
+                    resid=ef_resid_struct,
+                    ndev=int(mesh.shape["dp"]) if mesh is not None else 1,
+                    plan_label=plan_label, plan=plan)
+                telemetry_mod.memory.preflight(entries, hbm_budget,
+                                               what="precompile")
+            # opt-in shard audit over the EXACT warmed executables:
+            # shard_audit=True / MXNET_TPU_SHARD_AUDIT raises on MX802 drift;
+            # shard_audit="report" collects findings without raising (the
+            # --shardcheck CLI path)
+            from .analysis.sharding import shard_audit_enabled
+            report_only = shard_audit == "report"
+            shard_reports = []
+            if (report_only or shard_audit_enabled(shard_audit)) \
+                    and mesh is not None:
+                flat_elems = sum(int(np.prod(self.arg_params[k].shape))
+                                 for k in param_names)
+                for tj, args in jobs:
+                    shard_reports.append(self._shard_audit_program(
+                        tj, args, mesh=mesh, comm_spec=comm_spec,
+                        overlap_plan=overlap_plan, flat_elems=flat_elems,
+                        raise_on_error=not report_only))
+            return {"programs": len(jobs), "wall_seconds": wall,
+                    "labels": [tj.label for tj, _ in jobs],
+                    "shard_audit": shard_reports}
 
     def _shard_audit_program(self, tracked, args, *, mesh, comm_spec,
                              overlap_plan, flat_elems, raise_on_error=True,

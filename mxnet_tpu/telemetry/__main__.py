@@ -665,7 +665,7 @@ def main(argv=None):
                     help="gate/compare only records of this graph "
                          "fingerprint")
     lg.add_argument("--kind", default=None,
-                    choices=("fit", "predict", "bench"))
+                    choices=("fit", "predict"))
     lg.add_argument("--world", type=int, default=None)
     lg.add_argument("--backend", default=None)
     lg.add_argument("--metric", default="step_ms_p50",

@@ -75,7 +75,7 @@ EVENT_GOLDEN_KEYS = {
     "profile": ("phase", "steps", "device_ms", "coverage_pct"),
     # cross-run ledger (ISSUE 20): one event per appended RunRecord —
     # the in-stream pointer joining a JSONL trace to its ledger entry
-    # (source = "fit" | "predict" | "bench")
+    # (source = "fit" | "predict")
     "run_summary": ("run_id", "fingerprint", "backend", "source"),
 }
 

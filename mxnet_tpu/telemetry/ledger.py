@@ -344,7 +344,7 @@ def read_ledger(directory=None, logger=None):
 
 
 def match(records, fingerprint=None, world=None, backend=None, kind=None,
-          bench_metric=None, completed=None):
+          completed=None):
     """Filter records on identity — trend/compare/warm-start must only
     reason across runs of the SAME program shape."""
     out = []
@@ -356,9 +356,6 @@ def match(records, fingerprint=None, world=None, backend=None, kind=None,
         if backend is not None and r.get("backend") != backend:
             continue
         if kind is not None and r.get("kind") != kind:
-            continue
-        if bench_metric is not None and \
-                r.get("outcomes", {}).get("metric") != bench_metric:
             continue
         if completed is not None and \
                 bool(r.get("completed", True)) != bool(completed):

@@ -48,7 +48,8 @@ from . import distributed as _dist
 from .hub import hub as _hub
 
 __all__ = ["Span", "StepTimeline", "current_span", "clear_current_span",
-           "phase", "timed", "span_records", "spans_dropped"]
+           "phase", "timed", "span_records", "spans_dropped",
+           "record_compile"]
 
 _TLS = threading.local()
 
@@ -167,6 +168,29 @@ def span_records(since=None):
 def spans_dropped():
     """How many of the oldest span records the ring (16,384) has let go."""
     return _hub().spans_dropped()
+
+
+def record_compile(seconds, **attrs):
+    """One ``compile.backend`` record in the span ring for an XLA compile
+    (or persistent-cache read) of ``seconds`` that has just ended on this
+    thread: ``end`` is now, ``start`` is ``end - seconds``, and ``thread``,
+    ``parent``, ``epoch`` and ``step`` come from the phases open here, so a
+    compile inside ``fit`` names its step. Nothing is recorded when the
+    innermost open phase is ``setup.compile.backend``, which times the
+    same compile."""
+    end = time.perf_counter()
+    tls = _TLS
+    stack = getattr(tls, "phases", None)
+    if stack:
+        top = stack[-1]
+        if top.name == "setup.compile.backend":
+            return
+        parent, epoch, step = top.name, top.epoch, top.step
+    else:
+        parent = epoch = step = None
+    thread = getattr(tls, "thread", None) or threading.current_thread().name
+    _hub().record_span(("compile.backend", thread, end - seconds, end, parent,
+                        epoch, step, attrs), seconds)
 
 
 @contextlib.contextmanager

@@ -28,12 +28,14 @@ ops"); the TPU-native answer is four cooperating pieces:
      ``compile_stats()`` answers "what compiled, when, for how long" for
      the whole process.
 
-  3. **AOT warmup** — :meth:`TrackedJit.precompile` lowers + compiles a
-     program ahead of time (``.lower().compile()``) and keeps the
-     executable for signature-matched dispatch, so ``FeedForward
-     .precompile()`` / ``Executor.precompile()`` can compile every
-     bucket/eval program up front (and in parallel threads) instead of
-     stalling step 1 of each shape.
+  3. **AOT warmup** — :meth:`TrackedJit.precompile` traces, lowers and
+     compiles a program ahead of time (``.trace().lower().compile()``,
+     each stage a ``telemetry.phase()``) and keeps the executable for
+     signature-matched dispatch, so ``FeedForward.precompile()`` /
+     ``Executor.precompile()`` can compile every bucket/eval program up
+     front (and in parallel threads) instead of stalling step 1 of each
+     shape. Every other XLA compile leaves one ``compile.backend`` record
+     in the same span ring (the registry's ``jax.monitoring`` listener).
 
   4. **PadPolicy + RecompileTracker** — the policy folds odd shapes into
      known ones (pad-to-bucket, or next-pow2 to bound the program count
@@ -41,9 +43,10 @@ ops"); the TPU-native answer is four cooperating pieces:
      miss, logs it, and — armed in tests — turns "zero recompiles in steady
      state" from a hope into an enforced invariant.
 
-This module deliberately imports only jax + stdlib so every layer
-(executor, model, bucketing, io, monitor, bench) can use it without import
-cycles.
+This module deliberately imports only jax + stdlib at import time so every
+layer (executor, model, bucketing, io, monitor, bench) can use it without
+import cycles; the span primitive (``telemetry.timeline``) is imported
+where a compile uses it.
 """
 
 from __future__ import annotations
@@ -53,7 +56,6 @@ import hashlib
 import logging
 import os
 import threading
-import time
 
 import jax
 import numpy as np
@@ -116,6 +118,26 @@ def persistent_cache_dir():
 
 _UNTRACKED = "<untracked>"
 
+# the jax.monitoring events the registry reads, by a part of their names
+# (every other name, among them the per-jaxpr trace and lowering
+# durations, returns at once)
+_BACKEND_COMPILE = "backend_compile_duration"
+_TIME_SAVED = "compile_time_saved"
+_CACHE_ASKED = "/compile_requests_use_cache"
+_CACHE_HIT = "/cache_hits"
+
+
+def _cache_outcome(before, after):
+    """``"off"`` when no compile between the two marks asked the
+    persistent cache or it has no directory (JAX asks it all the same),
+    ``"hit"`` when every one that asked was served from it, else
+    ``"miss"``."""
+    asked, hits = after[0] - before[0], after[1] - before[1]
+    if not asked or not persistent_cache_dir():
+        return "off"
+    return "hit" if hits == asked else "miss"
+
+
 # Static memory plans (ISSUE 9): every AOT-compiled program registers its
 # XLA memory_analysis() breakdown here, keyed by the same program label as
 # the compile stats — the framework's answer to the reference's
@@ -174,7 +196,10 @@ class ProgramRegistry:
     are credited to whichever tracked program is currently dispatching on
     this thread (``<untracked>`` otherwise — e.g. op-by-op jnp dispatch).
     Persistent-cache hits and saved seconds are folded in from the same
-    event stream.
+    event stream. Each such compile outside ``TrackedJit.precompile``'s
+    own ``setup.compile.backend`` span also leaves one ``compile.backend``
+    record in the telemetry span ring (``label``, ``fun``: jax's name of
+    the function, ``cache``: ``hit`` / ``miss`` / ``off``).
     """
 
     def __init__(self):
@@ -203,27 +228,47 @@ class ProgramRegistry:
         finally:
             stack.pop()
 
+    def cache_marks(self):
+        """``(requests, hits)`` of the persistent cache so far on this
+        thread: its events fire on the compiling thread, before the
+        compile's ``backend_compile_duration``."""
+        tls = self._tls
+        return getattr(tls, "cache_asked", 0), getattr(tls, "cache_hits", 0)
+
     # -- event sinks (wired to jax.monitoring once, below) --------------------
-    def _on_duration(self, name, seconds):
-        if "backend_compile_duration" in name:
+    def _on_duration(self, name, seconds, fun_name=None, **_):
+        if _BACKEND_COMPILE in name:
+            # telemetry imports this module while it loads
+            from ..telemetry import timeline
+
             label = self.current_label() or _UNTRACKED
+            marks = self.cache_marks()
+            cache = _cache_outcome(
+                getattr(self._tls, "cache_at_compile", (0, 0)), marks)
+            self._tls.cache_at_compile = marks
             with self._lock:
                 c = self._labels.setdefault(label, _label_counters())
                 c["compiles"] += 1
                 c["compile_seconds"] += seconds
                 self._totals["compiles"] += 1
                 self._totals["compile_seconds"] += seconds
-        elif "compile_time_saved" in name:
+            timeline.record_compile(seconds, label=label, fun=fun_name,
+                                    cache=cache)
+        elif _TIME_SAVED in name:
             with self._lock:
                 self._totals["persistent_cache_saved_seconds"] += seconds
 
-    def _on_event(self, name):
-        if name.endswith("/cache_hits"):
+    def _on_event(self, name, **_):
+        tls = self._tls
+        if name.endswith(_CACHE_ASKED):
+            tls.cache_asked = getattr(tls, "cache_asked", 0) + 1
+        elif name.endswith(_CACHE_HIT):
+            tls.cache_hits = getattr(tls, "cache_hits", 0) + 1
             with self._lock:
                 self._totals["persistent_cache_hits"] += 1
 
     # -- dispatch accounting --------------------------------------------------
-    def record_call(self, label, kind, seconds=0.0, signature=None):
+    def record_call(self, label, kind, signature=None):
         """kind: 'hit' | 'miss' | 'aot_hit' | 'precompile'."""
         with self._lock:
             c = self._labels.setdefault(label, _label_counters())
@@ -310,10 +355,8 @@ def _install_listeners(reg):
         return
     from jax import monitoring
 
-    monitoring.register_event_duration_secs_listener(
-        lambda name, secs, **kw: reg._on_duration(name, secs))
-    monitoring.register_event_listener(
-        lambda name, **kw: reg._on_event(name))
+    monitoring.register_event_duration_secs_listener(reg._on_duration)
+    monitoring.register_event_listener(reg._on_event)
     _LISTENERS_INSTALLED = True
 
 
@@ -377,10 +420,11 @@ class TrackedJit:
       call as a cache hit or miss (miss = the jit trace cache grew during
       the call, i.e. a compile happened) and crediting compile-seconds to
       this program's label.
-    - ``precompile(*abstract_args)`` lowers + compiles ahead of time
-      (``.lower().compile()``) and keeps the executable; later calls whose
-      argument signature matches dispatch straight to it — the jit cache is
-      never consulted, so step 1 of a warmed shape pays zero compile.
+    - ``precompile(*abstract_args)`` traces, lowers and compiles ahead of
+      time (``.trace().lower().compile()``) and keeps the executable;
+      later calls whose argument signature matches dispatch straight to
+      it — the jit cache is never consulted, so step 1 of a warmed shape
+      pays zero compile.
     """
 
     def __init__(self, fn, label=None, registry_=None, **jit_kwargs):
@@ -427,14 +471,9 @@ class TrackedJit:
                         return out
         before = self._cache_size()
         with reg.attribute(self.label):
-            t0 = time.perf_counter()
             out = self._jitted(*args, **kwargs)
-            # intentionally un-barriered: this measures the HOST-side cost
-            # of the dispatch (trace + compile on a miss), which is
-            # synchronous — execution time is the profiler's job
-            dt = time.perf_counter() - t0  # mxlint: disable=MX306
         if self._cache_size() > before:
-            reg.record_call(self.label, "miss", seconds=dt,
+            reg.record_call(self.label, "miss",
                             signature=self.signature(args, kwargs))
         else:
             reg.record_call(self.label, "hit")
@@ -446,24 +485,40 @@ class TrackedJit:
     def precompile(self, *args, **kwargs):
         """AOT-compile for the given (abstract or concrete) arguments and
         register the executable for signature-matched dispatch. Idempotent
-        per signature; returns the compiled executable."""
+        per signature; returns the compiled executable.
+
+        The three stages are spans on the calling thread, each with this
+        program's ``label``: ``setup.compile.trace`` (the jaxpr: every
+        operator's Python and every Pallas kernel body runs here),
+        ``setup.compile.lower`` (StableHLO with the Mosaic payloads) and
+        ``setup.compile.backend`` (XLA's compile or the persistent cache's
+        read; ``cache`` = ``hit`` / ``miss`` / ``off``). ``trace().lower()``
+        is the module, and so the cache key, that ``lower()`` gives."""
         key = self.signature(args, kwargs)
         if key in self._aot:
             return self._aot[key]
-        reg = self._registry
-        with reg.attribute(self.label):
-            t0 = time.perf_counter()
-            compiled = self._jitted.lower(*args, **kwargs).compile()
-            dt = time.perf_counter() - t0
+        # telemetry imports this module while it loads
+        from ..telemetry.timeline import phase
+
+        reg, label = self._registry, self.label
+        with reg.attribute(label):
+            with phase("setup.compile.trace", label=label):
+                traced = self._jitted.trace(*args, **kwargs)
+            with phase("setup.compile.lower", label=label):
+                lowered = traced.lower()
+            with phase("setup.compile.backend", label=label) as span:
+                marks = reg.cache_marks()
+                compiled = lowered.compile()
+                span.attrs["cache"] = _cache_outcome(marks,
+                                                     reg.cache_marks())
         self._aot[key] = compiled
-        reg.record_call(self.label, "precompile", seconds=dt, signature=key)
+        reg.record_call(label, "precompile", signature=key)
         plan = memory_plan_from_compiled(compiled)
         if plan is not None:
             # every AOT program ships its HBM plan (per-pad-bucket programs
             # included): argument/output/temp/code bytes, queryable via the
             # registry + telemetry without re-lowering (ISSUE 9)
-            reg.record_memory_plan(self.label, plan)
-        logging.debug("precompiled %s in %.2fs", self.label, dt)
+            reg.record_memory_plan(label, plan)
         return compiled
 
     @property

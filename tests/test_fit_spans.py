@@ -296,7 +296,9 @@ def test_spans_change_no_arithmetic_and_no_program(monkeypatch):
     telemetry.reset()
     monkeypatch.setattr(telemetry, "phase", _NoSpan)
     _, bare_seen, bare_loss, bare_programs = _fit(with_eval=False)
-    assert telemetry.span_records() == []
+    # no span; what the ring holds is the compile registry's own records
+    assert {r["name"] for r in telemetry.span_records()} == {
+        "compile.backend"}
     assert len(seen) == EPOCHS and seen == bare_seen     # bitwise
     assert loss == bare_loss
     # one train program, the same fingerprint, the same counts
