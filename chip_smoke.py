@@ -32,6 +32,7 @@ line of stdout is
 
 from __future__ import annotations
 
+import functools
 import json
 import logging
 import os
@@ -313,21 +314,25 @@ def kernels_flash(sizes, covered):
 
     s, h = sizes["flash_seq"], sizes["flash_heads"]
 
-    def loss_flash(q, k, v, w):
-        o = flash_attention(q, k, v, causal=True)
+    def loss_flash(q, k, v, w, block=None):
+        o = flash_attention(q, k, v, causal=True, block_q=block,
+                            block_k=block)
         return jnp.sum(o.astype(jnp.float32) * w), o
 
     def loss_ref(q, k, v, w):
         o = attention_reference(q, k, v, causal=True)
         return jnp.sum(o * w), o
 
-    for d in (64, 128):
+    # the wrapper's blocks: the backward in one kernel; query blocks of 64
+    # (part of a lane tile): dq from flash_bwd_dq
+    for d, block in ((64, None), (128, None), (128, 64)):
         keys = jax.random.split(jax.random.PRNGKey(d), 4)
         q, k, v = (jax.random.normal(kk, (1, h, s, d), jnp.float32)
                    .astype(jnp.bfloat16) for kk in keys[:3])
         w = jax.random.normal(keys[3], (1, h, s, d), jnp.float32)
         (_, o), grads = compiled_call(
-            jax.value_and_grad(loss_flash, argnums=(0, 1, 2), has_aux=True),
+            jax.value_and_grad(functools.partial(loss_flash, block=block),
+                               argnums=(0, 1, 2), has_aux=True),
             q, k, v, w)
         # reference: same bf16 inputs widened to f32, dense softmax at
         # full matmul precision (the TPU default rounds operands to bf16)
@@ -338,7 +343,8 @@ def kernels_flash(sizes, covered):
         fwd = float(np.abs(np.asarray(o, np.float32)
                            - np.asarray(o_ref)).max())
         errs = [rel_err(g, r) for g, r in zip(grads, g_ref)]
-        log(f"flash fwd+bwd causal bf16 s={s} d={d}: max |do| = {fwd:.2e}, "
+        log(f"flash fwd+bwd causal bf16 s={s} d={d} blocks={block}: "
+            f"max |do| = {fwd:.2e}, "
             f"rel err dq/dk/dv = {', '.join(f'{e:.2e}' for e in errs)}")
         check(fwd <= 5e-2, f"flash forward d={d} within bf16 tolerance")
         check(max(errs) <= 3e-2, f"flash backward d={d} within bf16 tolerance")

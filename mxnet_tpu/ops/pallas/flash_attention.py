@@ -4,8 +4,16 @@ Design (pallas_guide.md patterns): the softmax is computed online per
 query-block with a running (max, sum) carried in VMEM scratch across the
 key-block grid dimension — the full [seq, seq] score matrix never
 materializes in HBM. Backward recomputes the probabilities from the saved
-log-sum-exp (the flash-attention trick) in two kernels: one accumulating dq
-over key blocks, one accumulating dk/dv over query blocks.
+log-sum-exp (the flash-attention trick) once a tile, in ONE kernel,
+``flash_bwd_dkv``: its grid walks a query head a row, the key blocks, and
+within each the query blocks that can need it; dk/dv accumulate over the
+query blocks (over the whole sequence where consecutive rows share a
+key-value head), and dq over the key blocks in a float32 VMEM accumulator
+that holds the row's whole sequence. Where what it must hold does not fit
+its budget (``_fused_vmem``: a long sequence), dq is computed by a second
+kernel, ``flash_bwd_dq``, that walks the key blocks of each query block
+and computes the probabilities again; ``flash_bwd_dkv`` then walks a
+key-value head a row. The shapes alone choose.
 
 Replaces the dense ``attention_reference`` einsum path wherever attention is
 the hot op (models/transformer.py); numerics are validated against the dense
@@ -48,7 +56,7 @@ key step of the grid, whose tile is the block diagonal; every tile is still
 outside, inside or walked by sub-tiles, its pattern told by one more term,
 the ``kind`` of its quadrant.
 
-The backward kernels do the same over query blocks; ``flash_bwd_dkv``
+The backward kernel does the same over query blocks; ``flash_bwd_dkv``
 accumulates a key-value head's gradient over the query heads that share
 it. Block shapes are chosen in the wrapper from the mask
 (``_choose_blocks``) unless a caller passes them. ``band_tiles`` counts
@@ -207,9 +215,10 @@ _SUB = {"flash_fwd": 128, "flash_bwd_dq": 256, "flash_bwd_dkv": 256}
 # a body is traced and lowered in every layer, and a pair's piece costs
 # about twice a head's to lower (its columns are cut apart as they load).
 # At 8,192 causal positions on a v5e (PERF.md section 6) the three kernels
-# of a layer then trace to fewer equations than the heads-first kernels
-# did (823 against 889), and the train step lowers 1.75 s sooner than with
-# 256 in the backward kernels, for 0.43 ms a layer more device time
+# of a layer then traced to fewer equations than the heads-first kernels
+# did (823 against 889; the forward and the one backward kernel 726), and
+# the train step lowered 1.75 s sooner than with 256 in the backward
+# kernels, for 0.43 ms a layer more device time
 _PAIR_SUB = {"flash_fwd": 256, "flash_bwd_dq": 512, "flash_bwd_dkv": 512}
 
 # a tile's quadrant under ``halves=2``: a clean query on clean keys (the
@@ -331,13 +340,15 @@ def band_tiles(seq_q, seq_k, bq, bk, sub, causal, window, step=1, halves=1):
 
 
 def _band(kernel, bq, bk, seq_q, seq_k, causal, window, step=1, halves=1,
-          rope_dim=0):
+          rope_dim=0, **attrs):
     """What a kernel's body needs of the band: its ``sub``, whether the
     last key block is ``padded``, and ``groups``, the distinct patterns of
     its grid with the ``(off, last)`` (and ``kind``) of the tiles that have
     each. Leaves one zero-length ``flash.band`` record a traced call, with
     ``rope_dim`` the width of the shared rotary key (latent attention) and
-    ``rope_shared`` 1 where there is one, 0 / 0 for every other call."""
+    ``rope_shared`` 1 where there is one, 0 / 0 for every other call, and
+    the kernel's own ``attrs`` (``flash_bwd_dkv``: ``dq`` and
+    ``dq_vmem_bytes``, ``_fused_vmem``)."""
     from ... import telemetry
 
     sub = (_PAIR_SUB if rope_dim else _SUB)[kernel]
@@ -353,7 +364,7 @@ def _band(kernel, bq, bk, seq_q, seq_k, causal, window, step=1, halves=1,
     with telemetry.phase("flash.band", kernel=kernel, seq=seq_q, bq=bq,
                          bk=bk, sub=sub, window=window or 0, step=step,
                          halves=halves, rope_dim=rope_dim,
-                         rope_shared=int(rope_dim > 0),
+                         rope_shared=int(rope_dim > 0), **attrs,
                          **band_tiles(seq_q, seq_k, bq, bk, sub, causal,
                                       window, step, halves)):
         pass
@@ -549,11 +560,13 @@ def _pair_keys(kv_ref, shared, rows, nope):
     return _pair(kv_ref, rows, 0, nope), shared[0, 0, rows, :]
 
 
-def _pair_query(q_ref, rows, rope, nope):
+def _pair_query(q_ref, rows, rope, nope, at=None):
     """The pair's queries of ``rows`` as ``(q_nope, q_pe)``, ``q_pe``
-    rotated to its positions."""
+    rotated to its positions (the tables' rows ``at``, ``rows`` unless
+    given)."""
     pe = _pair(q_ref, rows, nope, q_ref.shape[-1] // 2 - nope)
-    return _pair(q_ref, rows, 0, nope), _rotated(pe, rope, rows)
+    return _pair(q_ref, rows, 0, nope), _rotated(
+        pe, rope, rows if at is None else at)
 
 
 def _parts(f, x):
@@ -580,16 +593,47 @@ def _plus(a, b):
 
 
 def _acc_rows(acc, rows):
-    """The ``rows`` of an accumulator, or of each of its parts."""
-    return _parts(lambda ref: ref[..., rows, :], acc)
+    """The ``rows`` of an accumulator, or of each of its parts (``rows``
+    a tuple: a part's own)."""
+    if isinstance(acc, tuple):
+        rows = rows if isinstance(rows, tuple) else (rows,) * len(acc)
+        return tuple(ref[..., at, :] for ref, at in zip(acc, rows))
+    return acc[..., rows, :]
 
 
 def _acc_store(acc, rows, x):
     if isinstance(acc, tuple):
-        for ref, part in zip(acc, x):
-            ref[..., rows, :] = part
+        rows = rows if isinstance(rows, tuple) else (rows,) * len(acc)
+        for ref, at, part in zip(acc, rows, x):
+            ref[..., at, :] = part
     else:
         acc[..., rows, :] = x
+
+
+def _lanes_add(acc, cols, x):
+    """Add ``x`` to the columns ``cols`` (the last axis) of an accumulator,
+    or of each of its parts."""
+    for ref, part in zip(*((acc, x) if isinstance(acc, tuple)
+                           else ((acc,), (x,)))):
+        at = (slice(None),) * (ref.ndim - 1) + (cols,)
+        ref[at] = ref[at] + part
+
+
+def _swapped(x):
+    """``x`` with its last two axes swapped (a pair's heads stay first)."""
+    return jnp.swapaxes(x, -1, -2)
+
+
+def _each_block(n, size, body, align=8):
+    """``body(at, i)`` for the ``n`` blocks ``i`` of ``size`` rows (or
+    columns) of a whole sequence, ``at`` the block's slice: one traced
+    body in a loop (a store of a whole sequence's accumulator at once
+    would be unrolled over every vector register)."""
+    def step(i, carry):
+        body(pl.ds(pl.multiple_of(i * size, align), size), i)
+        return carry
+
+    jax.lax.fori_loop(0, n, step, 0)
 
 
 def _rotated(x, rope, rows=slice(None)):
@@ -603,14 +647,15 @@ def _rotated(x, rope, rows=slice(None)):
             + turned * sin_ref[rows, :]).astype(x.dtype)
 
 
-def _unrotated(g, rope, dtype):
+def _unrotated(g, rope, dtype, rows=slice(None)):
     """Gradient by the unrotated ``x`` from the gradient ``g`` (float32) by
-    the rotated one: ``g * cos + (g * sin) @ rot^T``."""
+    the rotated one: ``g * cos + (g * sin) @ rot^T``, at the tables'
+    ``rows``."""
     if rope is None:
         return g
     cos_ref, sin_ref, rot_ref = rope
-    return g * cos_ref[...] + _dot((g * sin_ref[...]).astype(dtype),
-                                   rot_ref[...], (1, 1))
+    return g * cos_ref[rows, :] + _dot((g * sin_ref[rows, :]).astype(dtype),
+                                       rot_ref[...], (1, 1))
 
 
 def _gated(do, gate, rows=slice(None)):
@@ -621,13 +666,19 @@ def _gated(do, gate, rows=slice(None)):
     return (do.astype(jnp.float32) * gate[0, rows, :]).astype(do.dtype)
 
 
-def _extra_specs(extras, d, q_block_map, stat_map, bq):
+def _extra_specs(extras, d, q_block_map, stat_map, bq, whole=0):
     """BlockSpecs of the rotary tables and the gate: cos and sin (``d``
-    wide) by the query block, the whole ``rot``, the gate as the row
-    statistics are. The shared key's follows them (the wrappers')."""
+    wide) by the query block, or all ``whole`` rows of them read once
+    (one buffer: the index never moves), the whole ``rot``, the gate as
+    the row statistics are. The shared key's follows them (the
+    wrappers')."""
     rotary, gated = extras[:2]
     specs = []
-    if rotary:
+    if rotary and whole:
+        specs += [pl.BlockSpec((whole, d), lambda *_: (0, 0),
+                               pipeline_mode=pl.Buffered(1))] * 2 + [
+            pl.BlockSpec((d, d), lambda *_: (0, 0))]
+    elif rotary:
         specs += [pl.BlockSpec((bq, d), q_block_map),
                   pl.BlockSpec((bq, d), q_block_map),
                   pl.BlockSpec((d, d), lambda *_: (0, 0))]
@@ -1007,20 +1058,35 @@ def _bwd_dq_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps, sub,
 
 
 def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps,
-                    group, sub, groups, padded, extras, step, halves, nope=0):
+                    group, sub, groups, padded, extras, step, halves, nope=0,
+                    shares=1, fused=False):
     (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), rope, gate, shared, \
         rest = _split_refs(refs, 6, extras)
     # with the shared key: the gradient of the pair's key-value block and
-    # the shared key's
-    dk_ref, dv_ref, dk_acc, dv_acc = rest[:4]
-    # the inner grid dimension walks the query heads (pairs) that share
-    # this key-value head, and within each the query blocks that can need
-    # this key block
-    t = pl.program_id(2)
-    _, qi, kj, kind, needed = _q_step(pl.program_id(1), _mod(t, steps), bq,
-                                      bk, nq, nk, causal, window, step,
-                                      halves)
+    # the shared key's; ``fused``, the gradient by the queries too
+    dk_ref, dv_ref = rest[:2]
+    dq_ref = rest[2] if fused else None
+    dk_acc, dv_acc, *more = rest[2 + fused:]
+    # the inner grid dimension walks the ``group`` query heads (pairs)
+    # that share this key-value head, and within each the query blocks
+    # that can need this key block. Fused a row is ONE query head (pair)
+    # and ``shares`` consecutive rows share the key-value head: its
+    # accumulator then holds the whole sequence (``whole``), and dq's
+    # holds the row's, the queries along the lanes (``dq^T += k^T . ds``:
+    # the keys' strip is transposed, not ds)
+    b, j, t = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    at = t if group == 1 else _mod(t, steps)
+    blk, qi, kj, kind, needed = _q_step(j, at, bq, bk, nq, nk, causal,
+                                        window, step, halves)
+    whole = shares > 1
+
+    def table(rows):     # the rotary tables' rows of a query block's rows
+        return pl.ds(qi * bq + rows.start, rows.stop - rows.start) \
+            if fused else rows
+
     if shared is None:
+        dq_acc = more[0] if fused else None
+
         def keys(rows):
             return k_ref[0, rows, :]
 
@@ -1028,7 +1094,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps,
             return v_ref[0, rows, :]
 
         def queries(rows):
-            return _rotated(q_ref[0, rows, :], rope, rows)
+            return _rotated(q_ref[0, rows, :], rope, table(rows))
 
         def grads(rows):
             return _gated(do_ref[0, rows, :], gate, rows)
@@ -1037,7 +1103,8 @@ def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps,
             return ref[0, 0, :, rows]
     else:     # the step's pair's, read from its blocks as they are; the
         # gradient by k_nope a pair's, by the shared key summed over all
-        dk_acc = (dk_acc, rest[4])
+        dk_acc = (dk_acc, more[0])
+        dq_acc = tuple(more[1:]) if fused else None
 
         def keys(rows):     # a strip's: the keys are the products' rows
             own, pe = _pair_keys(k_ref, shared, rows, nope)
@@ -1047,7 +1114,7 @@ def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps,
             return _pair(k_ref, rows, nope, do_ref.shape[-1] // 2)
 
         def queries(rows):
-            return _pair_query(q_ref, rows, rope, nope)
+            return _pair_query(q_ref, rows, rope, nope, table(rows))
 
         def grads(rows):
             return _pair(do_ref, rows, 0, do_ref.shape[-1] // 2)
@@ -1055,24 +1122,58 @@ def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps,
         def stats(ref, rows):
             return ref[:, 0, :, rows]
 
+    def kv_rows(cols):
+        """The accumulators' rows of a strip of keys: the block's, or the
+        sequence's where the accumulator is whole (with the shared key its
+        own part is always the block's)."""
+        rows = pl.ds(j * bk + cols.start, cols.stop - cols.start) \
+            if whole else cols
+        return rows if shared is None else (cols, rows)
+
+    if fused:
+        last_row = jnp.logical_and(j == halves * nk - 1, t == steps - 1)
+
+        @pl.when(jnp.logical_and(j == 0, t == 0))
+        def _start_row():
+            def clear(cols, i):
+                for ref in dq_acc if shared is not None else (dq_acc,):
+                    ref[..., cols] = jnp.zeros(
+                        (*ref.shape[:-1], bq), jnp.float32)
+
+            _each_block(halves * nq, bq, clear, _LANES)
+            if whole:
+                @pl.when(_mod(b, shares) == 0)
+                def _start_head():
+                    def clear_kv(rows, i):
+                        for ref in (dk_acc[1],) if shared is not None \
+                                else (dk_acc, dv_acc):
+                            ref[..., rows, :] = jnp.zeros(
+                                (*ref.shape[:-2], bk, ref.shape[-1]),
+                                jnp.float32)
+
+                    _each_block(halves * nk, bk, clear_kv)
+
     if shared is None:
-        @pl.when(t == 0)
-        def _init():
-            dk_acc[:] = jnp.zeros_like(dk_acc)
-            dv_acc[:] = jnp.zeros_like(dv_acc)
+        if not whole:
+            @pl.when(t == 0)
+            def _init():
+                dk_acc[:] = jnp.zeros_like(dk_acc)
+                dv_acc[:] = jnp.zeros_like(dv_acc)
     else:     # a pair's own accumulators anew, the shared key's once
-        @pl.when(_mod(t, steps) == 0)
+        @pl.when(at == 0)
         def _next_pair():
             for acc in (dk_acc[0], dv_acc):
                 acc[...] = jnp.zeros_like(acc)
 
-        @pl.when(t == 0)
-        def _init():
-            dk_acc[1][...] = jnp.zeros_like(dk_acc[1])
+        if not whole:
+            @pl.when(t == 0)
+            def _init():
+                dk_acc[1][...] = jnp.zeros_like(dk_acc[1])
 
     def body(classes):
         # strips of keys, each over the pieces of queries it needs, the
-        # keys down the rows of every product: nothing is transposed
+        # keys down the rows of every product: nothing is transposed but,
+        # fused, a strip's keys, once
         width, strips = _strips(classes, bq, bk, sub, by_keys=True)
         mask = _masks(bq, bk, sub, by_keys=True, step=step)
         # every step has another query block: what the strips need of it
@@ -1085,7 +1186,10 @@ def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps,
             cols = slice(c0, c0 + width)
             k = _parts(_mxu, keys(cols))
             v = _mxu(values(cols))
-            dk, dv = _acc_rows(dk_acc, cols), dv_acc[..., cols, :]
+            k_t = _parts(_swapped, k) if fused else None
+            dk, dv = _acc_rows(dk_acc, kv_rows(cols)), \
+                _acc_rows(dv_acc, cols if shared is not None
+                          else kv_rows(cols))
             for r0, n, terms in pieces:
                 q = _parts(lambda x: x[..., r0 - lo:r0 - lo + n, :], q_all)
                 do = do_all[..., r0 - lo:r0 - lo + n, :]
@@ -1096,28 +1200,110 @@ def _bwd_dkv_kernel(*refs, scale, causal, window, bq, bk, nq, nk, steps,
                 ds = (p * (dp - stats(delta_ref, slice(r0, r0 + n)))
                       * scale).astype(do.dtype)
                 dk = _plus(dk, _weigh(ds, q))
-            _acc_store(dk_acc, cols, dk)
-            dv_acc[..., cols, :] = dv
+                if fused:
+                    _lanes_add(dq_acc, pl.ds(pl.multiple_of(
+                        blk * bq + r0, _LANES), n), _parts(
+                            lambda kt: _dot(kt, ds, (1, 0)), k_t))
+            _acc_store(dk_acc, kv_rows(cols), dk)
+            _acc_store(dv_acc, cols if shared is not None else kv_rows(cols),
+                       dv)
 
     _on_tile(groups, qi * bq - kj * bk, kj == nk - 1, needed, causal,
              padded, body, kind)
 
+    if fused:
+        @pl.when(last_row)
+        def _dq_done():     # unrotated a block at a time, as q's rows lie
+            def put(rows, i):
+                cols = pl.ds(pl.multiple_of(i * bq, _LANES), bq)
+                tab = rows if halves == 1 else pl.ds(
+                    pl.multiple_of(_mod(i, nq) * bq, 8), bq)
+                if shared is None:
+                    dq_ref[0, rows, :] = _unrotated(
+                        _swapped(dq_acc[:, cols]), rope, dq_ref.dtype,
+                        tab).astype(dq_ref.dtype)
+                    return
+                own = _swapped(dq_acc[0][:, :, cols])
+                pe = _unrotated(_swapped(dq_acc[1][:, :, cols]), rope,
+                                dq_ref.dtype, tab)
+                dq_ref[0, rows, :] = jnp.concatenate(
+                    [own[0], pe[0], own[1], pe[1]], axis=-1).astype(
+                        dq_ref.dtype)
+
+            _each_block(halves * nq, bq, put)
+
+    done = jnp.logical_and(last_row, _mod(b, shares) == shares - 1) \
+        if whole else t == group * steps - 1
     if shared is None:
-        @pl.when(t == group * steps - 1)
+        @pl.when(done)
         def _finalize():
-            dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
-            dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+            if not whole:
+                dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
+                dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+                return
+
+            def put(rows, i):
+                dk_ref[0, rows, :] = dk_acc[rows, :].astype(dk_ref.dtype)
+                dv_ref[0, rows, :] = dv_acc[rows, :].astype(dv_ref.dtype)
+
+            _each_block(halves * nk, bk, put)
         return
 
-    @pl.when(_mod(t, steps) == steps - 1)
+    @pl.when(at == steps - 1)
     def _pair_done():     # [k_nope | v] of either head, as the rows lie
         own, dv = dk_acc[0][...], dv_acc[...]
         dk_ref[0] = jnp.concatenate([own[0], dv[0], own[1], dv[1]],
                                     axis=-1).astype(dk_ref.dtype)
 
-    @pl.when(t == group * steps - 1)
+    @pl.when(done)
     def _finalize_shared():
-        dv_ref[0, 0] = (dk_acc[1][0] + dk_acc[1][1]).astype(dv_ref.dtype)
+        if not whole:
+            dv_ref[0, 0] = (dk_acc[1][0] + dk_acc[1][1]).astype(dv_ref.dtype)
+            return
+
+        def put(rows, i):
+            dv_ref[0, 0, rows, :] = (dk_acc[1][0, rows, :]
+                                     + dk_acc[1][1, rows, :]).astype(
+                                         dv_ref.dtype)
+
+        _each_block(halves * nk, bk, put)
+
+
+# What ``flash_bwd_dkv`` may hold across a grid row to make dq as well
+# (``_fused_vmem``); a call whose shapes need more keeps ``flash_bwd_dq``.
+# At 8,192 positions every attention layer of the decoder cells holds 23-29
+# MB; a latent pair at 16,384 positions would hold 57.
+_FUSED_VMEM = 40 * 2 ** 20
+# what a fused call may take besides: its blocks in flight and the body's
+# values (the latent kernels' own limit, ``_LATENT_VMEM``, for a pair)
+_STREAM_VMEM = 32 * 2 ** 20
+
+
+def _fused_vmem(sq, sk, d, v_dim, rope_dim, group, itemsize, rope):
+    """Bytes that ``flash_bwd_dkv`` holds across a grid row when it makes
+    dq too: dq's float32 accumulator and its output block over the whole
+    (padded) sequence, ``d`` wide (a pair's with the shared key); where
+    ``group`` rows share a key-value head, its accumulators and output
+    blocks over the whole sequence (the shared key's: both heads' float32
+    and one block); and the whole rotary tables, float32."""
+    held = sq * d * (4 + itemsize)
+    if group > 1:
+        held += sk * (rope_dim * (2 * 4 + itemsize) if rope_dim
+                      else (d + v_dim) * (4 + itemsize))
+    if rope is not None:
+        held += 2 * rope[0].size * 4
+    return held
+
+
+def _row_sums(do, o, heads, width, packed):
+    """The row sums of ``do * o`` (delta), ``(batch x heads, seq)``
+    float32, the positions along the lanes."""
+    prod = do.astype(jnp.float32) * o.astype(jnp.float32)
+    if not packed:
+        return jnp.sum(prod, axis=-1)
+    b, sq = do.shape[:2]
+    return jnp.sum(prod.reshape(b, sq, heads, width), axis=-1).transpose(
+        0, 2, 1).reshape(b * heads, sq)
 
 
 def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
@@ -1129,8 +1315,16 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
     is the key-value rows and ``v`` None: ``dk`` is their gradient and
     ``dv`` the shared key's, summed over every head in ``flash_bwd_dkv``,
     whose one key-value head is the shared key; a row of the grids is a
-    pair of heads (``_pair_widths``), and ``delta`` is made by
-    ``flash_bwd_dq``."""
+    pair of heads (``_pair_widths``).
+
+    One kernel, ``flash_bwd_dkv``, makes all three gradients where what it
+    must hold across a grid row fits ``_FUSED_VMEM`` (``_fused_vmem``) and
+    the query blocks are whole lane tiles: each tile's probabilities and
+    dS are computed once, its grid walks a query head (pair) a row, and dq
+    is accumulated in VMEM over the whole sequence; ``delta`` is made by
+    XLA. Otherwise dq is ``flash_bwd_dq``'s (which makes ``delta`` with
+    the shared key) and ``flash_bwd_dkv``'s grid walks a key-value head a
+    row."""
     extras = (rope is not None, gate is not None, shared is not None)
     more = (*(rope or ()), *(() if gate is None else (gate,)),
             *(() if shared is None else (shared,)))
@@ -1145,90 +1339,93 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
     bh = q.shape[0] * hq if packed else q.shape[0]
     sq, sk = q.shape[1], k.shape[1]
     nq, nk = sq // bq // halves, sk // bk // halves      # a half's
-    if shared is not None:     # flash_bwd_dq's second output
-        delta = None
-    elif packed:
-        delta = jnp.sum(
-            (do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
-                q.shape[0], sq, hq, v_dim), axis=-1).transpose(
-                    0, 2, 1).reshape(bh, sq, 1)
-    else:
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1, keepdims=True)
-
-    kv_steps = _kv_steps(bq, bk, nk, causal, window) + halves - 1
-    kv_map = _kv_block_map(hq, hkv, bq, bk, nq, nk, causal, window, packed,
-                           step, halves)
+    # the query heads (pairs) that read one key-value head: with the
+    # shared key, every pair
+    group = hq if shared is not None else hq // hkv
+    held = _fused_vmem(sq, sk, d, v_dim, rope_dim, group, q.dtype.itemsize,
+                       rope)
+    fused = bq % _LANES == 0 and held <= _FUSED_VMEM
     band = (bq, bk, seq_q, seq_k, causal, window, step, halves, rope_dim)
-    dq_kernel = functools.partial(
-        _bwd_dq_kernel, scale=scale, causal=causal, window=window, bq=bq,
-        bk=bk, nq=nq, nk=nk, steps=kv_steps, extras=extras,
-        **_band("flash_bwd_dq", *band))
+    delta = None if shared is not None and not fused else _row_sums(
+        do, o, hq * per, v_dim // per, packed)[..., None]
+    if not fused:     # dq from flash_bwd_dq (and delta, with the shared key)
+        kv_steps = _kv_steps(bq, bk, nk, causal, window) + halves - 1
+        kv_map = _kv_block_map(hq, hkv, bq, bk, nq, nk, causal, window, packed,
+                               step, halves)
+        dq_kernel = functools.partial(
+            _bwd_dq_kernel, scale=scale, causal=causal, window=window, bq=bq,
+            bk=bk, nq=nq, nk=nk, steps=kv_steps, extras=extras,
+            **_band("flash_bwd_dq", *band))
 
-    def q_of_row(b, i, j):
-        return _q_index(b, i, hq, packed)
+        def q_of_row(b, i, j):
+            return _q_index(b, i, hq, packed)
 
-    def stat_of_row(b, i, j):
-        return (b, i, 0)
+        def stat_of_row(b, i, j):
+            return (b, i, 0)
 
-    outs = (pl.BlockSpec((1, bq, d), q_of_row),
-            jax.ShapeDtypeStruct(q.shape, q.dtype))
-    if shared is None:
-        own, last, ins = [pl.BlockSpec((1, bk, d), kv_map),
-                          pl.BlockSpec((1, bk, v_dim), kv_map)], [], (k, v)
-        sums = (delta, pl.BlockSpec((per, bq, 1), stat_of_row))
-    else:
-        dq_kernel = functools.partial(dq_kernel, nope=nope)
-        own, ins = [pl.BlockSpec((1, bk, kv_dim), kv_map)], (k,)
-        last = [pl.BlockSpec((1, 1, bk, rope_dim), _shared_map(kv_map))]
-        # the output rows in delta's place: the row sums of do * o are the
-        # kernel's second output, made there from the blocks it reads
-        sums = (o, pl.BlockSpec((1, bq, v_dim), q_of_row))
-        outs = ([outs[0], pl.BlockSpec((per, bq, 1), stat_of_row)],
-                [outs[1], jax.ShapeDtypeStruct((bh * per, sq, 1),
-                                               jnp.float32)])
+        outs = (pl.BlockSpec((1, bq, d), q_of_row),
+                jax.ShapeDtypeStruct(q.shape, q.dtype))
+        if shared is None:
+            own, last, ins = [pl.BlockSpec((1, bk, d), kv_map),
+                              pl.BlockSpec((1, bk, v_dim), kv_map)], [], (k, v)
+            sums = (delta, pl.BlockSpec((per, bq, 1), stat_of_row))
+        else:
+            dq_kernel = functools.partial(dq_kernel, nope=nope)
+            own, ins = [pl.BlockSpec((1, bk, kv_dim), kv_map)], (k,)
+            last = [pl.BlockSpec((1, 1, bk, rope_dim), _shared_map(kv_map))]
+            # the output rows in delta's place: the row sums of do * o are the
+            # kernel's second output, made there from the blocks it reads
+            sums = (o, pl.BlockSpec((1, bq, v_dim), q_of_row))
+            outs = ([outs[0], pl.BlockSpec((per, bq, 1), stat_of_row)],
+                    [outs[1], jax.ShapeDtypeStruct((bh * per, sq, 1),
+                                                   jnp.float32)])
 
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(bh, halves * nq, kv_steps),
-        in_specs=[pl.BlockSpec((1, bq, d), q_of_row)] + own + [
-            pl.BlockSpec((1, bq, v_dim), q_of_row),
-            pl.BlockSpec((per, bq, 1), stat_of_row),
-            sums[1],
-        ] + _extra_specs(extras, rope_dim or d, _table_map(
-            nq, halves, lambda b, i, j: i), stat_of_row, bq) + last,
-        out_specs=outs[0],
-        out_shape=outs[1],
-        scratch_shapes=(
-            _pair_scratch(bq, (nope, rope_dim), jnp.float32)
-            + _pair_scratch(bq, (nope, rope_dim), q.dtype)
-            if shared is not None else
-            [pltpu.VMEM((bq, d), jnp.float32)]
-            + ([pltpu.VMEM((bq, d), q.dtype)] if rope is not None else [])),
-        interpret=interpret,
-        name="flash_bwd_dq",
-        **_latent_params(shared),
-    )(q, *ins, do, lse, sums[0], *more)
-    if shared is not None:
-        dq, delta = dq
+        dq = pl.pallas_call(
+            dq_kernel,
+            grid=(bh, halves * nq, kv_steps),
+            in_specs=[pl.BlockSpec((1, bq, d), q_of_row)] + own + [
+                pl.BlockSpec((1, bq, v_dim), q_of_row),
+                pl.BlockSpec((per, bq, 1), stat_of_row),
+                sums[1],
+            ] + _extra_specs(extras, rope_dim or d, _table_map(
+                nq, halves, lambda b, i, j: i), stat_of_row, bq) + last,
+            out_specs=outs[0],
+            out_shape=outs[1],
+            scratch_shapes=(
+                _pair_scratch(bq, (nope, rope_dim), jnp.float32)
+                + _pair_scratch(bq, (nope, rope_dim), q.dtype)
+                if shared is not None else
+                [pltpu.VMEM((bq, d), jnp.float32)]
+                + ([pltpu.VMEM((bq, d), q.dtype)] if rope is not None
+                   else [])),
+            interpret=interpret,
+            name="flash_bwd_dq",
+            **_latent_params(shared),
+        )(q, *ins, do, lse, sums[0], *more)
+        if shared is not None:
+            dq, delta = dq
 
     # the one key-value head of latent attention is the shared key, which
     # every pair reads
     if shared is not None:
         hkv = 1
-    bkv = k.shape[0] * hkv if packed else k.shape[0]
-    group = hq // hkv
+    shares = group if fused else 1
     # over two halves a clean key block meets both halves' query blocks
     q_steps = halves * _q_steps(bq, bk, nq, causal, window)
 
     def q_row(b, j, t):
         # row of the flattened (batch x query heads) and query block of
-        # step t: this key-value head's ``t // q_steps``-th query head;
-        # block clamped to the last one needed
-        head = b if hq == hkv else \
-            _div(b, hkv) * hq + _mod(b, hkv) * group + _div(t, q_steps)
-        return head, _q_step(j, _mod(t, q_steps), bq, bk, nq, nk, causal,
-                             window, step, halves)[0]
+        # step t: fused the row's own; else this key-value head's
+        # ``t // q_steps``-th query head; block clamped to the last one
+        # needed
+        if fused:
+            head, s = b, t
+        else:
+            head = b if hq == hkv else \
+                _div(b, hkv) * hq + _mod(b, hkv) * group + _div(t, q_steps)
+            s = _mod(t, q_steps)
+        return head, _q_step(j, s, bq, bk, nq, nk, causal, window, step,
+                             halves)[0]
 
     def q_map(b, j, t):
         return _q_index(*q_row(b, j, t), hq, packed)
@@ -1244,52 +1441,89 @@ def _flash_bwd_padded(q, k, v, o, lse, do, *, scale, causal, window, hq,
         # reads them: the same bytes
         return x.reshape(bh * per, halves * nq, 1, bq)
 
-    def kv_of_row(b, j, t):
-        return _kv_index(b, j, hkv, packed)
+    def kv_head(b):     # the key-value head a grid row reads
+        return _kv_head(b, hq, hkv) if fused else b
 
+    def kv_of_row(b, j, t):
+        return _kv_index(kv_head(b), j, hkv, packed)
+
+    def kv_whole(b, j, t):
+        return _kv_index(kv_head(b), 0, hkv, packed)
+
+    rows = sk if shares > 1 else bk     # of the key-value accumulators
     if shared is None:
-        outs = [pl.BlockSpec((1, bk, d), kv_of_row),
-                pl.BlockSpec((1, bk, v_dim), kv_of_row)]
-        own, last = outs, []
+        own = [pl.BlockSpec((1, bk, d), kv_of_row),
+               pl.BlockSpec((1, bk, v_dim), kv_of_row)]
+        last = []
+        outs = own if shares == 1 else [
+            pl.BlockSpec((1, sk, d), kv_whole, pipeline_mode=pl.Buffered(1)),
+            pl.BlockSpec((1, sk, v_dim), kv_whole,
+                         pipeline_mode=pl.Buffered(1))]
         shapes = (v.shape, v.dtype)
+        scratch = [pltpu.VMEM((rows, d), jnp.float32),
+                   pltpu.VMEM((rows, v_dim), jnp.float32)] + (
+            [pltpu.VMEM((d, sq), jnp.float32)] if fused else [])
     else:     # the step's pair's key-value block, and the shared key's
         def kv_of_pair(b, j, t):
             return _kv_index(q_row(b, j, t)[0], j, hq, True)
 
-        outs = [pl.BlockSpec((1, bk, kv_dim), kv_of_pair),
-                pl.BlockSpec((1, 1, bk, rope_dim),
-                             lambda b, j, t: (b, 0, j, 0))]
-        own, last = outs[:1], outs[1:]
-        shapes = (shared.shape, shared.dtype)
+        def shared_of_row(b, j, t):
+            return (_div(b, hq) if fused else b, 0, j, 0)
 
-    dk, dv = pl.pallas_call(
+        own = [pl.BlockSpec((1, bk, kv_dim), kv_of_pair)]
+        last = [pl.BlockSpec((1, 1, bk, rope_dim), shared_of_row)]
+        outs = own + (last if shares == 1 else [pl.BlockSpec(
+            (1, 1, sk, rope_dim), lambda b, j, t: (_div(b, hq), 0, 0, 0),
+            pipeline_mode=pl.Buffered(1))])
+        shapes = (shared.shape, shared.dtype)
+        scratch = _pair_scratch(bk, (nope, v_dim // 2), jnp.float32) + [
+            pltpu.VMEM((2, rows, rope_dim), jnp.float32)] + (
+            [pltpu.VMEM((2, nope, sq), jnp.float32),
+             pltpu.VMEM((2, rope_dim, sq), jnp.float32)] if fused else [])
+    out_shape = [jax.ShapeDtypeStruct(k.shape, k.dtype),
+                 jax.ShapeDtypeStruct(*shapes)]
+    if fused:     # the row's dq, the whole sequence, written once
+        outs = outs + [pl.BlockSpec(
+            (1, sq, d), lambda b, j, t: _q_index(b, 0, hq, packed),
+            pipeline_mode=pl.Buffered(1))]
+        out_shape.append(jax.ShapeDtypeStruct(q.shape, q.dtype))
+        params = {"compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=held + (_LATENT_VMEM if shared is not None
+                                     else _STREAM_VMEM))}
+        grid = (bh, halves * nk, q_steps)
+    else:
+        params = _latent_params(shared)
+        grid = (k.shape[0] * hkv if packed else k.shape[0], halves * nk,
+                group * q_steps)
+
+    got = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           window=window, bq=bq, bk=bk, nq=nq, nk=nk,
-                          steps=q_steps, group=group, extras=extras,
-                          **_band("flash_bwd_dkv", *band),
+                          steps=q_steps, group=1 if fused else group,
+                          extras=extras, shares=shares, fused=fused,
+                          **_band("flash_bwd_dkv", *band, dq=int(fused),
+                                  dq_vmem_bytes=held),
                           **({} if shared is None else {"nope": nope})),
-        grid=(bkv, halves * nk, group * q_steps),
+        grid=grid,
         in_specs=[pl.BlockSpec((1, bq, d), q_map)] + own + [
             pl.BlockSpec((1, bq, v_dim), q_map),
             pl.BlockSpec((per, 1, 1, bq), stat_row_map),
             pl.BlockSpec((per, 1, 1, bq), stat_row_map),
         ] + _extra_specs(extras, rope_dim or d, _table_map(
             nq, halves, lambda b, j, t: q_row(b, j, t)[1]), stat_map,
-            bq) + last,
+            bq, rope[0].shape[0] if fused and rope is not None else 0) + last,
         out_specs=outs,
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(*shapes),
-        ],
-        scratch_shapes=(
-            _pair_scratch(bk, (nope, v_dim // 2, rope_dim), jnp.float32)
-            if shared is not None else
-            [pltpu.VMEM((bk, d), jnp.float32),
-             pltpu.VMEM((bk, v_dim), jnp.float32)]),
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
         name="flash_bwd_dkv",
-        **_latent_params(shared),
-    )(q, *ins, do, stat_rows(lse), stat_rows(delta), *more)
+        **params,
+    )(q, *((k,) if shared is not None else (k, v)), do, stat_rows(lse),
+      stat_rows(delta), *more)
+    if fused:
+        dk, dv, dq = got
+    else:
+        dk, dv = got
     return dq, dk, dv, delta
 
 
@@ -1607,11 +1841,12 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
     at once, a leading axis of 2. Nothing is transposed, broadcast or
     rotated in HBM but that small key. The output is ``[batch, seq, heads,
     v_dim]`` in the rows' layout; the gradient by ``q`` comes in ``q``'s
-    layout (``flash_bwd_dq`` writes a pair's block once, ``dq_pe``
-    unrotated in the kernel, and makes the row sums of ``do * o`` from the
-    blocks it reads, a second output for ``flash_bwd_dkv``), by ``k`` in
-    ``k``'s, and by ``key_rope`` summed over every head inside
-    ``flash_bwd_dkv``, whose grid walks all the pairs of a key block.
+    layout (written once a pair, ``dq_pe`` unrotated in the kernel), by
+    ``k`` in ``k``'s, and by ``key_rope`` summed over every head inside
+    ``flash_bwd_dkv``: its grid walks a pair a row, and the shared key's
+    gradient accumulates over the whole sequence and every pair (where
+    the two kernels run, ``flash_bwd_dq`` makes dq and the row sums of
+    ``do * o`` and ``flash_bwd_dkv`` walks all the pairs of a key block).
     ``latent_fits`` says which widths it takes. Set-up rules its kernels
     keep, because each kernel body is traced and lowered once a layer and
     the train step's lowering is paid in ``setup_s``: heads run on the
@@ -1624,8 +1859,10 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
     (``tests/test_tpu_lowering.py``).
 
     Exact (up to fp accumulation order) match of the dense masked softmax
-    attention, with O(block) VMEM footprint. Differentiable via Pallas
-    backward kernels. Block shapes are chosen here from the mask
+    attention, with O(block) VMEM footprint forward. Differentiable via
+    a Pallas backward kernel (two where a row's whole-sequence dq does not
+    fit its VMEM budget: ``_flash_bwd_padded``). Block shapes are chosen
+    here from the mask
     (``_choose_blocks``) unless given. On non-TPU backends defaults to
     interpret mode so the same kernel code runs in tests.
     """
@@ -1745,9 +1982,11 @@ def _flash_bwd_dkv_cost(in_avals, out_avals):
 
     bh, sq, sk, d, dv = _flash_dims(
         in_avals, in_avals[3] if len(in_avals) > 3 else None)
-    # recomputed scores (d) + dp (dv) + dv (dv) + dk (d) accumulations
-    return KernelCost(flops=4.0 * bh * sq * sk * (d + dv),
-                      bytes=io_bytes(in_avals, out_avals))
+    # recomputed scores (d) + dp (dv) + dv (dv) + dk (d) accumulations,
+    # and dq (d) where the call makes it (its third result)
+    return KernelCost(
+        flops=2.0 * bh * sq * sk * (2 * (d + dv) + d * (len(out_avals) > 2)),
+        bytes=io_bytes(in_avals, out_avals))
 
 
 register_kernel(
@@ -1758,4 +1997,5 @@ register_kernel(
     doc="flash attention backward: dq accumulated over key blocks")
 register_kernel(
     "flash_bwd_dkv", _flash_bwd_dkv_cost, module=__name__,
-    doc="flash attention backward: dk/dv accumulated over query blocks")
+    doc="flash attention backward: dk/dv accumulated over query blocks, "
+        "and dq in VMEM where it fits (then the whole backward)")

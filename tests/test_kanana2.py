@@ -140,6 +140,10 @@ def test_equal_widths_are_what_they_were():
     for name, factor in (("flash_fwd", 4), ("flash_bwd_dq", 6),
                          ("flash_bwd_dkv", 8)):
         assert kernel_cost(name, a, []).flops == factor * 6 * 256 * 256 * 64
+    # flash_bwd_dkv making dq too (a third result): the scores once, 10
+    # where the two kernels are 14
+    assert kernel_cost("flash_bwd_dkv", a, a).flops \
+        == 10 * 6 * 256 * 256 * 64
     wide = [a[0], a[0], jax.ShapeDtypeStruct((6, 256, 32), jnp.bfloat16)]
     assert kernel_cost("flash_fwd", wide, []).flops == 2 * 6 * 256 * 256 * 96
 
@@ -271,8 +275,10 @@ def test_latent_kernels_read_the_rows_as_projected():
         assert rel(got[1][:, :, head], want[1][:, :, head]) < 1e-5
     bands = [rec["attrs"] for rec in telemetry.span_records()[mark:]
              if rec["name"] == "flash.band"]
+    # blocks of 16 are part of a lane tile: dq is flash_bwd_dq's
     assert {a["kernel"] for a in bands} == {"flash_fwd", "flash_bwd_dq",
                                             "flash_bwd_dkv"}
+    assert all(a["dq"] == 0 for a in bands if a["kernel"] == "flash_bwd_dkv")
     assert all((a["rope_dim"], a["rope_shared"]) == (64, 1) for a in bands)
     # the registry prices the calls at keys of 192 and values of 128, the
     # padded 48 positions

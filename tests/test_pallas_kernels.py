@@ -474,7 +474,7 @@ def test_cost_rows_jaxpr_table_shows_kernels():
 @functools.lru_cache(maxsize=None)
 def _kernel_family_calls():
     """One traceable call a kernel family, with its operands: what a
-    roofline table prices. Built once for the seven cases below."""
+    roofline table prices. Built once for the eight cases below."""
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(1, 2, 128, 32).astype(np.float32))
     slab = jnp.asarray(rng.randn(8, 4096).astype(np.float32))
@@ -490,9 +490,16 @@ def _kernel_family_calls():
         "flash_attention_fwd": (
             lambda x: pk.flash_attention(x, x, x, causal=True), (q,),
             {"pallas::flash_fwd"}),
+        # query blocks of 128: one backward kernel makes every gradient
         "flash_attention_fwd_bwd": (
             lambda x: jax.grad(lambda y: jnp.sum(
                 pk.flash_attention(y, y, y, causal=True)))(x), (q,),
+            {"pallas::flash_fwd", "pallas::flash_bwd_dkv"}),
+        # of 64, part of a lane tile: dq is flash_bwd_dq's
+        "flash_attention_fwd_bwd_split": (
+            lambda x: jax.grad(lambda y: jnp.sum(
+                pk.flash_attention(y, y, y, causal=True, block_q=64,
+                                   block_k=64)))(x), (q,),
             {"pallas::flash_fwd", "pallas::flash_bwd_dq",
              "pallas::flash_bwd_dkv"}),
         "quant_int8": (
@@ -519,7 +526,8 @@ def _kernel_family_calls():
 
 
 @pytest.mark.parametrize("family", [
-    "flash_attention_fwd", "flash_attention_fwd_bwd", "quant_int8",
+    "flash_attention_fwd", "flash_attention_fwd_bwd",
+    "flash_attention_fwd_bwd_split", "quant_int8",
     "quant_twobit", "dequant_sum_int8", "fused_adam", "int8_matmul"])
 def test_cost_rows_price_every_kernel_family(family):
     """A roofline row a kernel family needs the registry to price it: the
