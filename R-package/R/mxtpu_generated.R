@@ -40,10 +40,14 @@ mx.symbol.LinearRegressionOutput <- function(...) mx.symbol.op("LinearRegression
 mx.symbol.LogisticRegressionOutput <- function(...) mx.symbol.op("LogisticRegressionOutput", ...)
 # MAERegressionOutput: Identity forward, sign(pred - label) gradient (L1 regression head;
 mx.symbol.MAERegressionOutput <- function(...) mx.symbol.op("MAERegressionOutput", ...)
+# Mamba2Mixer: The mixer of a Mamba-2 layer on projected rows, rows = batch *
+mx.symbol.Mamba2Mixer <- function(...) mx.symbol.op("Mamba2Mixer", ...)
 # MaskedDiffusionOutput: Loss head of masked (absorbing-state) diffusion within blocks, the
 mx.symbol.MaskedDiffusionOutput <- function(...) mx.symbol.op("MaskedDiffusionOutput", ...)
 # MixtureOfExperts: Sparse gated feed-forward layer, one rank's share of its experts.
 mx.symbol.MixtureOfExperts <- function(...) mx.symbol.op("MixtureOfExperts", ...)
+# MixtureOfExpertsSquaredReLU: ``MixtureOfExperts`` with ungated experts (Nemotron-H's
+mx.symbol.MixtureOfExpertsSquaredReLU <- function(...) mx.symbol.op("MixtureOfExpertsSquaredReLU", ...)
 # Pooling: Max/avg/sum pooling, NCHW or NHWC per ``layout`` (reference:
 mx.symbol.Pooling <- function(...) mx.symbol.op("Pooling", ...)
 # RMSNorm: Root-mean-square normalisation over the last axis with a learnable

@@ -18,7 +18,7 @@ from .base import MXNetError
 from .ndarray import NDArray
 
 __all__ = ["Initializer", "Uniform", "Normal", "Xavier", "One", "Zero", "Constant",
-           "Mixed"]
+           "Mixed", "SSMDecay", "SSMStepBias"]
 
 
 class Initializer:
@@ -43,9 +43,9 @@ class Initializer:
             self._init_one(name, arr)
         elif name.endswith("moving_avg"):
             self._init_zero(name, arr)
-        elif name.endswith(("expert_load", "mask_count")):
+        elif name.endswith(("expert_load", "mask_count", "ssm_record")):
             # counts kept as auxiliary states: MixtureOfExperts' picks,
-            # MaskedDiffusionOutput's rows
+            # MaskedDiffusionOutput's rows, Mamba2Mixer's steps
             self._init_zero(name, arr)
         else:
             self._init_default(name, arr)
@@ -174,6 +174,32 @@ class _Fill(Constant):
 
     def __call__(self, _name, arr):
         arr[:] = self.value
+
+
+class SSMDecay(Initializer):
+    """A state-space layer's ``A_log`` (one a head): ``log(1 .. heads)``,
+    so that head ``h`` decays by ``exp(-(h + 1) dt)`` a position at
+    first (Mamba-2; transformers' ``Mamba2`` initialisation)."""
+
+    def __call__(self, _name, arr):
+        arr[:] = np.log(np.arange(1, arr.shape[0] + 1, dtype=np.float32))
+
+
+class SSMStepBias(Initializer):
+    """A state-space layer's ``dt_bias`` (one a head): ``softplus^-1(dt0)``
+    with ``dt0 = exp(U(ln time_step_min, ln time_step_max))``, floored at
+    ``time_step_floor``, so that the first step sizes spread evenly in
+    scale over the range (Mamba-2's ``dt_init``)."""
+
+    def __init__(self, time_step_min=0.001, time_step_max=0.1,
+                 time_step_floor=1e-4):
+        self.range = (math.log(time_step_min), math.log(time_step_max))
+        self.floor = time_step_floor
+
+    def __call__(self, _name, arr):
+        _random.uniform(*self.range, out=arr)
+        dt = np.maximum(np.exp(arr.asnumpy().astype(np.float64)), self.floor)
+        arr[:] = (dt + np.log(-np.expm1(-dt))).astype(np.float32)
 
 
 class Mixed(Initializer):

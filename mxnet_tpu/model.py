@@ -150,6 +150,31 @@ def _host_local(x):
     return np.concatenate([np.asarray(s.data) for s in shards], axis=0)
 
 
+# A leaf whose last axis is not a whole number of 128-lane tiles lies
+# padded on a TPU, and the runtime lands that layout on the host several
+# times slower than a dense one (PERF.md section 7). The write-back
+# fetches a large such leaf as rows of 1,024 copied on its devices (under
+# a millisecond for 160 MB), one leaf at a time, so the chip holds at most
+# one copy beside the state. A leaf kept in a stored order is taken back to
+# its declared axes (``axes``) in that same copy, so the host does not
+# transpose it.
+_LANES = 128
+_DENSE_ROW = 1024
+_DENSE_MIN_BYTES = 1 << 24
+_as_rows = compile_mod.tracked_jit(
+    lambda x, axes=None: (x if axes is None else x.transpose(axes))
+    .reshape(-1, _DENSE_ROW),
+    label="write_back.rows", static_argnames=("axes",))
+
+
+def _lands_padded(x):
+    """Whether the write-back fetches ``x`` through ``_as_rows``."""
+    return (isinstance(x, jax.Array) and x.ndim > 0
+            and x.shape[-1] % _LANES != 0 and x.size % _DENSE_ROW == 0
+            and x.nbytes >= _DENSE_MIN_BYTES
+            and x.sharding.is_fully_replicated)
+
+
 def _to_dev(x, dev):
     """Move an array to `dev` unless it already lives there COMMITTED
     (committed host arrays from data iterators must not pin jit to the cpu
@@ -1838,7 +1863,10 @@ class FeedForward(BASE_ESTIMATOR):
             # declared order on the cpu device, where the transposition is
             # XLA's and runs on every core; as a strided numpy view it
             # would be landed by one thread (a v5e's host: 1.3 s for
-            # 1.6 GB against 0.39 s for the plain copy).
+            # 1.6 GB against 0.39 s for the plain copy). A lane-padded
+            # leaf (_lands_padded) is fetched as dense rows while the
+            # batch's other copies run, a stored one in its declared order.
+            names = [*param_names, *(None for _ in aux_names)]
             leaves = [params[k] for k in param_names] \
                 + [aux[k] for k in aux_names]
             joins = [x.is_fully_addressable for x in leaves]
@@ -1846,8 +1874,26 @@ class FeedForward(BASE_ESTIMATOR):
                                      arrays=len(leaves),
                                      bytes=sum(x.nbytes for x in leaves),
                                      batched=sum(joins)):
-                fetched = iter(jax.device_get(
-                    [x for x, join in zip(leaves, joins) if join]))
+                batch = [(k, x) for k, x, join in zip(names, leaves, joins)
+                         if join]
+                # batch index -> the declared axes of a stored leaf, or None
+                dense = {i: tuple(int(a) for a in np.argsort(order[k]))
+                         if k in order else None
+                         for i, (k, x) in enumerate(batch)
+                         if _lands_padded(x)}
+                for i, (_, x) in enumerate(batch):
+                    if i not in dense and isinstance(x, jax.Array):
+                        x.copy_to_host_async()
+                rows = {}
+                for i, back in sorted(dense.items()):
+                    x = batch[i][1]
+                    if back is None:
+                        rows[i] = jax.device_get(_as_rows(x)).reshape(x.shape)
+                    else:
+                        rows[i] = jax.device_get(_as_rows(x, axes=back)) \
+                            .reshape([x.shape[a] for a in back])
+                fetched = iter([rows[i] if i in dense else jax.device_get(x)
+                                for i, (_, x) in enumerate(batch)])
                 values = [next(fetched) if join else _host_local(x)
                           for x, join in zip(leaves, joins)]
                 # no target: the arrays land UNCOMMITTED, as the
@@ -1856,8 +1902,12 @@ class FeedForward(BASE_ESTIMATOR):
                 with jax.default_device(cpu().jax_device):
                     n = len(param_names)
                     landed = jax.device_put(values)
-                    declared = _reorder(dict(zip(param_names, landed[:n])),
-                                        order, back=True)
+                    as_declared = {batch[i][0] for i, back in dense.items()
+                                   if back is not None}
+                    declared = _reorder(
+                        dict(zip(param_names, landed[:n])),
+                        {k: v for k, v in order.items()
+                         if k not in as_declared}, back=True)
                 self.arg_params.update(
                     (k, NDArray(v)) for k, v in declared.items())
                 self.aux_params.update(

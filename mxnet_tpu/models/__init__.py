@@ -15,9 +15,10 @@ from .moe_transformer import MoEPipelineLM, moe_pipeline_config
 from .laguna import laguna
 from .sdar import sdar
 from .kanana2 import kanana2
+from .nemotron_h import nemotron_h
 
 __all__ = ["mlp", "lenet", "alexnet", "inception_bn_cifar", "inception_bn",
            "resnet", "resnet50", "lstm_unroll", "LSTMState", "LSTMParam",
            "LSTMLM", "TransformerLM", "transformer_lm_config",
            "MoEPipelineLM", "moe_pipeline_config", "laguna", "sdar",
-           "kanana2"]
+           "kanana2", "nemotron_h"]

@@ -31,33 +31,8 @@ selection bias moves either way. With ``remat`` every decoder layer ends
 in a ``RematBoundary``.
 """
 
-from math import gcd
-
 from .. import symbol as sym
-
-
-def _linear(data, width, name):
-    return sym.FullyConnected(data=data, num_hidden=width, no_bias=True,
-                              name=name)
-
-
-def _columns(data, first, rest, name):
-    """The first ``first`` columns of ``data`` and the ``rest`` after them,
-    as equal slices put back together (``SliceChannel`` cuts equal
-    parts)."""
-    part = gcd(first, rest)
-    parts = sym.SliceChannel(data=data, num_outputs=(first + rest) // part,
-                             axis=1, name=name)
-
-    def joined(lo, hi, suffix):
-        if hi - lo == 1:
-            return parts[lo]
-        return sym.Concat(*[parts[i] for i in range(lo, hi)], dim=1,
-                          name=f"{name}_{suffix}")
-
-    cut = first // part
-    return joined(0, cut, "first"), joined(cut, (first + rest) // part,
-                                           "rest")
+from .decoder_parts import columns, linear as _linear
 
 
 def kanana2(seq_len=8192, layers=48, vocab_rows=128256, experts_held=128,
@@ -84,9 +59,10 @@ def kanana2(seq_len=8192, layers=48, vocab_rows=128256, experts_held=128,
     for l in range(layers):
         name = f"layer{l}"
         n1 = sym.RMSNorm(data=x, eps=rms_norm_eps, name=f"{name}_attn_norm")
-        latent, key_rope = _columns(
+        latent, key_rope = columns(
             _linear(n1, kv_lora_rank + qk_rope_head_dim, f"{name}_kv_a"),
-            kv_lora_rank, qk_rope_head_dim, f"{name}_kv_a_split")
+            {"first": kv_lora_rank, "rest": qk_rope_head_dim},
+            f"{name}_kv_a_split")
         attn = sym.LatentAttention(
             name=f"{name}_attn", seq_len=seq_len, num_heads=heads,
             qk_nope_head_dim=qk_nope_head_dim,

@@ -32,6 +32,9 @@ through ``FeedForward.fit`` (doc/developer-guide/decoder-ops.md).
                     auxiliary state ``expert_load``; with
                     ``selection="biased"`` the picks are by the scores plus
                     a bias kept as ``selection_bias`` and moved by the load.
+  MixtureOfExpertsSquaredReLU
+                    the same routing over ungated experts
+                    ``down(relu(up x)^2)`` (Nemotron-H).
   RematBoundary     identity; closes a recomputation segment of the
                     executor (executor._remat_segments).
 
@@ -457,15 +460,22 @@ def _count(ids, length):
                    axis=0, dtype=jnp.int32)
 
 
+def _product(a, w):
+    """``a w^T`` with an (out, in) weight, accumulated in float32."""
+    return jax.lax.dot_general(
+        a, w.astype(a.dtype), (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(a.dtype)
+
+
 def _gated_ffn(x, gate_w, up_w, down_w):
     """``down(silu(gate x) * (up x))`` with (out, in) weights."""
-    def product(a, w):
-        return jax.lax.dot_general(
-            a, w.astype(a.dtype), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(a.dtype)
+    return _product(jax.nn.silu(_product(x, gate_w)) * _product(x, up_w),
+                    down_w)
 
-    return product(jax.nn.silu(product(x, gate_w)) * product(x, up_w),
-                   down_w)
+
+def _relu2_ffn(x, up_w, down_w):
+    """``down(relu(up x)^2)`` with (out, in) weights."""
+    return _product(jnp.square(jax.nn.relu(_product(x, up_w))), down_w)
 
 
 @register_op("MixtureOfExperts")
@@ -517,6 +527,9 @@ class MixtureOfExpertsOp(OpProp):
     # the selection bias's step after each training step (the rate the
     # DeepSeek-V3 report trains with; no published config has a key for it)
     BIAS_RATE = 0.001
+    # an expert's matrices, the last its down projection: (experts_held,
+    # width, hidden) each, the last (experts_held, hidden, width)
+    MATRICES = ("gate_weight", "up_weight", "down_weight")
 
     params = {
         "num_experts": (Range(int, lo=1), REQUIRED, "width of the router"),
@@ -547,11 +560,9 @@ class MixtureOfExpertsOp(OpProp):
                 f"{self.top_k} of {self.num_experts}")
 
     def list_arguments(self):
-        names = ["data", "router_weight", "gate_weight", "up_weight",
-                 "down_weight"]
+        names = ["data", "router_weight", *self.MATRICES]
         if self.shared_width:
-            names += ["shared_gate_weight", "shared_up_weight",
-                      "shared_down_weight"]
+            names += [f"shared_{m}" for m in self.MATRICES]
         return names
 
     def list_auxiliary_states(self):
@@ -567,16 +578,17 @@ class MixtureOfExpertsOp(OpProp):
         # step, ``down_weight``'s among them, which follow the other two
         # and go with them; stored so too, ``down_weight`` costs the
         # block-diffusion cell 150 MB more of temporaries than it saves)
-        return {"gate_weight": (0, 2, 1), "up_weight": (0, 2, 1)}
+        return {m: (0, 2, 1) for m in self.MATRICES[:-1]}
 
     def infer_shape(self, in_shapes):
         d = self._known(in_shapes, 0)
         hidden, e, w, s = d[1], self.experts_held, self.expert_width, \
             self.shared_width
-        shapes = [d, (self.num_experts, hidden), (e, w, hidden),
-                  (e, w, hidden), (e, hidden, w)]
+        ins = len(self.MATRICES) - 1
+        shapes = [d, (self.num_experts, hidden), *[(e, w, hidden)] * ins,
+                  (e, hidden, w)]
         if s:
-            shapes += [(s, hidden), (s, hidden), (hidden, s)]
+            shapes += [*[(s, hidden)] * ins, (hidden, s)]
         return shapes, [d], [(self.num_experts,)] * len(
             self.list_auxiliary_states())
 
@@ -600,7 +612,7 @@ class MixtureOfExpertsOp(OpProp):
             weights = jax.lax.stop_gradient(weights)
         return experts, weights
 
-    def routed(self, x, experts, weights, gate_w, up_w, down_w):
+    def routed(self, x, experts, weights, *matrices):
         """This rank's part of the routed sum.
 
         The picks live in a sorted space of rows x top_k entries, those on
@@ -638,18 +650,29 @@ class MixtureOfExpertsOp(OpProp):
                     a, jnp.swapaxes(w, 1, 2).astype(a.dtype), sizes,
                     preferred_element_type=a.dtype)
 
-        h = jax.nn.silu(grouped(xs, gate_w)) * grouped(xs, up_w)
-        return _sorted_to_rows(grouped(h, down_w),
+        h = self.hidden(lambda w: grouped(xs, w), *matrices[:-1])
+        return _sorted_to_rows(grouped(h, matrices[-1]),
                                jnp.where(here, weights, 0.0), order, slots,
                                n)
 
+    @staticmethod
+    def hidden(up, gate_w, up_w):
+        """An expert's activation between its products; ``up(w)`` is the
+        product of the rows with ``w``."""
+        return jax.nn.silu(up(gate_w)) * up(up_w)
+
+    @staticmethod
+    def shared(x, *matrices):
+        return _gated_ffn(x, *matrices)
+
     def fwd(self, ins, aux, is_train, rng):
-        x, router_w, gate_w, up_w, down_w = ins[:5]
+        x, router_w = ins[:2]
+        k = len(self.MATRICES)
         load, *bias = aux
         experts, weights = self.route(x, router_w, *bias)
-        y = self.routed(x, experts, weights, gate_w, up_w, down_w)
+        y = self.routed(x, experts, weights, *ins[2:2 + k])
         if self.shared_width:
-            y = y + _gated_ffn(x, *ins[5:8])
+            y = y + self.shared(x, *ins[2 + k:2 + 2 * k])
         if is_train:
             counts = _count(jax.lax.stop_gradient(experts),
                             self.num_experts).astype(load.dtype)
@@ -685,3 +708,24 @@ class MixtureOfExpertsOp(OpProp):
             "max_held": float(held.max()),
             "experts_hit": int(np.count_nonzero(held)),
             "experts_held": self.experts_held}
+
+
+@register_op("MixtureOfExpertsSquaredReLU")
+class MixtureOfExpertsSquaredReLUOp(MixtureOfExpertsOp):
+    """``MixtureOfExperts`` with ungated experts (Nemotron-H's
+    ``relu2``): each expert, and the shared one, is ``down(relu(up x)^2)``.
+    ``up_weight`` (experts_held, width, hidden), ``down_weight``
+    (experts_held, hidden, width), with ``shared_width``
+    ``shared_up_weight`` / ``shared_down_weight``; routing, selection, the
+    sorted space, its kernels and the ``expert_load`` record are the
+    gated layer's."""
+
+    MATRICES = ("up_weight", "down_weight")
+
+    @staticmethod
+    def hidden(up, up_w):
+        return jnp.square(jax.nn.relu(up(up_w)))
+
+    @staticmethod
+    def shared(x, *matrices):
+        return _relu2_ffn(x, *matrices)

@@ -275,3 +275,122 @@ def test_preempt_flush_leaves_the_current_values(tmp_path):
     # the epoch's write-back, then the flush's: the same span, both batched
     assert [(a["epoch"], a["batched"] == a["arrays"])
             for a in _spans()] == [(0, True), (1, True)]
+
+
+def _net_with_a_padded_leaf():
+    # fc1_weight is (64, 144): its last axis is not a whole number of
+    # 128-lane tiles and its 9,216 values are nine rows of 1,024
+    net = mx.sym.Convolution(mx.sym.Variable("data"), num_filter=4,
+                             kernel=(3, 3), name="c1")
+    net = mx.sym.Flatten(mx.sym.Activation(net, act_type="relu",
+                                           name="relu1"), name="flat")
+    net = mx.sym.FullyConnected(net, num_hidden=64, name="fc1")
+    net = mx.sym.FullyConnected(net, num_hidden=2, name="fc")
+    return mx.sym.SoftmaxOutput(net, name="softmax")
+
+
+def test_which_leaves_land_as_dense_rows(monkeypatch):
+    monkeypatch.setattr(model_mod, "_DENSE_MIN_BYTES", 0)
+    lands = model_mod._lands_padded
+    assert lands(jnp.zeros((64, 144)))
+    assert lands(jnp.zeros((8, 16, 232), jnp.bfloat16))
+    assert not lands(jnp.zeros((64, 256)))        # whole lane tiles
+    assert not lands(jnp.zeros((3, 100)))         # no whole rows of 1,024
+    assert not lands(jnp.zeros(()))
+    assert not lands(np.zeros((64, 144), np.float32))
+    monkeypatch.undo()
+    assert not lands(jnp.zeros((64, 144)))        # under 16 MB
+
+
+@placement
+def test_a_padded_leaf_lands_as_dense_rows_with_the_same_bytes(
+        placement, monkeypatch):
+    monkeypatch.setattr(model_mod, "_DENSE_MIN_BYTES", 0)
+    calls = []
+    as_rows = model_mod._as_rows
+    monkeypatch.setattr(model_mod, "_as_rows",
+                        lambda x: calls.append(x.shape) or as_rows(x))
+    telemetry.reset()
+    X, y = _data()
+    ctx, kvstore = PLACEMENTS[placement]
+    model = mx.FeedForward(_net_with_a_padded_leaf(), ctx=ctx(),
+                           num_epoch=2, initializer=mx.init.Xavier(),
+                           learning_rate=0.1, momentum=0.9)
+    live = _LiveState(model)
+    with jax.default_device(jax.devices()[2]):
+        model.fit(X, y, batch_size=BATCH, kvstore=kvstore)
+    assert calls == [(64, 144)] * 2               # once an epoch
+    want = _per_leaf_reference(live)
+    got = _written_back(model)
+    assert sorted(got) == sorted(want)
+    for k, ref in want.items():
+        value = got[k].asnumpy()
+        assert value.dtype == ref.dtype and value.shape == ref.shape, k
+        assert value.tobytes() == ref.tobytes(), k
+        assert got[k].context == mx.cpu(0), k
+    (first, second) = _spans()
+    assert first["batched"] == first["arrays"] == len(got)
+
+
+def _net_with_a_stored_padded_leaf():
+    # the expert layer keeps gate_weight and up_weight on the device as
+    # (4, 16, 48), the order its grouped product reads (declared
+    # (4, 48, 16)); down_weight is (4, 16, 48) as declared. All three have
+    # a last axis of part of a lane tile and 3,072 values: three rows (and
+    # so has fc1_weight, (16, 192), as declared)
+    net = mx.sym.Flatten(mx.sym.Variable("data"), name="flat")
+    net = mx.sym.FullyConnected(net, num_hidden=16, name="fc1")
+    net = mx.sym.MixtureOfExperts(data=net, num_experts=4, experts_held=4,
+                                  top_k=2, expert_width=48, name="moe")
+    net = mx.sym.FullyConnected(net, num_hidden=2, name="fc")
+    return mx.sym.SoftmaxOutput(net, name="softmax")
+
+
+@placement
+def test_a_stored_padded_leaf_lands_in_its_declared_order_from_the_device(
+        placement, monkeypatch):
+    """A lane-padded leaf kept in a stored order is taken back to its
+    declared axes in the copy that makes its dense rows, and the host
+    transposes nothing: the values are bitwise the live state's, taken
+    back to the declared order."""
+    monkeypatch.setattr(model_mod, "_DENSE_MIN_BYTES", 0)
+    calls = []
+    as_rows = model_mod._as_rows
+    monkeypatch.setattr(
+        model_mod, "_as_rows",
+        lambda x, **kw: calls.append((x.shape, kw)) or as_rows(x, **kw))
+    reorders = []
+    reorder = model_mod._reorder
+    monkeypatch.setattr(
+        model_mod, "_reorder",
+        lambda tree, order, back=False: reorders.append((back, order))
+        or reorder(tree, order, back))
+    telemetry.reset()
+    X, y = _data()
+    ctx, kvstore = PLACEMENTS[placement]
+    model = mx.FeedForward(_net_with_a_stored_padded_leaf(), ctx=ctx(),
+                           num_epoch=2, initializer=mx.init.Xavier(),
+                           learning_rate=0.1, momentum=0.9)
+    live = _LiveState(model)
+    with jax.default_device(jax.devices()[2]):
+        model.fit(X, y, batch_size=BATCH, kvstore=kvstore)
+    stored = {"moe_gate_weight", "moe_up_weight"}
+    assert model._state_order() == dict.fromkeys(stored, (0, 2, 1))
+    epoch = [((16, 192), {}), ((4, 16, 48), {}),
+             ((4, 16, 48), {"axes": (0, 2, 1)}),
+             ((4, 16, 48), {"axes": (0, 2, 1)})]
+    assert sorted(calls, key=repr) == sorted(epoch * 2, key=repr)
+    # the write-backs' host reorders are empty
+    assert [order for back, order in reorders if back] == [{}, {}]
+    want = {k: model_mod._host_local(v)
+            for k, v in {**model_mod._reorder(
+                live.params, model._state_order(), back=True),
+                **live.aux}.items()}
+    got = _written_back(model)
+    assert sorted(got) == sorted(want)
+    for k, ref in want.items():
+        value = got[k].asnumpy()
+        assert value.dtype == ref.dtype and value.shape == ref.shape, k
+        assert value.tobytes() == ref.tobytes(), k
+        assert got[k].context == mx.cpu(0), k
+    assert got["moe_up_weight"].shape == (4, 48, 16)

@@ -459,3 +459,30 @@ def test_a_latent_layer_lowers_to_a_bounded_program(v5e, monkeypatch):
         return n
 
     assert equations(jax.make_jaxpr(fn)(*args).jaxpr) <= 889
+
+
+def test_a_mamba2_layer_compiles_for_v5e_within_its_temporaries(v5e):
+    """The chunked scan at the cell's widths (``nemotron3_nano_30b_a3b``:
+    64 heads of 64, 8 groups of 128, chunks of 128, 8,192 positions,
+    bfloat16), forward and the gradient of every input, compiles for v5e
+    and plans at most 2.5 GB of temporaries (1.96 GB measured, jax 0.9.0;
+    the decay planes alone are (chunk, chunk) float32 a head and chunk,
+    268 MB each), which the cell's 16 GB holds beside 10.67 GB of
+    state."""
+    from mxnet_tpu.ops import OPS
+
+    op = OPS.create("Mamba2Mixer", seq_len=8192, num_heads=64, head_dim=64,
+                    n_groups=8, state_size=128, conv_kernel=4,
+                    chunk_size=128)
+    shapes, _, aux = op.infer_shape([(8192, 6144)] + [None] * 8)
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=v5e)
+            for s in shapes]
+    record = jax.ShapeDtypeStruct(aux[0], jnp.float32, sharding=v5e)
+
+    def loss(ins, record):
+        (out,), new = op.fwd(list(ins), [record], True, None)
+        return jnp.sum(out.astype(jnp.float32) ** 2), new
+
+    compiled = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
+        args, record).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2.5e9
